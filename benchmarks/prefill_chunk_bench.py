@@ -10,11 +10,6 @@ window=64 the reduction is 64x by construction (ceil(P/64) vs P passes).
 """
 from __future__ import annotations
 
-import sys
-
-sys.path.insert(0, __file__.rsplit("/", 1)[0])
-import _bootstrap  # noqa: F401  (honours JAX_PLATFORMS=cpu)
-
 import json
 import sys
 import time

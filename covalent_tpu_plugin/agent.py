@@ -736,8 +736,11 @@ class AgentClient:
 
         A definitive rejection means the task never started, so relaunch
         through the fallback path is safe.  A ``bad_frame`` code is torn
-        content — identical bytes can never be re-sent successfully — so
-        the rejection carries the duck-typed PERMANENT tag.
+        content — identical bytes can never be re-sent successfully — and
+        ``backend_held`` is a pool server refusing to fork out of a
+        process that holds an XLA backend (no relaunch on that worker can
+        get the accelerator either), so both rejections carry the
+        duck-typed PERMANENT tag.
         """
         if task_id not in self._errors:
             return None
@@ -747,8 +750,12 @@ class AgentClient:
             f"agent@{self.address} rejected {what} {task_id}: {message}"
         )
         rejection.rejected = True  # type: ignore[attr-defined]
-        if code == "bad_frame":
-            rejection.fault_label = "agent_bad_frame"  # type: ignore[attr-defined]
+        label = {
+            "bad_frame": "agent_bad_frame",
+            "backend_held": "runtime_holds_backend",
+        }.get(code)
+        if label:
+            rejection.fault_label = label  # type: ignore[attr-defined]
             rejection.fault_transient = False  # type: ignore[attr-defined]
         return rejection
 

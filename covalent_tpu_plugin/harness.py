@@ -140,6 +140,23 @@ def _emit_worker_event(spec: dict, type: str, _paths=None, **fields) -> None:
     _append_event_line(_build_worker_event(spec, type, **fields), paths)
 
 
+def live_backend() -> str:
+    """Platform of THIS process's initialised XLA backend, or ``""``.
+
+    Never the thing that initialises a backend, and never an importer: it
+    reads ``sys.modules`` only.  Heartbeat threads ask while the task's own
+    thread may be halfway through ``import jax``, and a second thread
+    entering that import graph from the side makes Python hand one of them
+    a partially initialised module.
+    """
+    jax = sys.modules.get("jax")
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    initialised = getattr(bridge, "backends_are_initialized", None)
+    if jax is None or initialised is None or not initialised():
+        return ""
+    return jax.default_backend()
+
+
 def _heartbeat_payload(metrics_file: str) -> dict:
     """One heartbeat's body: process vitals + user-published progress.
 
@@ -179,22 +196,18 @@ def _heartbeat_payload(metrics_file: str) -> dict:
         # every beat, so a serving worker's liveness stream doubles as its
         # load report on the dispatcher side.
         payload["serve"] = serve
-    if "jax" in sys.modules:
+    if live_backend():
         try:
             import jax
 
-            from jax._src import xla_bridge
-
-            if xla_bridge.backends_are_initialized():
-                device = jax.local_devices()[0]
-                stats = device.memory_stats() or {}
-                mem = {
-                    k: stats[k]
-                    for k in ("bytes_in_use", "peak_bytes_in_use")
-                    if k in stats
-                }
-                if mem:
-                    payload["device_mem"] = mem
+            stats = jax.local_devices()[0].memory_stats() or {}
+            mem = {
+                k: stats[k]
+                for k in ("bytes_in_use", "peak_bytes_in_use")
+                if k in stats
+            }
+            if mem:
+                payload["device_mem"] = mem
         except Exception:  # noqa: BLE001 - absent on CPU backends
             pass
     return payload
@@ -581,6 +594,11 @@ def _apply_spec_env(spec: dict) -> None:
     invocations executing inside the resident server — one server serves
     one executor, so ``task_env`` is constant across its invocations and
     the process-wide mutation is idempotent by construction.
+
+    Every other ``JAX_*`` variable (``JAX_COMPILATION_CACHE_DIR`` above
+    all) counts only if it lands here before this process first imports
+    jax, which reads them once: true for a forked task and for the first
+    session or invocation of a server that did not preload jax.
     """
     env = spec.get("env") or {}
     for key, value in env.items():
@@ -592,25 +610,24 @@ def _apply_spec_env(spec: dict) -> None:
         for entry in reversed(str(env["PYTHONPATH"]).split(os.pathsep)):
             if entry and entry not in sys.path:
                 sys.path.insert(0, entry)
-    # Env alone can lose to a site-level PJRT plugin registration that
-    # re-pins the platform after interpreter start; jax.config wins if set
-    # before first backend use.  Pin from the spec env always (explicit user
-    # intent, worth the jax import), and from the inherited process env only
-    # when a sitecustomize already imported jax — then the pin is free and
-    # protects every subprocess on hosts whose site hook overrides the env.
+    # jax reads JAX_PLATFORMS once, at import: a resident interpreter that
+    # preloaded jax (or already ran an invocation) no longer sees the env
+    # write above, so the spec's platform is pinned through jax.config —
+    # explicit even when "" (auto-select).  The pin only takes before the
+    # first backend use; a process already initialised under another
+    # platform list cannot honour it, and that is the task's error, never
+    # a task that quietly runs somewhere else.
     if "JAX_PLATFORMS" in env:
-        platforms = env["JAX_PLATFORMS"]  # explicit, even "" = auto-select
-    elif "jax" in sys.modules:
-        platforms = os.environ.get("JAX_PLATFORMS")
-    else:
-        platforms = None
-    if platforms is not None:
-        try:
-            import jax
+        platforms = str(env["JAX_PLATFORMS"])
+        import jax
 
-            jax.config.update("jax_platforms", str(platforms))
-        except Exception:
-            pass
+        if live_backend() and (jax.config.jax_platforms or "") != platforms:
+            raise RuntimeError(
+                f"task_env pins JAX_PLATFORMS={platforms!r} but pid "
+                f"{os.getpid()} already initialised its backends under "
+                f"{jax.config.jax_platforms!r}"
+            )
+        jax.config.update("jax_platforms", platforms)
 
 
 def run_task(spec: dict) -> int:
@@ -628,7 +645,14 @@ def run_task(spec: dict) -> int:
             f.write(str(os.getpid()))
         os.replace(tmp_pid, pid_file)
 
-    _apply_spec_env(spec)
+    distributed = spec.get("distributed")
+    process_id = int(distributed["process_id"]) if distributed else 0
+    try:
+        _apply_spec_env(spec)
+    except Exception as env_error:  # noqa: BLE001 - transported to dispatcher
+        if process_id == 0:
+            _fallback_result(result_file, env_error)
+        return 1
 
     # Event sinks resolve to absolute BEFORE the task chdirs into its
     # workdir: mid-task emissions (checkpoint saves, the SIGTERM
@@ -638,8 +662,6 @@ def run_task(spec: dict) -> int:
         if spec.get(sink_key):
             spec[sink_key] = os.path.abspath(spec[sink_key])
 
-    distributed = spec.get("distributed")
-    process_id = int(distributed["process_id"]) if distributed else 0
     _emit_worker_event(spec, "worker.task_started", process_id=process_id)
     # Liveness starts before any blocking stage (pip install, distributed
     # barrier, the task itself): a worker hung anywhere keeps beating —
@@ -836,12 +858,17 @@ def run_task(spec: dict) -> int:
 # a channel was down are flushed on the reconnecting client's re-watch; the
 # dispatcher dedups by each event's `seq`.
 #
-# Fork-safety: the parent preloads modules (cloudpickle, jax, ...) but never
-# initializes an XLA backend or runs a computation — backend init happens in
-# each child, which is the documented-safe pattern (import before fork, use
-# after).  Children setsid into their own sessions, so they survive a pool/
-# channel death exactly like the other launch paths, and the dispatcher can
-# fall back to pid polling.
+# Fork-safety: the server preloads modules (cloudpickle, jax, ...) and its own
+# loop never initialises an XLA backend — for a forked task, backend init
+# happens in the child (import before fork, use after).  RPC invocations and
+# pool-mode serving sessions DO run inside this process, though, and the
+# first one that touches jax makes the server the holder of its backend: on
+# a TPU the chip belongs to that one process, and on any platform a fork of
+# the now multi-threaded runtime deadlocks at its first computation.  From
+# then on `run` is refused (`backend_held`, permanent) instead of forking a
+# child that can only hang — see _spawn_task.  Children setsid into their own
+# sessions, so they survive a pool/channel death exactly like the other
+# launch paths, and the dispatcher can fall back to pid polling.
 # --------------------------------------------------------------------------
 
 
@@ -1187,6 +1214,19 @@ def _spawn_task(command: dict, children: dict) -> None:
         _emit({"event": "error", "id": task_id or "",
                "message": "run requires id and spec"})
         return
+    held = live_backend()
+    if held:
+        _emit({"event": "error", "id": task_id, "code": "backend_held",
+               "permanent": True,
+               "message": (
+                   f"resident runtime pid {os.getpid()} holds an "
+                   f"initialised {held} backend (an RPC invocation or a "
+                   "serving session ran in it): a forked task would "
+                   "deadlock on the inherited runtime, and an accelerator "
+                   "belongs to one process — launch this task from an "
+                   "executor with its own runtime"
+               )})
+        return
     sys.stdout.flush()
     pid = os.fork()
     if pid == 0:
@@ -1485,14 +1525,14 @@ def _run_rpc_task(command: dict, fn) -> None:
     task_id = command.get("id") or ""
     spec = dict(command.get("spec") or {})
     spec.setdefault("operation_id", task_id)
-    # Same env contract as a launch-mode harness child (os.environ +
-    # PYTHONPATH sys.path mirror + jax platform pin): task_env must mean
-    # the same thing whichever runtime executes the function.
-    _apply_spec_env(spec)
     result, exception = None, None
     try:
+        # Same env contract as a launch-mode harness child (os.environ +
+        # PYTHONPATH sys.path mirror + jax platform pin): task_env must
+        # mean the same thing whichever runtime executes the function.
+        _apply_spec_env(spec)
         args, kwargs = _decode_rpc_args(command)
-    except BaseException as err:  # noqa: BLE001 - torn args fail the task
+    except BaseException as err:  # noqa: BLE001 - bad env/torn args fail the task
         args, kwargs, exception = (), {}, err
     _emit_rpc_event(spec, task_id, "worker.task_started", process_id=0)
     heartbeat_stop = _start_rpc_heartbeat(spec, task_id)
@@ -2474,7 +2514,13 @@ class _ServeSession:
     # -- session thread ----------------------------------------------------
 
     def _open_engine(self) -> bool:
-        """Load + verify the factory payload, build the engine, ack open."""
+        """Apply the env contract, load + verify the factory payload, build
+        the engine, ack open."""
+        try:
+            _apply_spec_env(self.spec)
+        except Exception as err:  # noqa: BLE001 - answered as a refused open
+            self._emit_open_error("env_failed", err, permanent=True)
+            return False
         code, loaded = _load_fn_payload(self.path, self.digest)
         if code:
             self._emit_open_error(code, loaded, permanent=(
@@ -2843,14 +2889,13 @@ class _ServeSession:
                     self._finish_history(rid, "deadline_exceeded")
 
     def _loop(self) -> None:
-        _apply_spec_env(self.spec)
-        self._gray = _gray_chaos_from_env()
         if not self._open_engine():
             # Failed open: mark closed so late requests reject cleanly
             # instead of queueing into a thread that already exited.
             self._closed.set()
             _SERVE_SESSIONS.pop(self.sid, None)
             return
+        self._gray = _gray_chaos_from_env()  # reads the env just applied
         last_stats = time.monotonic()
         try:
             while not (self._closed.is_set()

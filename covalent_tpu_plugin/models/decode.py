@@ -61,9 +61,10 @@ def inference_params(params: Any) -> Any:
     """Cast f32 master weights to bf16 for serving.
 
     Decode steps are HBM-bandwidth-bound — every step re-reads the full
-    weight set — so halving the bytes is a direct speedup: measured +10%
-    tokens/s scanned and +48% with ``scan_layers=False`` on the v5e 125M
-    decode (benchmarks/DECODE_SWEEP.md).  Non-f32 leaves (e.g. int
+    weight set — so halving the bytes is a direct speedup (+10% tokens/s
+    scanned, +48% with ``scan_layers=False`` on the 125M decode in an
+    earlier v5e sweep; not re-measured on this installation).  Non-f32
+    leaves (e.g. int
     embeddings) pass through untouched; training should keep the f32
     masters, this is a serving-side copy.
     """

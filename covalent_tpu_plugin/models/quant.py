@@ -211,8 +211,8 @@ def quantize_lm(model, params) -> tuple[Any, Any]:
     everything else (embeddings, norms) copied through.  Requires
     ``scan_layers=False`` — a scanned kernel's leading layer axis is
     indistinguishable from a contraction axis in the stacked tree, and
-    unrolled is the measured serving-optimal mode anyway
-    (benchmarks/DECODE_SWEEP.md).  Compose with
+    unrolled was the faster serving mode in an earlier v5e sweep (not
+    re-measured on this installation).  Compose with
     :func:`..decode.inference_params` to also cast the float leftovers to
     bf16.
     """
@@ -221,8 +221,7 @@ def quantize_lm(model, params) -> tuple[Any, Any]:
     config = model.config
     if config.scan_layers:
         raise ValueError(
-            "quantize_lm requires scan_layers=False (serve unrolled; see "
-            "benchmarks/DECODE_SWEEP.md)"
+            "quantize_lm requires scan_layers=False (serve unrolled)"
         )
     if config.moe_experts:
         raise ValueError("quantize_lm does not support MoE models yet")

@@ -30,8 +30,7 @@ TPU-native design — the pieces map to the compilation model:
   the tiny (B,) state vectors between calls, harvests finished rows,
   zeroes their cache lanes, and writes the next queued prompt into the
   slot.  One host round-trip per ``sync_steps`` tokens instead of one
-  per token — the knob trades admission latency against host chatter
-  (tunnelled TPUs want it large).
+  per token — the knob trades admission latency against host chatter.
 * **Bucketed batched prefill at admission** (``prefill="batched"``, the
   default).  An admitted prompt runs ONE single-lane prefill pass padded
   to a power-of-two bucket, then enters the shared decode loop — time to
@@ -912,9 +911,9 @@ def continuous_generate(
         ]
         if finished:
             # Bulk-harvest: ONE fetch each of buffer/plen/n_gen per sync
-            # boundary instead of three per finished slot — on tunneled
-            # backends every fetch is a full host round trip, and this
-            # loop's host chatter is the serving throughput floor.
+            # boundary instead of three per finished slot — every fetch
+            # blocks on the device, and this loop's host chatter is the
+            # serving throughput floor.
             # Admissions below only mutate freed slots, so the
             # pre-admission snapshot stays valid for the other rows.
             buffer_h = np.asarray(state[1])
@@ -2399,6 +2398,12 @@ def lm_engine_factory(model: TransformerLM, params: Any, **engine_kwargs):
     serializes this module by *reference* — workers must be able to
     import the package (or the caller registers it by value via
     ``cloudpickle.register_pickle_by_value``).
+
+    ``params`` travel inside the pickle, so hand this host (numpy) arrays.
+    A dispatcher that materialised them on an accelerator holds that
+    accelerator, and the worker on the same host can then never have it:
+    there, write a factory that builds or loads the params in the worker
+    (``examples/serve_lattice.py``).
     """
     def factory() -> ContinuousEngine:
         return ContinuousEngine(model, params, **engine_kwargs)

@@ -171,8 +171,7 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     ``vocab_chunk`` switches to the fused vocab-chunked cross-entropy
     (``ops/xent.py``): the model returns final FEATURES and the loss
     streams over lm_head chunks, so the (B, S, vocab) logits tensor is
-    never materialised in HBM — the loss-side bandwidth lever the round-4
-    step sweep left on the table.  Requires a plain float lm_head kernel
+    never materialised in HBM.  Requires a plain float lm_head kernel
     (no lm_head LoRA, unquantized)."""
     tokens = batch["tokens"]
     if vocab_chunk is None:

@@ -67,9 +67,10 @@ class TransformerConfig:
     remat_policy: str = "full"
     #: lax.scan over the block stack keeps compile time O(1) in depth, but
     #: blocks XLA from fusing/scheduling across block boundaries — unrolled
-    #: (False) measured ~33% faster on the v5e train step at 12 layers
-    #: (benchmarks/LM_STEP_SWEEP.md).  Scan stays the default for
-    #: compile-latency-sensitive paths; flip it off for long runs.
+    #: (False) was ~33% faster on the train step at 12 layers in an earlier
+    #: v5e sweep (not re-measured on this installation).  Scan stays the
+    #: default for compile-latency-sensitive paths; flip it off for long
+    #: runs.
     scan_layers: bool = True
     #: device mesh: required for attention="ring"; with attention="flash"
     #: it switches the kernel to the shard_map (collective-free) path.

@@ -19,8 +19,9 @@ on the fly: one accumulates dk/dv sweeping query tiles, one accumulates dq
 sweeping key tiles.  Backward HBM stays O(S·D), the same as forward, where
 the dense path's backward would materialise O(S²) probabilities.
 
-On non-TPU backends the same kernel runs in interpreter mode, which is what
-the CPU test tier exercises.
+On an explicit CPU platform the same kernels run in interpreter mode, which
+is what the CPU test tier exercises; a backend that fails to initialise
+raises instead of sliding there (see ``on_tpu``).
 """
 
 from __future__ import annotations
@@ -38,24 +39,23 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 _NEG_INF = NEG_INF
 
-# MXU-sweep winners on v5e at S=4096 (see flash_attention docstring).
+# Forward tile winners at S=4096 from an earlier v5e sweep; not re-measured
+# on this installation.
 _DEFAULT_BLOCK_Q = 512
 _DEFAULT_BLOCK_K = 1024
 
 
 def _pick_windowed_blocks(seq_len_q: int, seq_len_k: int,
                           window: int) -> tuple[int, int]:
-    """Forward-tile winners for the BANDED (windowed) grids, from the v5e
-    r4 hardware sweep (benchmarks/WINDOW_SWEEP.md).
+    """Forward-tile choice for the BANDED (windowed) grids.
 
     The band run is quantised to whole key tiles, so tile choice trades
     band tightness (smaller BK wastes fewer out-of-band columns) against
-    MXU/overhead efficiency (larger tiles amortise better).  On-device
-    chained timing (dispatch-noise-free; see WINDOW_SWEEP.md's method
-    note) shows (512, 512) winning for w <= 512 and (1024, 1024) for
-    wider bands, consistently across S = 4k..16k; the full-attention
-    default (512, 1024) gives up 4-15% on banded shapes.  Explicit
-    ``block_q``/``block_k`` args always override.
+    MXU/overhead efficiency (larger tiles amortise better).  The
+    constants came from an earlier v5e sweep over S = 4k..16k — (512, 512)
+    for w <= 512, (1024, 1024) for wider bands — and have not been
+    re-measured on this installation.  Explicit ``block_q``/``block_k``
+    args always override.
     """
     if window <= 512:
         return 512, 512
@@ -73,11 +73,28 @@ def _gqa_group(q: jax.Array, k: jax.Array) -> int:
 
 
 def on_tpu() -> bool:
-    try:
-        device = jax.devices()[0]
-    except Exception:
+    """Whether the default backend is a TPU.
+
+    A backend that fails to initialise raises out of here: answering
+    ``False`` would quietly send every caller down the interpreter or the
+    dense-reference road on a machine that was meant to have a chip.
+    """
+    return jax.devices()[0].platform == "tpu"
+
+
+def default_interpret() -> bool:
+    """Pallas mode for a call that did not pick one: compiled (Mosaic) on
+    a TPU, interpreter on an explicit CPU platform, an error anywhere else
+    — the kernels use TPU memory spaces no other compiler lowers."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
         return False
-    return "tpu" in (device.platform + " " + getattr(device, "device_kind", "")).lower()
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash attention kernels run compiled on 'tpu' or interpreted on "
+        f"'cpu'; the default backend is {platform!r}"
+    )
 
 
 def _band_visible(qpos, kpos, window: int | None, sinks: int = 0):
@@ -136,8 +153,7 @@ def _check_window(window, causal, sinks: int = 0) -> None:
 # With a sliding window the visible band covers only ~S·w of the S² score
 # matrix.  The `@pl.when` tile-skip alone saves the MXU work but the grid
 # still *visits* (and DMAs) every K/V tile — at S=16k/w=1k that is ~8× of
-# wasted HBM traffic (measured: the windowed win saturated near 2× of a
-# ~16× opportunity, BENCH_r02).  When positions are the default contiguous
+# wasted HBM traffic.  When positions are the default contiguous
 # arange, the tiles a query tile needs are statically a contiguous run of
 # ~⌈(BQ+w)/BK⌉+1 key tiles, so the sweep dimension can be shrunk to that
 # run with a q-tile-relative index_map.  The index_map clamps to the last
@@ -235,10 +251,9 @@ def _kt_interior(i, jj, block_q: int, block_k: int, window: int,
                  kt_full: int, sinks: int = 0):
     """Inner step ``jj`` of query tile ``i`` is an INTERIOR tile: every
     (q, k) pair it holds is visible, so the kernel may skip the band mask
-    entirely (round-5 per-tile-overhead cut, WINDOW_SWEEP.md: at w=1k the
-    measured multiple sat on the 1024-tile geometry ceiling; tighter
-    tiles only win if the per-tile VPU work shrinks — interior tiles are
-    the dominant per-tile VPU cost once DMA is banded).  Exact only for
+    entirely (interior tiles are the dominant per-tile VPU cost once DMA
+    is banded; the gain has not been measured on this installation).
+    Exact only for
     contiguous positions, which is the precondition of the banded grid
     this is used with.  A tile is interior iff it is fully causal
     (``max_k <= min_q``) and fully inside the band (``min_k > max_q -
@@ -582,8 +597,8 @@ def _flash_forward(
     return out, lse
 
 
-# Backward tile edge (v5e sweep, 2026-07): 1024 beat 512/256 at every
-# (S, head_dim) probed — S=2048/4096/8192, d=64/128; see benchmarks/.
+# Backward tile edge: 1024 beat 512/256 at S=2048/4096/8192, d=64/128 in
+# an earlier v5e sweep; not re-measured on this installation.
 # _fit_block halves it to divide shorter or odd sequences.
 _DEFAULT_BWD_BLOCK = 1024
 
@@ -997,12 +1012,12 @@ def flash_attention(
     (ring K/V shards).
 
     ``interpret=None`` auto-selects: compiled Mosaic kernel on TPU,
-    interpreter elsewhere (the CPU-mesh test tier).  Default (None) blocks
-    are the MXU-sweep winners on v5e (fwd 512×1024: 16.9× over the fused
-    XLA path at S=4096; bwd 1024²: 5.6× at S=4096, 15.7× at S=8192 — see
-    benchmarks/ATTENTION_SWEEP.md), auto-shrunk by halving to divide any
-    sequence length; explicitly passed blocks must divide the sequence
-    exactly.
+    interpreter on an explicit CPU platform (the CPU-mesh test tier); a
+    backend that fails to initialise raises.  Default (None) blocks (fwd
+    512×1024, bwd 1024²) came from an earlier v5e sweep and have not been
+    re-measured on this installation; they auto-shrink by halving to
+    divide any sequence length, while explicitly passed blocks must
+    divide the sequence exactly.
 
     ``window=w`` (sliding-window / Mistral-style local attention,
     requires ``causal``) restricts each query to the ``w`` most recent
@@ -1016,7 +1031,7 @@ def flash_attention(
     """
     _check_window(window, causal, sinks)
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = default_interpret()
     return _flash(
         q, k, v, q_positions, k_positions, causal, block_q, block_k,
         interpret, window, sinks,

@@ -4,8 +4,8 @@ The standard LM loss path materialises a (tokens, vocab) f32 logits
 tensor — at the 125M bench shape (8×1024 tokens, 32k vocab) that is
 ~1 GB written by the lm_head matmul, re-read by the softmax, and visited
 again in the backward, on a chip whose usual bottleneck is exactly that
-HBM bandwidth (round-4 step sweep: 51% MFU with every matmul lever
-already pulled — the residual gap is loss-side traffic).  The reference
+HBM bandwidth (its gain has not been measured on this installation).
+The reference
 stack has no analog (it runs opaque callables, SURVEY §2); this is a
 TPU-first component in the spirit of flash attention applied to the
 classifier: stream over vocabulary chunks, keep each (T, chunk) logits

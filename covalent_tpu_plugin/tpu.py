@@ -190,9 +190,8 @@ _EXECUTOR_PLUGIN_DEFAULTS = {
     # larger than fn pickles and can fill a disk well inside the TTL.
     # 0 disables; COVALENT_TPU_CAS_MAX_BYTES overrides per process.
     "cas_max_bytes": 0,
-    # NOT jax by default: forking a parent that already imported jax (PJRT
-    # plugins register at import) measurably slows TPU backend init in the
-    # children; interpreter+sitecustomize startup is the big win anyway.
+    # NOT jax by default: the fork saves interpreter start-up either way,
+    # and a task that never touches jax should not pay its import.
     "pool_preload": "cloudpickle",
     # Binary agent-channel frames (transport/frames.py): negotiated on the
     # ready-banner handshake; RPC args/results and streamed serve tokens
@@ -5357,6 +5356,9 @@ class TPUExecutor(RemoteExecutor):
             for err in errors:
                 if isinstance(err, _StageUploadFailed):
                     raise err
+            for err in errors:
+                if getattr(err, "fault_transient", None) is False:
+                    raise err  # keeps its permanent label: never retried
             raise TransportError(
                 f"launch failed on {len(errors)}/{len(conns)} workers: "
                 f"{errors[0]}"
@@ -5417,6 +5419,12 @@ class TPUExecutor(RemoteExecutor):
                         f"agent submit on {conn.address} failed after the "
                         f"run command was sent: {err}"
                     ) from err
+                if getattr(err, "fault_transient", None) is False:
+                    # A self-classified permanent refusal (the runtime
+                    # holds the accelerator backend): a nohup-launched
+                    # process on the same worker cannot have the chip
+                    # either — surface the refusal, do not route around it.
+                    raise
                 app_log.warning(
                     "agent submit on %s failed (%s); nohup fallback",
                     conn.address, err,

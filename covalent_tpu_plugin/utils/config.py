@@ -18,16 +18,9 @@ from __future__ import annotations
 
 import os
 import threading
+import tomllib
 from pathlib import Path
 from typing import Any
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11
-    try:
-        import tomli as tomllib  # type: ignore[no-redef]
-    except ModuleNotFoundError:  # no TOML parser at all: reads degrade
-        tomllib = None  # type: ignore[assignment]
 
 try:  # covered by the stub-covalent interop tier when importable
     from covalent._shared_files.config import get_config as _ct_get_config
@@ -58,19 +51,10 @@ def _load() -> dict[str, Any]:
     global _cache
     if _cache is None:
         path = _config_path()
-        if path.is_file() and tomllib is not None:
+        if path.is_file():
             with open(path, "rb") as f:
                 _cache = tomllib.load(f)
         else:
-            if path.is_file():
-                import warnings
-
-                warnings.warn(
-                    f"no TOML parser available (python < 3.11 without tomli); "
-                    f"ignoring config file {path}",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
             _cache = {}
     return _cache
 

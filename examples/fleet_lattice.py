@@ -38,7 +38,9 @@ def pool_spec(name: str, capacity: int, fallback: bool = False) -> dict:
             "python_path": sys.executable,
             "poll_freq": 0.2,
             "use_agent": False,
-            "task_env": {"JAX_PLATFORMS": "cpu"},  # drop on a real TPU VM
+            # Drop on a real TPU VM — where a pool's capacity must not
+            # exceed its chips once electrons use them: one process a chip.
+            "task_env": {"JAX_PLATFORMS": "cpu"},
         },
     }
 

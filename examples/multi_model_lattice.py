@@ -15,7 +15,10 @@ The ROADMAP item-1 arc end to end, runnable on any machine:
    a dedicated single-adapter oracle engine, while every base request
    issued across the promotion completes untouched.
 
-On a real deployment, swap the executors for `workers=[...]` /
+This process never touches jax: every model, param tree and engine —
+the oracle included — is built in a worker, and the stages run one
+after another, so on a one-chip host each finds the chip free.  On a
+real deployment, swap the executors for `workers=[...]` /
 `tpu_name=...` and drop the CPU pins.  Run:
 
   JAX_PLATFORMS=cpu python examples/multi_model_lattice.py
@@ -30,29 +33,17 @@ import time
 repo_root = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, repo_root)
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 from covalent_tpu_plugin import TPUExecutor
-from covalent_tpu_plugin.models import (
-    TransformerConfig,
-    TransformerLM,
-    add_lora,
-)
-from covalent_tpu_plugin.models import lora as lora_mod
-from covalent_tpu_plugin.models.serve import ContinuousEngine, lm_engine_factory
 from covalent_tpu_plugin.serving import open_session
 from covalent_tpu_plugin.workflow import dispatch_sync, electron, lattice
 
-CONFIG = TransformerConfig(
+CONFIG = dict(
     vocab_size=64,
     d_model=32,
     n_layers=2,
     n_heads=2,
     d_ff=64,
     max_seq=64,
-    dtype=jnp.float32,
     attention="reference",
     scan_layers=False,  # serving-optimal, and required by add_lora
 )
@@ -167,21 +158,71 @@ def finetune(config_dict: dict, ckpt_dir: str) -> dict:
     )
 
 
-def tuned_tree(model, params, leaves):
-    """Rebuild the full LoRA params tree from the portable leaf list
-    (the registry wire form) — for the local oracle engine."""
+def base_model():
+    """Worker side: the serving model and its seeded base params."""
+    import jax
+    import jax.numpy as jnp
+
+    from covalent_tpu_plugin.models import TransformerConfig, TransformerLM
+
+    model = TransformerLM(TransformerConfig(dtype=jnp.float32, **CONFIG))
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
+def engine_factory():
+    """Runs ONCE, inside the resident serving worker — the process that
+    holds the accelerator builds everything that lives on it."""
+    from covalent_tpu_plugin.models.serve import ContinuousEngine
+
+    model, params = base_model()
+    # adapter_rank sizes the (empty) bank; attach fills it live.
+    return ContinuousEngine(
+        model, params, max_batch=4, sync_steps=4, adapter_rank=RANK
+    )
+
+
+@electron(executor=spot)
+def oracle_stream(leaves: list) -> list:
+    """A dedicated single-adapter engine, rebuilt from the portable leaf
+    list (the registry wire form), decoding the promoted request."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from covalent_tpu_plugin.models import add_lora
+    from covalent_tpu_plugin.models import lora as lora_mod
+    from covalent_tpu_plugin.models.serve import ContinuousEngine
+
+    model, params = base_model()
     lmodel, filled = add_lora(model, params, rank=RANK)
     mask = jax.tree_util.tree_leaves(lora_mod.lora_mask(filled))
     flat, treedef = jax.tree_util.tree_flatten(filled)
     it = iter(leaves)
-    merged = [
-        jnp.asarray(next(it)) if m else leaf
-        for leaf, m in zip(flat, mask)
-    ]
-    return lmodel, jax.tree_util.tree_unflatten(treedef, merged)
+    tuned = jax.tree_util.tree_unflatten(treedef, [
+        jnp.asarray(next(it)) if m else leaf for leaf, m in zip(flat, mask)
+    ])
+    oracle = ContinuousEngine(
+        lmodel, tuned, max_batch=2, sync_steps=4,
+        max_new_tokens=MAX_NEW_TOKENS, length=48,
+    )
+    oracle.admit("r", np.asarray([7], np.int32))
+    expected: list = []
+    while oracle.busy:
+        for event in oracle.step():
+            expected.extend(event["tokens"])
+    oracle.close()
+    return [int(t) for t in expected]
 
 
-async def serve_and_promote(model, params, leaves) -> None:
+@lattice
+def oracle(leaves: list) -> list:
+    return oracle_stream(leaves)
+
+
+async def serve_and_promote(leaves) -> list:
     executor = TPUExecutor(
         transport="local",
         cache_dir=os.path.join(workdir, "cache_serve"),
@@ -198,13 +239,7 @@ async def serve_and_promote(model, params, leaves) -> None:
     )
     t0 = time.perf_counter()
     handle = await open_session(
-        executor,
-        # adapter_rank sizes the (empty) bank; attach fills it live.
-        lm_engine_factory(
-            model, params, max_batch=4, sync_steps=4,
-            adapter_rank=RANK,
-        ),
-        stats_interval_s=0.5,
+        executor, engine_factory, stats_interval_s=0.5,
     )
     print(f"session {handle.sid} open in {time.perf_counter() - t0:.1f}s "
           f"(adapter bank, rank {RANK})")
@@ -212,7 +247,7 @@ async def serve_and_promote(model, params, leaves) -> None:
         # Base traffic first — and it KEEPS flowing while we promote.
         in_flight = [
             await handle.request(
-                [i % CONFIG.vocab_size],
+                [i % CONFIG["vocab_size"]],
                 params={"max_new_tokens": MAX_NEW_TOKENS},
             )
             for i in range(BASE_REQUESTS)
@@ -245,23 +280,8 @@ async def serve_and_promote(model, params, leaves) -> None:
             len(stream) == MAX_NEW_TOKENS for stream in base_streams
         ), "a base stream was dropped across the promotion"
 
-        # The promoted adapter decodes bit-equal to a dedicated
-        # single-adapter oracle engine built from the same leaves.
-        lmodel, tuned = tuned_tree(model, params, leaves)
-        oracle = ContinuousEngine(
-            lmodel, tuned, max_batch=2, sync_steps=4,
-            max_new_tokens=MAX_NEW_TOKENS, length=48,
-        )
-        oracle.admit("r", np.asarray([7], np.int32))
-        expected: list = []
-        while oracle.busy:
-            for event in oracle.step():
-                expected.extend(event["tokens"])
-        oracle.close()
-        assert tuned_stream == expected, "promoted adapter diverged"
         print(f"{BASE_REQUESTS} base requests completed across the "
-              f"promotion (zero drops); tuned stream bit-equal to the "
-              f"single-adapter oracle: {tuned_stream}")
+              f"promotion (zero drops)")
         print("worker stats:", {
             k: v for k, v in (handle.stats or {}).items()
             if k.startswith("adapter_")
@@ -270,16 +290,11 @@ async def serve_and_promote(model, params, leaves) -> None:
         closed = await handle.close()
         await executor.close()
         print("closed after", closed.get("served"), "requests served")
+    return tuned_stream
 
 
 if __name__ == "__main__":
-    config_dict = dict(
-        vocab_size=CONFIG.vocab_size, d_model=CONFIG.d_model,
-        n_layers=CONFIG.n_layers, n_heads=CONFIG.n_heads,
-        d_ff=CONFIG.d_ff, max_seq=CONFIG.max_seq,
-        attention=CONFIG.attention, scan_layers=CONFIG.scan_layers,
-    )
-    result = dispatch_sync(finetune)(config_dict, CKPT)
+    result = dispatch_sync(finetune)(CONFIG, CKPT)
     assert result.status == "COMPLETED", result.error
     trained = result.result
     print(f"fine-tune done at step {trained['step']} "
@@ -287,8 +302,13 @@ if __name__ == "__main__":
           f"loss {trained['loss']:.4f}, "
           f"{len(trained['leaves'])} adapter leaves")
 
-    model = TransformerLM(CONFIG)
-    params = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-    asyncio.run(serve_and_promote(model, params, trained["leaves"]))
+    tuned_stream = asyncio.run(serve_and_promote(trained["leaves"]))
+
+    # The promoted adapter decodes bit-equal to a dedicated single-adapter
+    # oracle engine built from the same leaves — in a worker of its own,
+    # after the session's runtime has gone and the accelerator is free.
+    expected = dispatch_sync(oracle)(trained["leaves"])
+    assert expected.status == "COMPLETED", expected.error
+    assert tuned_stream == expected.result, "promoted adapter diverged"
+    print(f"tuned stream bit-equal to the single-adapter oracle: "
+          f"{tuned_stream}")

@@ -1,19 +1,21 @@
 """One resident serving session, many concurrent callers.
 
 The ISSUE 9 acceptance shape, runnable on any machine: a tiny
-TransformerLM is loaded and compiled ONCE inside a warm gang's resident
+TransformerLM is built and compiled ONCE inside a warm gang's resident
 runtime (`serve_open` ships the engine factory by CAS digest), then 12
 concurrent requests from two tenants share its fixed-slot continuous
 batch — each a single `serve_request` write on the held-open agent
 channel, tokens streamed back incrementally so time-to-first-token is
 one decode chunk, not end-of-batch.  Shows:
 
-* `serving.open_session` + `models/serve.lm_engine_factory`,
+* `serving.open_session` with a factory that builds the model, its
+  params and the `ContinuousEngine` in the worker,
 * the `request.stream()` chunk iterator (real TTFT) vs `result()`,
 * per-session stats (queue depth, tokens/s) and the session status view.
 
-On a real deployment, swap the executor for `workers=[...]` /
-`tpu_name=...` and drop the CPU pin.  Run:
+This process never touches jax: an accelerator belongs to one process,
+and that process is the resident worker.  On a real deployment, swap the
+executor for `workers=[...]` / `tpu_name=...` and drop the CPU pin.  Run:
 
   JAX_PLATFORMS=cpu python examples/serve_lattice.py
 """
@@ -27,14 +29,10 @@ import time
 repo_root = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, repo_root)
 
-import jax
-
 from covalent_tpu_plugin import TPUExecutor
-from covalent_tpu_plugin.models import TransformerConfig, TransformerLM
-from covalent_tpu_plugin.models.serve import lm_engine_factory
 from covalent_tpu_plugin.serving import open_session
 
-CONFIG = TransformerConfig(
+CONFIG = dict(
     vocab_size=256,
     d_model=64,
     n_layers=2,
@@ -42,11 +40,28 @@ CONFIG = TransformerConfig(
     d_ff=128,
     max_seq=64,
     attention="reference",
-    scan_layers=False,  # serving-optimal (benchmarks/LM_STEP_SWEEP.md)
+    scan_layers=False,  # unrolled: the serving mode
 )
 
 REQUESTS = 12
 MAX_NEW_TOKENS = 12
+
+
+def engine_factory():
+    """Runs ONCE, inside the resident worker: the model, its params and
+    the engine are built by the process that holds the accelerator —
+    nothing device-resident is pickled across from the dispatcher."""
+    import jax
+    import jax.numpy as jnp
+
+    from covalent_tpu_plugin.models import TransformerConfig, TransformerLM
+    from covalent_tpu_plugin.models.serve import ContinuousEngine
+
+    model = TransformerLM(TransformerConfig(**CONFIG))
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return ContinuousEngine(model, params, max_batch=4, sync_steps=4)
 
 
 async def main() -> None:
@@ -59,8 +74,7 @@ async def main() -> None:
         use_agent="pool",  # sessions live in the resident runtime
         prewarm=False,
         heartbeat_interval=0.0,
-        # The factory pickles `models/serve` by REFERENCE: the resident
-        # worker must be able to import the package.
+        # The factory imports the package inside the resident worker.
         task_env={
             "PYTHONPATH": os.path.abspath(repo_root) + os.pathsep
             + os.environ.get("PYTHONPATH", ""),
@@ -68,17 +82,11 @@ async def main() -> None:
         },
     )
 
-    model = TransformerLM(CONFIG)
-    params = model.init(
-        jax.random.PRNGKey(0),
-        jax.numpy.zeros((1, 8), jax.numpy.int32),
-    )["params"]
-
     t0 = time.perf_counter()
     handle = await open_session(
         executor,
-        # Params load + prefill/decode jit happen ONCE, in here:
-        lm_engine_factory(model, params, max_batch=4, sync_steps=4),
+        # Model build + prefill/decode jit happen ONCE, in the worker:
+        engine_factory,
         stats_interval_s=0.5,
     )
     print(f"session {handle.sid} open in {time.perf_counter() - t0:.1f}s "
@@ -99,7 +107,7 @@ async def main() -> None:
         t1 = time.perf_counter()
         requests = [
             await handle.request(
-                [i % CONFIG.vocab_size],
+                [i % CONFIG["vocab_size"]],
                 params={"max_new_tokens": MAX_NEW_TOKENS},
                 tenant="interactive" if i % 2 else "batch",
             )

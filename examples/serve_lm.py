@@ -16,8 +16,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 
-if "xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", ""):
-    jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np
@@ -41,7 +39,7 @@ CONFIG = TransformerConfig(
     max_seq=64,
     dtype=jnp.float32,
     attention="reference",
-    scan_layers=False,  # serving-optimal (benchmarks/LM_STEP_SWEEP.md)
+    scan_layers=False,  # unrolled: the serving mode
 )
 
 

@@ -165,3 +165,41 @@ def test_run_task_writes_profiler_trace(tmp_path):
     assert exception is None and result == 64.0
     # jax writes plugins/profile/<ts>/*.xplane.pb under the trace dir
     assert any(profile_dir.rglob("*.xplane.pb"))
+
+
+def test_platform_pin_that_cannot_take_is_the_tasks_error(tmp_path, monkeypatch):
+    """This process already runs on the CPU: a spec pinning another platform
+    must fail in the task's result, not run the task somewhere else."""
+    import jax
+
+    jax.devices()  # backends initialised under JAX_PLATFORMS=cpu
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # restored after the env write
+    ran = tmp_path / "ran"
+    spec, result_file = _stage(
+        tmp_path, lambda: ran.write_text("x"), env={"JAX_PLATFORMS": "tpu"}
+    )
+    assert harness.run_task(spec) == 1
+    result, exception = load_result(result_file)
+    assert result is None and not ran.exists()
+    assert isinstance(exception, RuntimeError)
+    assert "JAX_PLATFORMS='tpu'" in str(exception)
+    assert str(os.getpid()) in str(exception)
+    # The same pin the process started under keeps working.
+    harness._apply_spec_env({"env": {"JAX_PLATFORMS": "cpu"}})
+
+
+def test_pool_server_refuses_to_fork_once_it_holds_a_backend(capsys):
+    """A forked child of a jax-initialised process deadlocks at its first
+    computation (and on a TPU the chip has one owner): ``run`` must answer
+    with a permanent, classified refusal naming the holder — and not fork."""
+    import jax
+
+    jax.devices()
+    assert harness.live_backend() == "cpu"
+    children: dict = {}
+    harness._spawn_task({"id": "op-1", "spec": "/nonexistent.json"}, children)
+    event = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert children == {}
+    assert event["event"] == "error" and event["id"] == "op-1"
+    assert event["code"] == "backend_held" and event["permanent"] is True
+    assert f"pid {os.getpid()}" in event["message"]

@@ -403,6 +403,49 @@ def test_rpc_unavailable_runtime_falls_back_to_launch(tmp_path, run_async):
     assert mode == "launch"
 
 
+def test_launch_from_a_runtime_holding_a_backend_fails_at_once(
+    tmp_path, run_async
+):
+    """One runtime, both modes: after an RPC electron initialised jax inside
+    the resident server, a launch-mode electron would fork a child that can
+    only hang (and, on a TPU, could never have the chip).  It is refused at
+    once, permanently, naming the holder — no retry, no nohup detour."""
+
+    def touch_jax():
+        import os
+
+        import jax.numpy as jnp
+
+        return os.getpid(), float(jnp.arange(4.0).sum())
+
+    async def flow():
+        ex = make_rpc_executor(tmp_path, max_task_retries=2)
+        try:
+            holder, value = await ex.run(
+                touch_jax, [], {}, {"dispatch_id": "hold", "node_id": 0}
+            )
+            with pytest.raises(RuntimeError) as refused:
+                await asyncio.wait_for(
+                    ex.run(
+                        square, [3], {},
+                        {"dispatch_id": "hold", "node_id": 1,
+                         "dispatch_mode": "launch"},
+                    ),
+                    timeout=60,
+                )
+            # The runtime itself is unharmed: RPC work keeps flowing.
+            again = await ex.run(
+                square, [4], {}, {"dispatch_id": "hold", "node_id": 2}
+            )
+        finally:
+            await ex.close()
+        return holder, value, str(refused.value), again
+
+    holder, value, message, again = run_async(flow())
+    assert value == 6.0 and again == 16
+    assert f"pid {holder} holds an initialised cpu backend" in message
+
+
 def test_rpc_preselect_static_fallbacks(tmp_path):
     """Shapes RPC mode cannot serve route to launch before any attempt."""
     ex = make_rpc_executor(tmp_path / "base", dispatch_mode="auto")
