@@ -35,6 +35,9 @@ import threading
 import time
 import zlib
 
+#: Fallback for ``_process_start`` (monotonic clock).
+_T_LOADED = time.monotonic()
+
 
 #: Per-process worker-event sequence + write-failure accounting.  The seq
 #: lets the dispatcher dedup re-delivered lines (the telemetry side-band
@@ -138,6 +141,142 @@ def _emit_worker_event(spec: dict, type: str, _paths=None, **fields) -> None:
     if not paths:
         return
     _append_event_line(_build_worker_event(spec, type, **fields), paths)
+
+
+# --------------------------------------------------------------------------
+# The worker's half of the trace.  One recorder for every runtime: a
+# launch-mode task keeps its records in a list and writes them once, in
+# the result file's trailer; an RPC invocation sends its list in one
+# record, a serving session each span as it ends, over the telemetry
+# side-band.  The dispatcher re-emits them with these ids kept
+# (``obs.trace.record_remote_span``).
+# --------------------------------------------------------------------------
+
+
+class _Annotated:
+    """``with _Annotated(name):`` — a ``jax.profiler.TraceAnnotation`` where
+    jax is already imported in this process, a no-op where it is not: a
+    profiler capture's host plane then shows what the program did, on the
+    device's clock.  jax is never imported for it, and with no profiler
+    session open entering one costs a flag test."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str) -> None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._annotation = (
+            None if profiler is None else profiler.TraceAnnotation(name)
+        )
+
+    def __enter__(self) -> "_Annotated":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+
+def _emit_span(
+    sink, name: str, trace, t0: float, t1: float | None = None,
+    status: str = "OK", **attrs,
+) -> None:
+    """Hand ``sink`` one span record of the worker's own work.
+
+    ``trace`` is a ``context_of`` carrier (the task spec's ``trace``, or
+    the per-request one off a ``serve_request``/``serve_prefill`` header);
+    without one (an old dispatcher, a malformed carrier) the span is
+    dropped — a worker must never mint orphan traces the store can't
+    finalize.  ``t0``/``t1`` are monotonic stamps; the wall-clock
+    ``start_ts`` is reconstructed here so the two clock domains never mix
+    on the wire.
+    """
+    if not isinstance(trace, dict) or not trace.get("trace_id"):
+        return
+    t1 = time.monotonic() if t1 is None else t1
+    parent = trace.get("span_id")
+    fields = {
+        "name": name,
+        "trace_id": str(trace["trace_id"]),
+        "parent_id": str(parent) if parent else None,
+        "span_id": os.urandom(8).hex(),
+        "start_ts": round(time.time() - (time.monotonic() - t0), 6),
+        "duration_s": round(max(0.0, t1 - t0), 6),
+        "status": status,
+    }
+    if attrs:
+        fields["attributes"] = attrs
+    sink(fields)
+
+
+class _WorkerSpan(_Annotated):
+    """One timed segment of a task: monotonic stamps, the annotation beside
+    it, one record through :func:`_emit_span` on exit — ``ERROR`` when the
+    block raised (the exception propagates)."""
+
+    __slots__ = ("_sink", "_name", "_trace", "_t0")
+
+    def __init__(self, sink, name: str, trace) -> None:
+        super().__init__(name)
+        self._sink, self._name, self._trace = sink, name, trace
+
+    def __enter__(self) -> "_WorkerSpan":
+        self._t0 = time.monotonic()
+        super().__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        super().__exit__(exc_type, exc, tb)
+        _emit_span(
+            self._sink, self._name, self._trace, self._t0,
+            status="OK" if exc is None else "ERROR",
+        )
+        return False
+
+
+def _process_start() -> float:
+    """When this process started, as a ``time.monotonic()`` stamp: the
+    kernel's own record of it (a forked task's is its fork), or this
+    module's load where ``/proc`` cannot say."""
+    try:
+        with open("/proc/self/stat", encoding="utf-8") as f:
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK")
+        return time.monotonic() - max(0.0, age)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return _T_LOADED
+
+
+def _jit_totals() -> dict | None:
+    """This process's own account of its compiles (``obs.jitstats``), where
+    the program loaded it; a plain-Python task never did, and nothing is
+    imported to ask."""
+    jitstats = sys.modules.get("covalent_tpu_plugin.obs.jitstats")
+    if jitstats is None:
+        return None
+    try:
+        return jitstats.totals()
+    except Exception:  # noqa: BLE001 - observability never fails the task
+        return None
+
+
+def _trace_trailer(spans: list) -> bytes:
+    """What follows the ``(result, exception)`` pickle in a launch-mode
+    result file: one JSON line with the worker's spans and its compile
+    counters.  ``pickle.load`` stops at the pickle's end, so every reader
+    of the pair is unaffected; ``utils.serialize.load_result_and_trailer``
+    reads both."""
+    trailer: dict = {"spans": spans}
+    jit = _jit_totals()
+    if jit is not None:
+        trailer["jit"] = jit
+    try:
+        return b"\n" + json.dumps(trailer, default=repr).encode() + b"\n"
+    except (TypeError, ValueError):
+        return b""
 
 
 def live_backend() -> str:
@@ -631,8 +770,21 @@ def _apply_spec_env(spec: dict) -> None:
 
 
 def run_task(spec: dict) -> int:
-    """Execute one staged task described by ``spec``.  Returns the exit code."""
+    """Execute one staged task described by ``spec``.  Returns the exit code.
+
+    The task's own waterfall goes home in the result file's trailer: five
+    spans on this process's monotonic clock, under the dispatcher's
+    ``executor.run`` (``spec["trace"]``).  ``worker.boot``: process start
+    to the first read of the staged function (interpreter, env contract,
+    pip deps, imports); ``worker.load``: digest check, the distributed
+    bootstrap where there is one, unpickle; ``worker.execute``: the user's
+    function and only it (``ERROR`` when it raised); ``worker.to_host``;
+    ``worker.store``: the result pickle's write (the rename that publishes
+    it follows: the trailer has to be inside the file it publishes).
+    """
     result_file = spec["result_file"]
+    trace = spec.get("trace")
+    spans: list = []
 
     pid_file = spec.get("pid_file")
     if pid_file:
@@ -724,6 +876,8 @@ def run_task(spec: dict) -> int:
             _fallback_result(result_file, import_error)
         return 1
 
+    t_load = time.monotonic()
+    _emit_span(spans.append, "worker.boot", trace, _process_start(), t_load)
     expected_digest = spec.get("function_digest")
     if expected_digest:
         # The function file is a content-addressed (CAS) artifact: verify
@@ -762,8 +916,10 @@ def run_task(spec: dict) -> int:
             process_id=process_id,
         )
 
-    with open(spec["function_file"], "rb") as f:
-        fn, args, kwargs = pickle.load(f)
+    with _Annotated("worker.load"):
+        with open(spec["function_file"], "rb") as f:
+            fn, args, kwargs = pickle.load(f)
+    _emit_span(spans.append, "worker.load", trace, t_load)
 
     # The SIGTERM preemption contract (notice event + final cooperative
     # snapshot + die with the signal) — installed after EVERY import that
@@ -793,8 +949,10 @@ def run_task(spec: dict) -> int:
         if workdir:
             os.makedirs(workdir, exist_ok=True)
             os.chdir(workdir)
-        result = fn(*args, **kwargs)
-        result = _to_host(result)
+        with _WorkerSpan(spans.append, "worker.execute", trace):
+            result = fn(*args, **kwargs)
+        with _WorkerSpan(spans.append, "worker.to_host", trace):
+            result = _to_host(result)
     except Exception as task_error:  # noqa: BLE001 - transported to dispatcher
         exception = task_error
     finally:
@@ -811,8 +969,11 @@ def run_task(spec: dict) -> int:
     # done-marker the control plane can watch for all-workers-finished.
     if process_id == 0:
         tmp = result_file + ".tmp"
-        with open(tmp, "wb") as f:
-            pickle.dump((result, exception), f)
+        with _WorkerSpan(spans.append, "worker.store", trace):
+            with open(tmp, "wb") as f:
+                pickle.dump((result, exception), f)
+        with open(tmp, "ab") as f:
+            f.write(_trace_trailer(spans))
         os.replace(tmp, result_file)
     else:
         done = f"{result_file}.done.{process_id}"
@@ -1417,8 +1578,9 @@ def _pickle_rpc_result(result, exception) -> bytes:
         )
 
 
-def _emit_rpc_result(task_id: str, result, exception, command: dict) -> None:
-    """Stream one invocation's result, inline or staged by size.
+def _emit_rpc_result(task_id: str, data: bytes, ok: bool, command: dict) -> None:
+    """Stream one invocation's result pickle ``data``, inline or staged by
+    size.
 
     The dispatcher's ``rpc_inline_args_max`` policy applies symmetrically:
     a result pickle at or below ``result_max_inline`` rides the channel
@@ -1432,7 +1594,6 @@ def _emit_rpc_result(task_id: str, result, exception, command: dict) -> None:
     """
     import base64
 
-    data = _pickle_rpc_result(result, exception)
     result_path = command.get("result_path")
     try:
         max_inline = int(command.get("result_max_inline"))
@@ -1451,7 +1612,7 @@ def _emit_rpc_result(task_id: str, result, exception, command: dict) -> None:
         else:
             _emit({
                 "event": "result", "id": task_id,
-                "ok": exception is None,
+                "ok": ok,
                 "data_path": result_path,
                 "data_digest": hashlib.sha256(data).hexdigest(),
                 "bytes": len(data),
@@ -1463,13 +1624,13 @@ def _emit_rpc_result(task_id: str, result, exception, command: dict) -> None:
         _emit_frame(
             _VERB_RESULT,
             {"event": "result", "id": task_id,
-             "ok": exception is None, "_body": "data_bytes"},
+             "ok": ok, "_body": "data_bytes"},
             data,
         )
         return
     _emit({
         "event": "result", "id": task_id,
-        "ok": exception is None,
+        "ok": ok,
         "data": base64.b64encode(data).decode("ascii"),
     })
 
@@ -1520,18 +1681,30 @@ def _run_rpc_task(command: dict, fn) -> None:
     The launch-mode contract, minus the process: task_started /
     heartbeats / task_finished events (trace-stamped from the spec), user
     exceptions transported — never raised — and device arrays materialised
-    to host before pickling.
+    to host before pickling.  The same five ``worker.*`` spans as
+    ``run_task`` go home in one ``worker.trace`` record over the
+    side-band, ahead of the result (the dispatcher closes the electron's
+    trace on it): ``worker.boot`` is the env contract, ``worker.load`` the
+    arguments' decode (the function was loaded at registration),
+    ``worker.store`` the result's pickling; with them, this runtime's
+    running compile totals.
     """
+    t_boot = time.monotonic()
     task_id = command.get("id") or ""
     spec = dict(command.get("spec") or {})
     spec.setdefault("operation_id", task_id)
+    trace = spec.get("trace")
+    spans: list = []
     result, exception = None, None
     try:
         # Same env contract as a launch-mode harness child (os.environ +
         # PYTHONPATH sys.path mirror + jax platform pin): task_env must
         # mean the same thing whichever runtime executes the function.
         _apply_spec_env(spec)
+        t_load = time.monotonic()
+        _emit_span(spans.append, "worker.boot", trace, t_boot, t_load)
         args, kwargs = _decode_rpc_args(command)
+        _emit_span(spans.append, "worker.load", trace, t_load)
     except BaseException as err:  # noqa: BLE001 - bad env/torn args fail the task
         args, kwargs, exception = (), {}, err
     _emit_rpc_event(spec, task_id, "worker.task_started", process_id=0)
@@ -1539,14 +1712,23 @@ def _run_rpc_task(command: dict, fn) -> None:
     try:
         if exception is None:
             try:
-                result = fn(*args, **kwargs)
-                result = _to_host(result)
+                with _WorkerSpan(spans.append, "worker.execute", trace):
+                    result = fn(*args, **kwargs)
+                with _WorkerSpan(spans.append, "worker.to_host", trace):
+                    result = _to_host(result)
             except Exception as task_error:  # noqa: BLE001 - transported
                 exception = task_error
     finally:
         if heartbeat_stop is not None:
             heartbeat_stop.set()
-    _emit_rpc_result(task_id, result, exception, command)
+    with _WorkerSpan(spans.append, "worker.store", trace):
+        data = _pickle_rpc_result(result, exception)
+    jit = _jit_totals()
+    _emit_rpc_event(
+        spec, task_id, "worker.trace", spans=spans,
+        **({"jit": jit} if jit is not None else {}),
+    )
+    _emit_rpc_result(task_id, data, exception is None, command)
     _emit_rpc_event(
         spec, task_id, "worker.task_finished", process_id=0,
         ok=exception is None,
@@ -1745,7 +1927,14 @@ def _profile_start(command: dict) -> None:
             import jax
 
             os.makedirs(trace_dir, exist_ok=True)
-            jax.profiler.start_trace(trace_dir)
+            # Python's call tracer off: on a busy worker it is most of the
+            # trace and made ``stop_trace`` outlast the dispatcher's wait.
+            # The host's account is the program's own annotations
+            # (``serve.loop.*``, ``serve.engine.*``, ``worker.*``); the
+            # device planes and TraceMe events stay.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception as err:  # noqa: BLE001 - any profiler failure
             _emit({"event": "profile_error", "id": profile_id,
                    "code": "unavailable", "message": repr(err)})
@@ -2247,38 +2436,11 @@ class _ServeSession:
             "serve.reject", rid=rid, code=code, message=message
         )
 
-    def _emit_span(
-        self, name: str, trace, t0: float, t1: float | None = None,
-        **attrs,
-    ) -> None:
-        """One worker-side span record over the telemetry side-band.
-
-        ``trace`` is the per-request ``context_of`` carrier off the
-        ``serve_request``/``serve_prefill`` command header; without one
-        (an old dispatcher, a malformed carrier) the span is dropped —
-        a worker must never mint orphan traces the store can't finalize.
-        The dispatcher re-emits the record with these ids preserved
-        (``SessionSupervisor._on_remote_span``), which is what puts the
-        worker's queue/admission/decode time inside the request's own
-        waterfall.  ``t0``/``t1`` are monotonic stamps; the wall-clock
-        ``start_ts`` is reconstructed here so the two clock domains
-        never mix on the wire.
-        """
-        if not isinstance(trace, dict) or not trace.get("trace_id"):
-            return
-        t1 = time.monotonic() if t1 is None else t1
-        parent = trace.get("span_id")
-        fields = {
-            "name": name,
-            "trace_id": str(trace["trace_id"]),
-            "parent_id": str(parent) if parent else None,
-            "span_id": os.urandom(8).hex(),
-            "start_ts": round(time.time() - (time.monotonic() - t0), 6),
-            "duration_s": round(max(0.0, t1 - t0), 6),
-            "status": "OK",
-        }
-        if attrs:
-            fields["attributes"] = attrs
+    def _send_span(self, fields: dict) -> None:
+        """:func:`_emit_span`'s sink for this session: the record rides the
+        telemetry side-band, and ``SessionSupervisor`` re-emits it with its
+        ids kept, which is what puts the worker's queue/admission/decode
+        time inside the request's own waterfall."""
         self._emit_serve("span", **fields)
 
     def _emit_kv(
@@ -2343,9 +2505,9 @@ class _ServeSession:
                 self._emit_kv(rid, code="prefill_failed", message=repr(err))
                 continue
             self.prefills += 1
-            self._emit_span(
-                "serve.worker.prefill", trace, t_prefill,
-                rid=rid, kv_bytes=len(data),
+            _emit_span(
+                self._send_span, "serve.worker.prefill", trace,
+                t_prefill, rid=rid, kv_bytes=len(data),
             )
             self._emit_kv(rid, bytes(data))
 
@@ -2500,6 +2662,11 @@ class _ServeSession:
             extra["kv_fallbacks"] = self.kv_fallbacks
         if self.prefills:
             extra["prefills"] = self.prefills
+        jit = _jit_totals()
+        if jit is not None:
+            # Running totals of this runtime's compiles; the dispatcher
+            # adds their growth into covalent_tpu_worker_jit_seconds_total.
+            extra["jit"] = jit
         self._emit_serve(
             "serve.stats",
             slots=self.slots,
@@ -2605,8 +2772,8 @@ class _ServeSession:
             params = dict(command.get("params") or {})
             trace = command.get("trace")
             t_admit_start = time.monotonic()
-            self._emit_span(
-                "serve.worker.queue_wait", trace,
+            _emit_span(
+                self._send_span, "serve.worker.queue_wait", trace,
                 command["_enqueued"], t_admit_start, rid=rid,
             )
             admitted = False
@@ -2639,9 +2806,9 @@ class _ServeSession:
                     self._emit_reject(rid, "engine_error", repr(err))
                     continue
             t_admitted = time.monotonic()
-            self._emit_span(
-                "serve.worker.admission", trace, t_admit_start, t_admitted,
-                rid=rid, kv=admitted,
+            _emit_span(
+                self._send_span, "serve.worker.admission", trace,
+                t_admit_start, t_admitted, rid=rid, kv=admitted,
             )
             self.running[rid] = {
                 "deadline": (
@@ -2684,8 +2851,8 @@ class _ServeSession:
                     self._cancelled_pending.add(rid)
                 continue
             self._cancel_lane(rid)
-            self._emit_span(
-                "serve.worker.decode", state.get("trace"),
+            _emit_span(
+                self._send_span, "serve.worker.decode", state.get("trace"),
                 state["t_admit"], rid=rid,
                 tokens=state["emitted"], error="cancelled",
             )
@@ -2806,7 +2973,8 @@ class _ServeSession:
         spec = bool(getattr(self._engine, "spec_active", False))
         t_step = time.monotonic()
         try:
-            events = self._engine.step() or []
+            with _Annotated("serve.loop.step"):
+                events = self._engine.step() or []
         except BaseException as err:  # noqa: BLE001 - engine crash fails all
             for rid in list(self.running):
                 self._emit_reject(rid, "engine_error", repr(err))
@@ -2814,6 +2982,11 @@ class _ServeSession:
                 self.running.pop(rid, None)
             return
         step_s = time.monotonic() - t_step
+        with _Annotated("serve.loop.emit"):
+            self._emit_chunk(events, step_s, spec)
+
+    def _emit_chunk(self, events: list, step_s: float, spec: bool) -> None:
+        """Stream one decode chunk's fresh tokens, then enforce deadlines."""
         chunk_tokens = sum(
             len(e.get("tokens") or ()) for e in events
         ) if spec else 0
@@ -2845,8 +3018,8 @@ class _ServeSession:
                 # finalizes the trace on ``done``, and the side-band is
                 # ordered — emitting after would strand the decode span
                 # as a straggler.
-                self._emit_span(
-                    "serve.worker.decode", state.get("trace"),
+                _emit_span(
+                    self._send_span, "serve.worker.decode", state.get("trace"),
                     state["t_admit"], rid=rid,
                     tokens=state["emitted"] + len(tokens),
                 )
@@ -2874,8 +3047,8 @@ class _ServeSession:
         for rid, state in list(self.running.items()):
             if state["deadline"] is not None and now >= state["deadline"]:
                 self._cancel_lane(rid)
-                self._emit_span(
-                    "serve.worker.decode", state.get("trace"),
+                _emit_span(
+                    self._send_span, "serve.worker.decode", state.get("trace"),
                     state["t_admit"], rid=rid,
                     tokens=state["emitted"], error="deadline_exceeded",
                 )
@@ -2901,10 +3074,14 @@ class _ServeSession:
             while not (self._closed.is_set()
                        and not self.running
                        and self.queue.empty()):
-                self._drain_cancels()
-                self._pump_attach()
-                self._pump_prefill()
-                self._admit_waiting()
+                # One annotation per phase of a turn: a profiler capture's
+                # host plane then says what the loop did in a device gap.
+                with _Annotated("serve.loop.drain"):
+                    self._drain_cancels()
+                    self._pump_attach()
+                    self._pump_prefill()
+                with _Annotated("serve.loop.admit"):
+                    self._admit_waiting()
                 if self.running:
                     self._pump_engine()
                 else:

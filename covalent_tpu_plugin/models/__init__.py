@@ -8,6 +8,7 @@ pretrain config, both written mesh-first so the same code spans one chip to
 a pod.
 """
 
+from ..obs.jitstats import watch_jit
 from .beam import beam_search
 from .data import synthetic_lm_batch, synthetic_lm_batches
 from .decode import generate, inference_params, init_cache
@@ -38,6 +39,11 @@ from .train import (
     make_sharded_train_state,
     make_train_step,
 )
+
+# Every module above imports jax: from here on the process keeps its own
+# account of its compiles (seconds by phase, persistent-cache hits and
+# misses) in the metrics registry.
+watch_jit()
 
 __all__ = [
     "MLP",

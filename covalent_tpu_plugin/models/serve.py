@@ -1984,25 +1984,34 @@ class ContinuousEngine:
     def _step_local(self) -> list[dict]:
         """One sync chunk on THIS lane group only (plain or speculative
         decode, whichever the session resolved to at construction)."""
-        self._flush_admissions()
+        # Each phase is annotated for the profiler: a capture's host plane
+        # then says what the engine did in each device gap.
+        if self._pending or self._pending_kv:
+            with jax.profiler.TraceAnnotation("serve.engine.admit_wave"):
+                self._flush_admissions()
         if not self._rid_slot:
             return []
-        if self._spec_run is not None:
-            (self._state, self._draft_caches, proposed, accepted) = (
-                self._spec_run(
-                    self._params, self._draft_params, self._state,
-                    self._draft_caches,
+        with jax.profiler.TraceAnnotation("serve.engine.run_steps"):
+            # The dispatch alone: the call returns before the device ends.
+            if self._spec_run is not None:
+                (self._state, self._draft_caches, proposed, accepted) = (
+                    self._spec_run(
+                        self._params, self._draft_params, self._state,
+                        self._draft_caches,
+                    )
                 )
-            )
-            self.stats["spec_rounds"] += self._spec_rounds
-            self.stats["spec_proposed"] += int(proposed)
-            self.stats["spec_accepted"] += int(accepted)
-        else:
-            self._state = self._run_steps(self._params, self._state)
-        buffer_h = np.asarray(self._state[1])
-        plen_h = np.asarray(self._state[3])
-        n_gen_h = np.asarray(self._state[5])
-        done_h = np.asarray(self._state[6])
+            else:
+                self._state = self._run_steps(self._params, self._state)
+        with jax.profiler.TraceAnnotation("serve.engine.harvest"):
+            # The blocking reads: the host waits here for the chunk.
+            if self._spec_run is not None:
+                self.stats["spec_rounds"] += self._spec_rounds
+                self.stats["spec_proposed"] += int(proposed)
+                self.stats["spec_accepted"] += int(accepted)
+            buffer_h = np.asarray(self._state[1])
+            plen_h = np.asarray(self._state[3])
+            n_gen_h = np.asarray(self._state[5])
+            done_h = np.asarray(self._state[6])
         events: list[dict] = []
         for slot in range(self.slots):
             rid = self._slot_rid[slot]
@@ -2378,14 +2387,15 @@ class ContinuousEngine:
             ] + [
                 (p[0], p[1], p[5]) for p in picked_kv
             ]
-            for slot, tokens, aslot in candidates:
-                self._insert_prefix(
-                    tokens,
-                    lambda slot=slot: jax.tree_util.tree_map(
-                        lambda c: c[slot], state[0]
-                    ),
-                    aslot=aslot,
-                )
+            with jax.profiler.TraceAnnotation("serve.engine.insert_prefix"):
+                for slot, tokens, aslot in candidates:
+                    self._insert_prefix(
+                        tokens,
+                        lambda slot=slot: jax.tree_util.tree_map(
+                            lambda c: c[slot], state[0]
+                        ),
+                        aslot=aslot,
+                    )
 
 
 def lm_engine_factory(model: TransformerLM, params: Any, **engine_kwargs):

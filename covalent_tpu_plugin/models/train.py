@@ -98,9 +98,12 @@ def make_train_step(
     dtype).
     """
     def grads_of(params, apply_fn, batch):
-        return jax.value_and_grad(
-            lambda p: loss_fn(p, apply_fn, batch)
-        )(params)
+        # Scoped so a device trace can tell the loss's forward and backward
+        # from the optimizer's update (flax already scopes the modules).
+        with jax.named_scope("loss"):
+            return jax.value_and_grad(
+                lambda p: loss_fn(p, apply_fn, batch)
+            )(params)
 
     def step(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
         with nn.logical_axis_rules(list(rules)):
@@ -139,7 +142,8 @@ def make_train_step(
                     lambda g, p: (g * scale).astype(p.dtype),
                     grads, state.params,
                 )
-            new_state = state.apply_gradients(grads=grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads=grads)
             metrics = {
                 "loss": loss,
                 "grad_norm": optax.global_norm(grads),

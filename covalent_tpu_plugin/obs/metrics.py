@@ -1,8 +1,8 @@
 """Process-wide metrics registry: counters, gauges, histograms.
 
 The reference plugin exposes no metrics at all (SURVEY §5); the only number
-the TPU build captured before this subsystem was a per-run ``StageTimer``
-dict that died with the executor instance.  This module is the durable sink:
+the TPU build captured before this subsystem was a per-run dict of stage
+timings that died with the executor instance.  This module is the durable sink:
 every instrumented component (executor lifecycle, workflow runner, agent
 RPCs, transport pool) records into one process-wide registry that can be
 read back as a JSON snapshot (``Registry.snapshot``) or Prometheus text
@@ -125,6 +125,12 @@ class _Metric:
         key = tuple(_fmt_label_value(labels[n]) for n in self.label_names)
         with self._lock:
             self._children.pop(key, None)
+
+    def values(self) -> dict[tuple[str, ...], float]:
+        """Label values (in ``label_names`` order) -> current value, for
+        counters and gauges."""
+        with self._lock:
+            return {key: child.value for key, child in self._children.items()}
 
     def _series(self) -> list[tuple[dict[str, str], Any]]:
         with self._lock:

@@ -1,11 +1,9 @@
-"""Span-based tracing for the dispatch control plane.
+"""Span-based tracing for the dispatch control plane and its workers.
 
-Subsumes the old ``utils.timing.StageTimer`` (now a shim over this module):
-where the timer recorded a flat ``{stage: seconds}`` dict that died with the
-executor instance, a :class:`Span` carries trace/span/parent ids, status,
-and attributes, propagates through ``contextvars`` (so asyncio tasks nest
-correctly without threading a handle through every call), and on close
-fans out to both sinks:
+A :class:`Span` carries trace/span/parent ids, status, and attributes,
+propagates through ``contextvars`` (so asyncio tasks nest correctly without
+threading a handle through every call), and on close fans out to both
+sinks:
 
 * the structured event stream (``obs.events``) as a ``span`` event — the
   JSONL file doubles as a flat trace export with consistent ids;
@@ -19,18 +17,28 @@ Usage::
     with span("executor.run", operation_id=op) as root:
         with span("executor.connect"):
             ...
-    root.stage_durations   # {"executor.connect": 0.012}
+    root.stage_durations   # {"connect": 0.012}
 
 Parent spans accumulate each direct child's duration under the child's
-*leaf* name (the part after the last dot), which is what lets the
-``StageTimer`` compatibility summary (total/overhead accounting) fall out
-of the trace for free.
+*leaf* name (the part after the last dot): ``stage_durations`` and
+``summary()`` are the executor's ``last_timings``.
+
+Workers time their own segments on their own monotonic clock (the
+harness is stdlib-only and has no sink of ours) and ship the records
+home; :func:`record_remote_span` re-emits them here with the worker's
+ids kept, so worker time lands inside the dispatch's own waterfall.
+
+Where ``jax`` is already imported in the process, an open span also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+capture's host plane shows the program's spans on the device's clock.
+jax is never imported for it: the dispatcher stays off JAX.
 """
 
 from __future__ import annotations
 
 import contextvars
 import os
+import sys
 import time
 from typing import Any
 
@@ -39,7 +47,7 @@ from .metrics import REGISTRY
 
 __all__ = [
     "Span", "span", "current_span", "SPAN_HISTOGRAM",
-    "context_of", "extract_context", "record_span",
+    "context_of", "extract_context", "record_span", "record_remote_span",
 ]
 
 #: Name of the histogram every finished span observes into.
@@ -52,6 +60,15 @@ _current: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
 
 def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``, or ``None`` in a
+    process that has not imported jax (it is never imported for this).
+    With no profiler session open, entering one costs a flag test."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    return None if profiler is None else profiler.TraceAnnotation(name)
 
 
 def current_span() -> "Span | None":
@@ -109,6 +126,7 @@ class Span:
         "name", "trace_id", "span_id", "parent_id", "attributes",
         "status", "start_ts", "duration_s", "stage_durations",
         "_t0", "_token", "_parent", "_emit", "_activate", "_context",
+        "_annotation",
     )
 
     def __init__(
@@ -121,8 +139,8 @@ class Span:
         context: tuple[str, str] | None = None,
     ) -> None:
         """``parent`` overrides contextvar lookup; ``activate=False`` keeps
-        the span out of the ambient context (long-lived roots that are never
-        exited, like the StageTimer shim's, must not capture it).
+        the span out of the ambient context (for a long-lived root that is
+        never exited, which must not capture it).
         ``context`` — a ``(trace_id, parent_span_id)`` pair from
         :func:`extract_context` — adopts a *remote* parent when no local
         one applies, joining a trace that started in another process."""
@@ -134,8 +152,7 @@ class Span:
         self.span_id = _new_id(8)
         self.start_ts: float | None = None
         self.duration_s: float | None = None
-        #: leaf-name -> accumulated seconds of *direct* child spans; the
-        #: StageTimer-compat view of this span's trace subtree.
+        #: leaf-name -> accumulated seconds of *direct* child spans.
         self.stage_durations: dict[str, float] = {}
         self._t0: float | None = None
         self._token = None
@@ -143,6 +160,7 @@ class Span:
         self._emit = emit
         self._activate = activate
         self._context = context
+        self._annotation = None
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -161,6 +179,9 @@ class Span:
         self._t0 = time.perf_counter()
         if self._activate:
             self._token = _current.set(self)
+        self._annotation = _annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -186,6 +207,9 @@ class Span:
         if self._t0 is None or self.duration_s is not None:
             return  # never entered, or already ended
         self.duration_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._token is not None:
             try:
                 _current.reset(self._token)
@@ -220,7 +244,7 @@ class Span:
                 **({"attributes": self.attributes} if self.attributes else {}),
             )
 
-    # -- StageTimer-compat accounting -------------------------------------
+    # -- stage accounting (``TPUExecutor.last_timings``) -------------------
 
     def total(self) -> float:
         if self.duration_s is not None:
@@ -285,6 +309,33 @@ def record_span(
         **({"attributes": dict(attributes)} if attributes else {}),
     )
     return span_id
+
+
+def record_remote_span(data: Any) -> None:
+    """Re-emit one span a worker recorded, with the worker's ids kept.
+
+    ``data`` is the record the harness's recorder builds (``name``,
+    ``trace_id``, ``parent_id``, ``span_id``, ``start_ts``, ``duration_s``,
+    ``status``, ``attributes``), off a serving session's side-band, an RPC
+    invocation's, or a launch-mode result's trailer.  Keeping the ids is
+    what puts worker time inside the request's or electron's own waterfall
+    rather than in a disconnected worker-local trace.  Never raises: the
+    record crossed a process boundary, and observability is never fatal.
+    """
+    try:
+        attributes = data.get("attributes")
+        record_span(
+            str(data.get("name") or "worker"),
+            trace_id=data.get("trace_id") or None,
+            parent_id=data.get("parent_id") or None,
+            span_id=data.get("span_id") or None,
+            start_ts=data.get("start_ts"),
+            duration_s=float(data.get("duration_s") or 0.0),
+            status=str(data.get("status") or "OK"),
+            attributes=attributes if isinstance(attributes, dict) else None,
+        )
+    except Exception:  # noqa: BLE001 - records come off the wire
+        pass
 
 
 def span(name: str, **attributes: Any) -> Span:

@@ -574,6 +574,7 @@ def _flash_forward(
         )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[qo_spec, kv_spec, kv_spec, qpos_spec, kpos_spec],
         out_specs=[qo_spec, lse_spec],
@@ -858,6 +859,7 @@ def _flash_backward(
                 _flash_bwd_dkdv_kernel, causal=causal, scale=scale,
                 window=window, sinks=sinks, band=band, kt_offset=kt_offset,
             ),
+            name="flash_bwd_dkdv",
             grid=(batch, kv_heads, kt_n, group, n_inner),
             in_specs=[qo_spec_q, kv_spec_in, kv_spec_in, qo_spec_q,
                       stat_spec_q, stat_spec_q, qpos_spec_q, kpos_spec_k],
@@ -929,6 +931,7 @@ def _flash_backward(
             _flash_bwd_dq_kernel, causal=causal, scale=scale, window=window,
             sinks=sinks, band=band_q,
         ),
+        name="flash_bwd_dq",
         grid=(batch, heads, qt_full, n_inner_kt),
         in_specs=[qo_spec_i, kv_spec_j, kv_spec_j, qo_spec_i, stat_spec_i,
                   stat_spec_i, qpos_spec_i, kpos_spec_j],

@@ -12,6 +12,7 @@ collectives — never hand-written NCCL-style calls.
 # level (seconds), which the dispatcher control plane — which imports this
 # package only for `coordinator_spec` — must not pay.
 import importlib
+import sys
 
 _EXPORTS = {
     "psum": ".collectives",
@@ -42,6 +43,13 @@ _EXPORTS = {
 def __getattr__(name):
     if name in _EXPORTS:
         module = importlib.import_module(_EXPORTS[name], __name__)
+        if "jax" in sys.modules:
+            # That module imported jax (``.distributed`` alone does not):
+            # from here on the process keeps its own account of its
+            # compiles (obs.jitstats).
+            from ..obs.jitstats import watch_jit
+
+            watch_jit()
         value = getattr(module, name)
         globals()[name] = value  # cache: subsequent lookups skip __getattr__
         return value

@@ -39,7 +39,8 @@ from ..cache import bytes_digest, cas_path
 from ..fleet import journal as journal_mod
 from ..fleet.health import HEALTH
 from ..obs import events as obs_events
-from ..obs.trace import Span, context_of, record_span
+from ..obs import jitstats
+from ..obs.trace import Span, context_of, record_remote_span, record_span
 from ..resilience import FaultClass, RetryPolicy, classify_error
 from ..transport.base import TransportError
 from ..utils.log import app_log
@@ -442,6 +443,9 @@ class SessionSupervisor:
         ).strip().lower() not in ("0", "off", "false", "no")
         self.opened_at = 0.0
         self.stats: dict[str, Any] = {}
+        #: compile totals already added (``serve.stats`` carries the
+        #: runtime's running totals).
+        self._jit_seen: dict = {}
         self.address = ""
         self._payload: bytes | None = None
         self._digest = ""
@@ -1277,35 +1281,9 @@ class SessionSupervisor:
         elif kind == "serve.preempt":
             self._on_preempt(data)
         elif kind == "span":
-            self._on_remote_span(data)
-
-    def _on_remote_span(self, data: dict) -> None:
-        """One worker-recorded span off the telemetry side-band.
-
-        The worker has no event sink of ours, so it times its segments
-        (queue wait, admission, decode, prefill) locally and ships them
-        as ``span`` telemetry records; re-emitting through
-        :func:`record_span` with the ORIGINAL ids preserved is what
-        makes worker time appear inside the request's own waterfall
-        rather than in a disconnected worker-local trace.
-        """
-        try:
-            record_span(
-                str(data.get("name") or "serve.worker"),
-                trace_id=data.get("trace_id") or None,
-                parent_id=data.get("parent_id") or None,
-                span_id=data.get("span_id") or None,
-                start_ts=data.get("start_ts"),
-                duration_s=float(data.get("duration_s") or 0.0),
-                status=str(data.get("status") or "OK"),
-                attributes=(
-                    data.get("attributes")
-                    if isinstance(data.get("attributes"), dict)
-                    else None
-                ),
-            )
-        except Exception:  # noqa: BLE001 - observability never fatal
-            pass
+            # The worker has no event sink of ours: it times its segments
+            # (queue wait, admission, decode, prefill) itself and ships them.
+            record_remote_span(data)
 
     def _on_preempt(self, data: dict) -> None:
         """The worker hosting this session announced a preemption notice
@@ -1471,6 +1449,10 @@ class SessionSupervisor:
         ))
 
     def _on_stats(self, data: dict) -> None:
+        # The runtime's running compile totals: only their growth is added.
+        jitstats.absorb_worker(
+            data.get("jit"), self._jit_seen, source=data.get("pid")
+        )
         self.stats = {
             k: v for k, v in data.items()
             if k in (

@@ -83,7 +83,8 @@ from .obs.opsserver import (
     unregister_profile_provider,
     unregister_status_provider,
 )
-from .obs.trace import Span, context_of
+from .obs import jitstats
+from .obs.trace import Span, context_of, record_remote_span
 from .parallel.distributed import coordinator_spec
 from .serving.metrics import SERVE_WORKER_SLOTS
 from .resilience import (
@@ -110,7 +111,7 @@ from .transport import codec as codec_mod
 from .transport.chaos import plan_from_spec
 from .utils.config import get_config, update_config
 from .utils.log import app_log
-from .utils.serialize import dump_task, load_result
+from .utils.serialize import dump_task, load_result_and_trailer
 
 # Plugin identity — the hook Covalent's loader keys on (pattern: ssh.py:34).
 EXECUTOR_PLUGIN_NAME = "TPUExecutor"
@@ -775,6 +776,13 @@ class TPUExecutor(RemoteExecutor):
         #: attempt operation ids whose worker announced a preemption
         #: notice (worker.preempt_notice): relabels the coming death.
         self._preempt_notices: set[str] = set()
+        #: attempt operation id -> seconds its worker spent in the user's
+        #: function (the ``worker.execute`` span it sent home), read once
+        #: by the attempt's epilogue.
+        self._worker_execute_s: dict[str, float] = {}
+        #: worker -> compile totals already added for its resident
+        #: runtime, which reports running totals with every invocation.
+        self._worker_jit_seen: dict[str, dict] = {}
         #: (lineage, step, digest) triples already counted/mirrored — the
         #: telemetry side-band re-tails from offset 0 after reconnects.
         self._ckpt_seen: set[tuple[str, int, str]] = set()
@@ -2146,6 +2154,13 @@ class TPUExecutor(RemoteExecutor):
         if data.get("type") == "worker.heartbeat":
             self._record_heartbeat(operation_id, worker, data)
             return
+        if data.get("type") == "worker.trace":
+            # An RPC invocation's half of the trace, ahead of its result.
+            self._absorb_worker_trace(
+                operation_id, data,
+                seen=self._worker_jit_seen.setdefault(worker, {}),
+            )
+            return
         if data.get("type") == "worker.checkpoint_saved":
             # Elastic gangs: learn the lineage's newest checkpoint and
             # mirror the bundle locally while the worker is still alive —
@@ -2166,6 +2181,39 @@ class TPUExecutor(RemoteExecutor):
             backhaul=True,
             **({"worker_ts": worker_ts} if worker_ts else {}),
             **body,
+        )
+
+    def _absorb_worker_trace(
+        self, operation_id: str, record: dict | None,
+        seen: dict | None = None,
+    ) -> None:
+        """The worker's half of one attempt's trace: a launch-mode result
+        file's trailer, or an RPC invocation's ``worker.trace`` record.
+
+        Each ``worker.*`` span is re-emitted with the worker's ids kept
+        (they name ``executor.run`` as parent), ``worker.execute``'s
+        seconds are kept for the epilogue's ``wall_overhead``, and the
+        worker's compile counters are added into this process's
+        ``covalent_tpu_worker_*`` series (``seen``: a resident runtime's
+        running totals).  Never raises: the record crossed a process
+        boundary.
+        """
+        if not isinstance(record, dict):
+            return
+        spans = record.get("spans")
+        for span in spans if isinstance(spans, list) else ():
+            if not isinstance(span, dict):
+                continue
+            record_remote_span(span)
+            if span.get("name") == "worker.execute":
+                try:
+                    self._worker_execute_s[operation_id] = float(
+                        span.get("duration_s") or 0.0
+                    )
+                except (TypeError, ValueError):
+                    pass
+        jitstats.absorb_worker(
+            record.get("jit"), seen, source=record.get("pid")
         )
 
     # ------------------------------------------------------------------ #
@@ -2983,6 +3031,9 @@ class TPUExecutor(RemoteExecutor):
         ``key`` is the worker's pool key (the identity codecs were
         negotiated under — the *configured* address, which can differ
         from ``conn.address``); callers without one get the raw path.
+
+        The same file's trailer holds the worker's half of the trace
+        (``worker.*`` spans, compile counters): no round trip of its own.
         """
         codec = (
             self._codec_for(key, conn)
@@ -2993,7 +3044,9 @@ class TPUExecutor(RemoteExecutor):
             conn, staged.remote_result_file, staged.local_result_file,
             codec=codec, python_path=self.python_path,
         )
-        return load_result(staged.local_result_file)
+        pair, trailer = load_result_and_trailer(staged.local_result_file)
+        self._absorb_worker_trace(staged.operation_id, trailer)
+        return pair
 
     async def _remote_log_tail(self, conn: Transport, staged: StagedTask) -> str:
         """Worker logs are the #1 debugging surface on pods (SURVEY §5)."""
@@ -4219,18 +4272,24 @@ class TPUExecutor(RemoteExecutor):
         # Stage spans SUM concurrent work (pipelined upload/submit run
         # per worker, staging overlaps the dial), so the wall-clock
         # overhead the caller actually waited is reported separately:
-        # elapsed time minus the task's own runtime.  Profile capture
-        # (trace stop + tar + fetch, potentially seconds) observes the
-        # dispatch rather than being part of it — charging it as
-        # overhead would burn the dispatch_overhead SLO and bench
-        # budgets on profiled-but-healthy traffic.
+        # elapsed time minus the task's own runtime, which is the
+        # worker's ``worker.execute`` span (the user's function and only
+        # it), each term on one clock.  ``executor.execute`` would hide
+        # the worker's own overhead (boot, imports, unpickle, the result's
+        # write, the wait for the next poll) inside "the task's runtime";
+        # it stands in only where no worker span came home (a worker that
+        # died, a local fallback).  Profile capture (trace stop + tar +
+        # fetch, potentially seconds) observes the dispatch rather than
+        # being part of it — charging it as overhead would burn the
+        # dispatch_overhead SLO and bench budgets on profiled-but-healthy
+        # traffic.
         not_overhead = ("execute", "profile")
+        task_s = self._worker_execute_s.pop(
+            operation_id, root.stage_durations.get("execute", 0.0)
+        )
         self.last_timings["wall_overhead"] = max(
             0.0,
-            root.total() - sum(
-                root.stage_durations.get(stage, 0.0)
-                for stage in not_overhead
-            ),
+            root.total() - task_s - root.stage_durations.get("profile", 0.0),
         )
         self.last_timings["overhead"] = root.overhead(exclude=not_overhead)
         _ACTIVE_ELECTRONS.dec()

@@ -1,4 +1,4 @@
-"""Cross-cutting utilities: config, logging, serialization, stage timing.
+"""Cross-cutting utilities: config, logging, serialization, checkpoints.
 
 The reference keeps these as loose globals inside ``ssh.py`` (config at
 ``covalent_ssh_plugin/ssh.py:31,39-50``, logging at ``ssh.py:36-37``,
@@ -20,7 +20,6 @@ from .checkpoint import (
 from .config import get_config, set_config, update_config
 from .log import app_log
 from .serialize import dump_task, load_result
-from .timing import StageTimer
 
 __all__ = [
     "checkpoint_dir",
@@ -38,5 +37,4 @@ __all__ = [
     "app_log",
     "dump_task",
     "load_result",
-    "StageTimer",
 ]

@@ -12,6 +12,7 @@ round-trip to another machine.
 
 from __future__ import annotations
 
+import json
 import pickle
 from pathlib import Path
 from typing import Any, Callable
@@ -29,5 +30,22 @@ def dump_task(
 
 def load_result(path: str | Path) -> tuple[Any, BaseException | None]:
     """Unpickle a fetched result file (reference: ssh.py:455-458)."""
+    return load_result_and_trailer(path)[0]
+
+
+def load_result_and_trailer(
+    path: str | Path,
+) -> tuple[tuple[Any, BaseException | None], dict | None]:
+    """The ``(result, exception)`` pair and, where the harness wrote one,
+    the JSON trailer that follows the pickle in the same file: the worker's
+    spans and compile counters (``harness._trace_trailer``), which so come
+    home in the fetch the dispatcher makes anyway.  A missing or torn
+    trailer reads as ``None``: observability never fails a result."""
     with open(path, "rb") as f:
-        return pickle.load(f)
+        pair = pickle.load(f)
+        rest = f.read()
+    try:
+        trailer = json.loads(rest) if rest.strip() else None
+    except ValueError:
+        trailer = None
+    return pair, trailer if isinstance(trailer, dict) else None

@@ -236,21 +236,6 @@ def test_span_durations_land_in_histogram():
     assert child.count >= 1
 
 
-def test_stagetimer_shim_matches_old_api():
-    from covalent_tpu_plugin.utils.timing import StageTimer
-
-    t = StageTimer()
-    with t.stage("validate"):
-        time.sleep(0.005)
-    with t.stage("execute"):
-        time.sleep(0.005)
-    s = t.summary()
-    assert set(s) == {"validate", "execute", "total", "overhead"}
-    assert s["overhead"] == pytest.approx(s["validate"])
-    assert s["total"] >= s["validate"] + s["execute"]
-    assert t.stages["validate"] == s["validate"]
-
-
 # --------------------------------------------------------------------- #
 # Event sink round-trip
 # --------------------------------------------------------------------- #
@@ -394,6 +379,11 @@ def test_full_run_produces_ordered_span_set(tmp_path, run_async, events_file):
     # span is pipelined (serialization overlaps the connect/pre-flight
     # round trips), so only the strictly-sequential stages keep a fixed
     # completion order.
+    # The worker's own five spans come home with the result and hang
+    # under the same root (tests/test_worker_trace.py covers them).
+    brought_home = [s for s in children if s["name"].startswith("worker.")]
+    assert len(brought_home) == 5
+    children = [s for s in children if s not in brought_home]
     assert sorted(s["name"] for s in children) == sorted(EXPECTED_LIFECYCLE)
     sequential = [
         s["name"] for s in children if s["name"] != "executor.stage"
