@@ -1456,7 +1456,7 @@ def accelerator_electron(progress_path: str, budget_s: float) -> dict:
             )
 
             # Fused-xent arm: the same step with the
-            # vocab-chunked loss (ops/xent.py) — the lm_head matmul runs
+            # row-tiled loss (ops/xent.py) — the lm_head matmul runs
             # bf16-native and the (B,S,V) logits tensor never reaches
             # HBM.  A/B against the standard arm above; own try so a
             # fused failure can't void the standard number.  The gate is

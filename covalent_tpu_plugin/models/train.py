@@ -172,11 +172,14 @@ def classifier_loss(params, apply_fn, batch):
 def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     """Next-token loss over a {"tokens": (B, S)} batch.
 
-    ``vocab_chunk`` switches to the fused vocab-chunked cross-entropy
-    (``ops/xent.py``): the model returns final FEATURES and the loss
-    streams over lm_head chunks, so the (B, S, vocab) logits tensor is
-    never materialised in HBM.  Requires a plain float lm_head kernel
-    (no lm_head LoRA, unquantized)."""
+    ``vocab_chunk`` switches to the fused cross-entropy (``ops/xent.py``):
+    the model returns final FEATURES and the loss walks them in tiles of
+    rows, each tile's scores the whole vocabulary wide, forming the
+    gradients in the same pass, so the (B, S, vocab) logits tensor is
+    never materialised in HBM.  ``vocab_chunk`` (a divisor of the
+    vocabulary) bounds the scores live at once, at B·S x vocab_chunk
+    elements.  Requires a plain float lm_head kernel (no lm_head LoRA,
+    unquantized)."""
     tokens = batch["tokens"]
     if vocab_chunk is None:
         logits = apply_fn({"params": params}, tokens[:, :-1])

@@ -1,9 +1,9 @@
-"""Fused vocab-chunked cross-entropy (ops/xent.py) vs the dense path.
+"""Fused row-tiled cross-entropy (ops/xent.py) vs the dense path.
 
 Exactness contract: at f32 inputs the fused loss and BOTH gradients match
 a dense logits + stable log-softmax reference to float tolerance (the
-chunked online logsumexp is the same math, reassociated); through the
-model at bf16 the comparison is against the standard `lm_loss` path
+row tiles are the same math, the sums over rows reassociated); through
+the model at bf16 the comparison is against the standard `lm_loss` path
 within bf16-matmul tolerance (the fused path intentionally runs the
 lm_head matmul with bf16 inputs on the MXU-native path, where the
 logits_dtype=f32 default upcasts first).
@@ -29,22 +29,39 @@ def _ref(x, w, labels):
     return jnp.mean(lse - lab)
 
 
-@pytest.mark.parametrize("chunk", [32, 64, 256])
-def test_fused_xent_matches_dense(chunk):
-    T, d, V = 48, 32, 256
+def _inputs(T, d, V):
     x = jax.random.normal(jax.random.PRNGKey(0), (T, d), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (d, V)) * 0.1
     labels = jax.random.randint(jax.random.PRNGKey(2), (T,), 0, V)
+    return x, w, labels
+
+
+def _head_matmuls(jaxpr, vocab):
+    """dot_generals with a vocabulary-sized operand, sub-jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general" and any(
+            vocab in v.aval.shape for v in eqn.invars
+        )
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _head_matmuls(sub, vocab)
+    return n
+
+
+# 256 is the whole vocabulary (one tile), 1 its smallest divisor (a row a tile).
+@pytest.mark.parametrize("chunk", [32, 64, 256, 1])
+def test_fused_xent_matches_dense(chunk):
+    T, d, V = 48, 32, 256
+    x, w, labels = _inputs(T, d, V)
     lf = fused_cross_entropy(x, w, labels, chunk)
     lr = _ref(x, w, labels)
     assert abs(float(lf) - float(lr)) < 1e-5
 
 
-def test_fused_xent_grads_match_dense():
-    T, d, V, chunk = 48, 32, 256, 64
-    x = jax.random.normal(jax.random.PRNGKey(0), (T, d), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(1), (d, V)) * 0.1
-    labels = jax.random.randint(jax.random.PRNGKey(2), (T,), 0, V)
+@pytest.mark.parametrize("chunk", [64, 256, 1])
+def test_fused_xent_grads_match_dense(chunk):
+    T, d, V = 48, 32, 256
+    x, w, labels = _inputs(T, d, V)
     dxf, dwf = jax.grad(
         lambda x, w: fused_cross_entropy(x, w, labels, chunk), argnums=(0, 1)
     )(x, w)
@@ -53,6 +70,77 @@ def test_fused_xent_grads_match_dense():
     )(x, w)
     assert float(jnp.abs(dxf - dxr).max()) < 1e-6
     assert float(jnp.abs(dwf - dwr).max()) < 1e-6
+
+
+@pytest.mark.parametrize("differentiated, matmuls", [(True, 3), (False, 1)])
+def test_fused_xent_makes_each_score_once(differentiated, matmuls):
+    """The loss and both gradients take three head-sized matmuls (scores,
+    dx, dW), the loss alone one: no score is recomputed."""
+    T, d, V, chunk = 48, 32, 256, 64
+    x = jnp.zeros((T, d))
+    w = jnp.zeros((d, V))
+    labels = jnp.zeros((T,), jnp.int32)
+
+    def loss(x, w):
+        return fused_cross_entropy(x, w, labels, chunk)
+
+    fn = jax.value_and_grad(loss, argnums=(0, 1)) if differentiated else loss
+    assert _head_matmuls(jax.make_jaxpr(fn)(x, w).jaxpr, V) == matmuls
+
+
+def test_fused_xent_cotangent_scales_gradients():
+    """The gradients are made in the forward rule for a cotangent of 1;
+    any other has to scale both, exactly."""
+    T, d, V, chunk = 48, 32, 256, 64
+    x, w, labels = _inputs(T, d, V)
+
+    def grads(scale):
+        return jax.grad(
+            lambda x, w: scale * fused_cross_entropy(x, w, labels, chunk),
+            argnums=(0, 1),
+        )(x, w)
+
+    (dx, dw), (dx_s, dw_s) = grads(1.0), grads(2.5)
+    assert float(jnp.abs(dx).max()) > 0 and float(jnp.abs(dw).max()) > 0
+    assert bool(jnp.array_equal(dx_s, 2.5 * dx))
+    assert bool(jnp.array_equal(dw_s, 2.5 * dw))
+
+
+# Tiles of 8 rows at chunk 64 of 256: neither T is a multiple of 8.
+@pytest.mark.parametrize("T", [50, 53])
+def test_fused_xent_padded_rows_carry_no_weight(T):
+    d, V, chunk = 32, 256, 64
+    x, w, labels = _inputs(T, d, V)
+    lf, (dxf, dwf) = jax.value_and_grad(
+        lambda x, w: fused_cross_entropy(x, w, labels, chunk), argnums=(0, 1)
+    )(x, w)
+    lr, (dxr, dwr) = jax.value_and_grad(
+        lambda x, w: _ref(x, w, labels), argnums=(0, 1)
+    )(x, w)
+    assert abs(float(lf) - float(lr)) < 1e-5
+    assert abs(float(fused_cross_entropy(x, w, labels, chunk)) - float(lr)) < 1e-5
+    assert dxf.shape == x.shape
+    assert float(jnp.abs(dxf - dxr).max()) < 1e-6
+    assert float(jnp.abs(dwf - dwr).max()) < 1e-6
+
+
+def test_fused_xent_bf16_inputs_match_dense_bf16():
+    """bfloat16 features against a float32 head, as a train step has them:
+    the tolerances of test_lm_loss_fused_path_matches_standard."""
+    T, d, V, chunk = 48, 32, 256, 64
+    x, w, labels = _inputs(T, d, V)
+    x = x.astype(jnp.bfloat16)
+    lf, gf = jax.value_and_grad(
+        lambda x, w: fused_cross_entropy(x, w, labels, chunk), argnums=(0, 1)
+    )(x, w)
+    lr, gr = jax.value_and_grad(
+        lambda x, w: _ref(x, w, labels), argnums=(0, 1)
+    )(x, w)
+    assert abs(float(lf) - float(lr)) < 2e-3
+    for f, r in zip(gf, gr):
+        assert f.dtype == r.dtype
+        f, r = f.astype(jnp.float32), r.astype(jnp.float32)
+        assert float(jnp.abs(f - r).max() / jnp.abs(r).max()) < 0.05
 
 
 def test_fused_xent_rejects_ragged_vocab():
