@@ -12,6 +12,7 @@ import os
 import time
 
 from benchmarks.suite import compare, harness_util, loadgen, program
+from benchmarks.suite.readers import slow_steps
 
 
 async def _dispatch(cell: dict, args, trace_dir, workdir: str,
@@ -85,6 +86,9 @@ def run(cell: dict, args, t_start: float, require_tpu: bool = True,
         t_check = time.time()
         numbers = compare.train_numbers(report, readings(cell, args))
         numbers["check_s"] = time.time() - t_check
+        # Not compared: the untraced line's notes tell a host's stall (a few
+        # slow steps) from a slower program.
+        numbers["slow_steps"] = slow_steps.count(report["step_times"])
     finally:
         harness_util.cleanup(workdir)
     tokens = report["steps"] * report["tokens_per_step"]

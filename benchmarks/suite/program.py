@@ -6,7 +6,9 @@ From the program the benchmark takes the entry points users call
 (``open_session`` -> ``ContinuousEngine``; ``TPUExecutor.run`` ->
 ``make_sharded_train_state`` / ``make_train_step``), its counters, and the
 names of its jitted steps.  Weights come from ``weights.leaf`` and the seed,
-placed into the program's own parameter tree.
+placed into the program's own parameter tree.  Which model that is, what its
+loss is and how its parameters are named is the architecture's
+(``archs.load(config)``).
 """
 
 from __future__ import annotations
@@ -16,34 +18,7 @@ import math
 import os
 import time
 
-from benchmarks.suite import loadgen, weights
-
-#: program leaf path -> the benchmark's leaf name.
-_LEAF = {
-    ("attention", "q_proj", "kernel"): "q",
-    ("attention", "k_proj", "kernel"): "k",
-    ("attention", "v_proj", "kernel"): "v",
-    ("attention", "out_proj", "kernel"): "o",
-    ("mlp", "wi", "kernel"): "wi",
-    ("mlp", "wo", "kernel"): "wo",
-    ("ln_attn", "scale"): "ln_attn",
-    ("ln_mlp", "scale"): "ln_mlp",
-}
-_TOP = {("embedding",): "embedding", ("ln_final", "scale"): "ln_final",
-        ("lm_head", "kernel"): "lm_head"}
-
-
-def leaf_name(path) -> str:
-    """``layer_3.q`` for ``params['layer_3']['attention']['q_proj']['kernel']``
-    (a flax ``Partitioned`` box's ``.value`` step is skipped)."""
-    keys = tuple(
-        k.key for k in path if hasattr(k, "key") and isinstance(k.key, str)
-    )
-    if keys in _TOP:
-        return _TOP[keys]
-    if keys and keys[0].startswith("layer_") and keys[1:] in _LEAF:
-        return f"{keys[0]}.{_LEAF[keys[1:]]}"
-    raise KeyError(f"no benchmark leaf for the program's parameter {keys}")
+from benchmarks.suite import archs, loadgen, weights
 
 
 def compile_log():
@@ -95,37 +70,19 @@ def require_chips(chips: int | None) -> dict:
     return report
 
 
-def model_config(config: dict, **overrides):
-    """The program's ``TransformerConfig`` at the configuration's sizes."""
-    import jax.numpy as jnp
-
-    from covalent_tpu_plugin.models.transformer import TransformerConfig
-
-    s = weights.sizes(config)
-    if s["D"] != s["H"] * s["hd"]:
-        raise ValueError("the program's block needs head_dim = hidden / heads")
-    return TransformerConfig(
-        vocab_size=s["V"], d_model=s["D"], n_layers=s["L"], n_heads=s["H"],
-        n_kv_heads=s["KV"], d_ff=s["F"],
-        dtype=jnp.dtype(config["activation_dtype"]),
-        param_dtype=jnp.dtype(config["weight_dtype"]),
-        sliding_window=config["sliding_window"],
-        rope_base=config["rope_theta"], scan_layers=False, **overrides,
-    )
-
-
 def place_weights(template, config: dict, seed: int, dtype=None):
     """Fill the program's parameter tree ``template`` (arrays, shapes or
     boxed either) with the seed's weights in ONE jitted call, each leaf made
     where the template's sharding puts it."""
     import jax
 
+    arch = archs.load(config)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(template)
-    specs = {name: (shape, std) for name, shape, std in
+    specs = {name: (shape, init) for name, shape, init in
              weights.leaf_specs(config)}
     plan, shardings = [], []
     for path, leaf in leaves:
-        name = leaf_name(path)
+        name = arch.leaf_name(path)
         shape, std = specs[name]
         size = 1
         for d in leaf.shape:
@@ -142,7 +99,7 @@ def place_weights(template, config: dict, seed: int, dtype=None):
 
     def build(key):
         return [
-            weights.leaf(key, name, shape, std, dt).reshape(held)
+            weights.leaf(key, name, shape, std, dt, arch).reshape(held)
             for name, shape, std, held, dt in plan
         ]
 
@@ -183,7 +140,6 @@ def engine_factory(config: dict, traffic: dict, seed: int, report_path: str,
         import jax.numpy as jnp
         import numpy as np
 
-        from covalent_tpu_plugin.models import TransformerLM
         from covalent_tpu_plugin.models.serve import ContinuousEngine
         from covalent_tpu_plugin.parallel.sharding import unbox
 
@@ -195,7 +151,7 @@ def engine_factory(config: dict, traffic: dict, seed: int, report_path: str,
             raise
         t_device = time.time()
         engine_args = traffic["engine"]
-        lm = TransformerLM(model_config(config, max_seq=engine_args["max_seq"]))
+        lm = archs.load(config).serve_model(config, traffic)
         template = unbox(jax.eval_shape(
             lambda: lm.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))["params"]
@@ -246,7 +202,6 @@ def engine_factory(config: dict, traffic: dict, seed: int, report_path: str,
         # finishes a request at its admission, so lanes free at once.
         prompts = traffic["prompt_tokens"]
         rng = np.random.default_rng(0)
-        s = weights.sizes(config)
         waves = []
         g = 1
         while g <= engine_args["max_batch"]:
@@ -259,8 +214,10 @@ def engine_factory(config: dict, traffic: dict, seed: int, report_path: str,
             for g in waves:
                 for _ in range(g):
                     n += 1
-                    engine.admit(f"warm-{n}", rng.integers(0, s["V"], size),
-                                 {"max_new_tokens": 1})
+                    engine.admit(
+                        f"warm-{n}",
+                        rng.integers(0, config["vocab_size"], size),
+                        {"max_new_tokens": 1})
                 while engine.busy:
                     engine.step()
         for key in engine.stats:
@@ -277,7 +234,7 @@ def engine_factory(config: dict, traffic: dict, seed: int, report_path: str,
 # -- training --------------------------------------------------------------
 
 
-def _norms_by_leaf(tree, scale: float = 1.0) -> dict:
+def _norms_by_leaf(tree, leaf_name, scale: float = 1.0) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -294,11 +251,12 @@ def _delta_norms(params, config: dict, seed: int) -> dict:
     import jax.numpy as jnp
 
     key = weights.seed_key(seed)
-    specs = {name: (shape, std) for name, shape, std in
+    arch = archs.load(config)
+    specs = {name: (shape, init) for name, shape, init in
              weights.leaf_specs(config)}
 
     def change(key, now, name, shape, std):
-        first = weights.leaf(key, name, shape, std, now.dtype)
+        first = weights.leaf(key, name, shape, std, now.dtype, arch)
         return jnp.sqrt(jnp.sum(jnp.square(
             now.astype(jnp.float32) - first.reshape(now.shape)
             .astype(jnp.float32))))
@@ -306,7 +264,7 @@ def _delta_norms(params, config: dict, seed: int) -> dict:
     fn = jax.jit(change, static_argnums=(3, 4))
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        name = leaf_name(path)
+        name = arch.leaf_name(path)
         shape, std = specs[name]
         out[name] = float(fn(key, leaf, weights.name_hash(name), shape, std))
     return out
@@ -320,15 +278,10 @@ def train_electron(config: dict, job: dict, seed: int, seconds: float,
     then the measured window on the same compiled step and state."""
     t_enter = time.time()
     compiles = compile_log()
-    import functools
-
     import jax
-    import numpy as np
     import optax
 
     from covalent_tpu_plugin.models import (
-        TransformerLM,
-        lm_loss,
         make_sharded_train_state,
         make_train_step,
     )
@@ -343,10 +296,8 @@ def train_electron(config: dict, job: dict, seed: int, seconds: float,
     # The mesh takes as many devices as the job's plan names: all of the
     # cell's chips on the chip, the first few of a CPU rehearsal's.
     mesh = make_mesh(plan, jax.local_devices()[: math.prod(job["mesh"].values())])
-    lm = TransformerLM(model_config(
-        config, max_seq=job["sequence"], attention=job["attention"],
-        remat=job["remat"], mesh=mesh,
-    ))
+    arch = archs.load(config)
+    lm, loss_fn = arch.program(config, job, mesh)
     pool = loadgen.train_batches(config, job, seed, job["feed_batches"])
     batches = [shard_batch({"tokens": b}, mesh) for b in pool]
     state, shardings = make_sharded_train_state(
@@ -354,7 +305,6 @@ def train_electron(config: dict, job: dict, seed: int, seconds: float,
         batches[0]["tokens"][:, :-1], mesh,
     )
     state = state.replace(params=place_weights(state.params, config, seed))
-    loss_fn = functools.partial(lm_loss, vocab_chunk=job["vocab_chunk"])
     loss_fn = hooks.get("loss_fn", lambda f: f)(loss_fn)
     step = make_train_step(loss_fn, mesh, shardings)
     step = hooks.get("step", lambda f: f)(step)
@@ -368,7 +318,8 @@ def train_electron(config: dict, job: dict, seed: int, seconds: float,
         losses.append(float(metrics["loss"]))
         if i == 0:
             mu = state.opt_state[0].mu
-            report["grad_norms"] = _norms_by_leaf(mu, 1.0 / (1.0 - 0.9))
+            report["grad_norms"] = _norms_by_leaf(
+                mu, arch.leaf_name, 1.0 / (1.0 - 0.9))
     report["losses"] = losses
     report["delta_norms"] = _delta_norms(state.params, config, seed)
     n = checks

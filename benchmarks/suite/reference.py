@@ -1,11 +1,12 @@
-"""The plain reference: a StarCoder2-shaped decoder (pre-norm, rotary, GQA,
-sliding window, non-gated tanh-GELU MLP) in straightforward ``jax.numpy``,
-float32 at matmul precision ``highest``, with no kernel, cache or batching.
+"""The plain reference, as far as it is nobody's in particular: the norm,
+the row-chunked head, the batch's mean loss with its planted fault, AdamW
+written out and the steps that follow a train job; the block itself is the
+architecture's (``archs/<model_type>.py``: ``sequence_loss``, ``layer``).
+Straightforward ``jax.numpy``, float32 at matmul precision ``highest``,
+with no kernel, cache or batching.
 
 It imports nothing of the program and takes nothing the program has made:
-weights come from ``weights.leaf`` and the seed.  Departures from the
-published model are the configuration file's ``departures`` (RMSNorm for
-LayerNorm, no biases, untied head), which the program's block forces.
+weights come from ``weights.leaf`` and the seed.
 
 Two uses decide ``correct``:
 
@@ -30,11 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.suite import weights
+from benchmarks.suite import archs, weights
 
 #: AdamW as the train jobs state it (optax.adamw's defaults, written out).
 ADAM_B1, ADAM_B2, ADAM_EPS, WEIGHT_DECAY = 0.9, 0.999, 1e-8, 1e-4
-LAYER_LEAVES = ("ln_attn", "q", "k", "v", "o", "ln_mlp", "wi", "wo")
 
 
 def _precision(dtype):
@@ -49,71 +49,20 @@ def rms_norm(x, scale, eps, dtype):
     return (y * scale.astype(jnp.float32)).astype(dtype)
 
 
-def rope(x, theta):
-    """Rotary embedding, half-split (rotate_half) form, over (S, H, hd)."""
-    seq, _, head_dim = x.shape
-    half = head_dim // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[:, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def attention(q, k, v, window, block=512):
-    """Causal sliding-window GQA over (S, H, hd) / (S, KV, hd), a block of
-    query rows at a time so the (heads, block, S) scores fit."""
-    seq, heads, head_dim = q.shape
-    kv = k.shape[1]
-    block = min(block, seq)
-    if seq % block:
-        raise ValueError(f"sequence {seq} is not a multiple of {block}")
-    qg = q.reshape(seq // block, block, kv, heads // kv, head_dim)
-    k_pos = jnp.arange(seq)
-
-    @jax.checkpoint
-    def rows(args):
-        i, qb = args
-        q_pos = i * block + jnp.arange(block)
-        scores = jnp.einsum(
-            "qkgd,skd->kgqs", qb, k, preferred_element_type=jnp.float32
-        ) * (head_dim ** -0.5)
-        seen = (k_pos[None, :] <= q_pos[:, None]) & (
-            k_pos[None, :] > q_pos[:, None] - window
-        )
-        scores = jnp.where(seen[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("kgqs,skd->qkgd", probs, v)
-
-    out = jax.lax.map(rows, (jnp.arange(seq // block), qg))
-    return out.reshape(seq, heads * head_dim)
-
-
-def layer(x, w, config, dtype):
-    """One block over (S, D): x + attn(norm(x)); x + mlp(norm(x))."""
-    s = weights.sizes(config)
-    eps, theta = config["rms_norm_eps"], config["rope_theta"]
-    h = rms_norm(x, w["ln_attn"], eps, dtype)
-    q = rope((h @ w["q"]).reshape(-1, s["H"], s["hd"]), theta)
-    k = rope((h @ w["k"]).reshape(-1, s["KV"], s["hd"]), theta)
-    v = (h @ w["v"]).reshape(-1, s["KV"], s["hd"])
-    x = x + attention(q, k, v, config["sliding_window"]) @ w["o"]
-    h = rms_norm(x, w["ln_mlp"], eps, dtype)
-    return x + jax.nn.gelu(h @ w["wi"], approximate=True) @ w["wo"]
-
-
-def _layer_leaves(config, key, hashes, dtype):
-    specs = weights.layer_specs(config, 0)
+def _layer_leaves(arch, config, key, hashes, dtype):
+    """One layer's leaves by their short names (``layer_0.q`` -> ``q``), the
+    names' hashes traced: one compiled block serves every layer."""
     return {
-        short: weights.leaf(key, hashes[j], shape, std, dtype)
-        for j, (short, (_, shape, std)) in enumerate(zip(LAYER_LEAVES, specs))
+        name.split(".", 1)[1]: weights.leaf(
+            key, hashes[j], shape, init, dtype, arch)
+        for j, (name, shape, init) in enumerate(arch.layer_specs(config, 0))
     }
 
 
-def _layer_hashes(i: int):
+def _layer_hashes(arch, config: dict, i: int):
     return jnp.asarray(
-        [weights.name_hash(f"layer_{i}.{n}") for n in LAYER_LEAVES], jnp.int32
+        [weights.name_hash(name) for name, _, _ in arch.layer_specs(config, i)],
+        jnp.int32,
     )
 
 
@@ -131,7 +80,10 @@ def serve_gaps(config: dict, seed: int, samples: list, length: int,
     held = jnp.dtype(config["weight_dtype"])
     f32 = jnp.float32
     key = weights.seed_key(seed)
-    s = weights.sizes(config)
+    arch = archs.load(config)
+    s = arch.sizes(config)
+    top = {name: (shape, init)
+           for name, shape, init in weights.leaf_specs(config)}
     tokens = np.zeros((len(samples), length), np.int32)
     for r, (prompt, served) in enumerate(samples):
         row = list(prompt) + list(served[:-1])
@@ -139,28 +91,26 @@ def serve_gaps(config: dict, seed: int, samples: list, length: int,
             raise ValueError(f"sample of {len(row)} tokens exceeds {length}")
         tokens[r, : len(row)] = row
 
-    std = float(config["initializer_range"])
-
     @jax.jit
     def embed(key, tokens):
-        table = weights.leaf(key, "embedding", (s["V"], s["D"]), std, held)
+        table = weights.leaf(key, "embedding", *top["embedding"], held, arch)
         return table[tokens].astype(f32)
 
     @jax.jit
     def block(key, hashes, x):
         w = {
             n: a.astype(f32)
-            for n, a in _layer_leaves(config, key, hashes, held).items()
+            for n, a in _layer_leaves(arch, config, key, hashes, held).items()
         }
         with _precision(f32):
-            return jax.lax.map(lambda row: layer(row, w, config, f32), x)
+            return jax.lax.map(lambda row: arch.layer(row, w, config, f32), x)
 
     @jax.jit
     def head(key, x, rows, at, served):
         # One call of one shape whatever the samples' lengths: ``rows`` and
         # ``at`` pick each served position's features out of ``x``.
         kernel = weights.leaf(
-            key, "lm_head", (s["D"], s["V"]), std, held
+            key, "lm_head", *top["lm_head"], held, arch
         ).astype(f32)
         feats = rms_norm(x[rows, at], jnp.ones((s["D"],), f32),
                          config["rms_norm_eps"], f32)
@@ -171,7 +121,7 @@ def serve_gaps(config: dict, seed: int, samples: list, length: int,
 
     x = embed(key, jnp.asarray(tokens))
     for i in range(s["L"]):
-        x = block(key, _layer_hashes(i), x)
+        x = block(key, _layer_hashes(arch, config, i), x)
     # Padded to the most that the samples could hold, so the shape is the
     # cell's and not the run's.
     most = len(samples) * int(max_served)
@@ -200,30 +150,21 @@ def serve_gaps(config: dict, seed: int, samples: list, length: int,
 
 def all_leaves(config: dict, seed: int, dtype) -> dict:
     key = weights.seed_key(seed)
-    make = jax.jit(weights.leaf, static_argnums=(2, 3, 4))
+    arch = archs.load(config)
+    make = jax.jit(weights.leaf, static_argnums=(2, 3, 4, 5))
     return {
-        name: make(key, weights.name_hash(name), shape, std, dtype)
-        for name, shape, std in weights.leaf_specs(config)
+        name: make(key, weights.name_hash(name), shape, init, dtype, arch)
+        for name, shape, init in weights.leaf_specs(config)
     }
 
 
-def sequence_loss(w, tokens, config, dtype, positions=None, chunk=2048):
-    """Sum of next-token cross-entropies of one row of ``S + 1`` tokens
-    (and the count): logits a block of rows at a time, in float32.
-    ``positions`` keeps only the first that many (a planted fault)."""
-    s = weights.sizes(config)
-    x = w["embedding"].astype(dtype)[tokens[:-1]]
-    for i in range(s["L"]):
-        lw = {n: w[f"layer_{i}.{n}"].astype(dtype) for n in LAYER_LEAVES}
-        x = jax.checkpoint(
-            functools.partial(layer, config=config, dtype=dtype)
-        )(x, lw)
-    feats = rms_norm(x, w["ln_final"], config["rms_norm_eps"], dtype)
-    labels = tokens[1:]
+def head_loss(feats, labels, kernel, positions=None, chunk=2048):
+    """Sum of the cross-entropies of ``labels`` under ``feats @ kernel`` (and
+    the count): logits a block of rows at a time, in float32.  ``positions``
+    keeps only the first that many (a planted fault)."""
     if positions is not None:
         feats, labels = feats[:positions], labels[:positions]
     chunk = min(chunk, feats.shape[0])
-    kernel = w["lm_head"].astype(dtype)
 
     @jax.checkpoint
     def rows(args):
@@ -253,6 +194,7 @@ def batch_loss(w, batch, config, dtype, fault=None):
             rows = batch[: batch.shape[0] // 2]
         else:
             positions = (batch.shape[1] - 1) // 2
+    sequence_loss = archs.load(config).sequence_loss
     with _precision(dtype):
         sums, counts = zip(*(
             sequence_loss(w, rows[b], config, dtype, positions)
@@ -322,16 +264,18 @@ def train_readings(config: dict, job: dict, seed: int, batches,
             grad_norms = {n: float(x) for n, x in norms.items()}
     del m, v
     key = weights.seed_key(seed)
+    arch = archs.load(config)
     change = jax.jit(
-        lambda key, now, name, shape, std: _norm(
+        lambda key, now, name, shape, init: _norm(
             now.astype(jnp.float32)
-            - weights.leaf(key, name, shape, std, dtype).astype(jnp.float32)
+            - weights.leaf(key, name, shape, init, dtype, arch)
+            .astype(jnp.float32)
         ),
         static_argnums=(3, 4),
     )
     delta_norms = {
-        name: float(change(key, w[name], weights.name_hash(name), shape, std))
-        for name, shape, std in weights.leaf_specs(config)
+        name: float(change(key, w[name], weights.name_hash(name), shape, init))
+        for name, shape, init in weights.leaf_specs(config)
     }
     return {"losses": losses, "grad_norms": grad_norms,
             "delta_norms": delta_norms}

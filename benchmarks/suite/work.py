@@ -9,7 +9,7 @@ from __future__ import annotations
 import json
 import os
 
-from benchmarks.suite import weights
+from benchmarks.suite import archs
 
 _PEAKS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "peaks.json")
 
@@ -24,18 +24,6 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def matmul_parameters(config: dict) -> int:
-    """Weights that multiply every token: the layers' six kernels and the
-    output head.  The embedding is a lookup, the norms are vectors."""
-    s = weights.sizes(config)
-    per_layer = (
-        s["D"] * s["H"] * s["hd"] * 2          # q, o
-        + s["D"] * s["KV"] * s["hd"] * 2       # k, v
-        + s["D"] * s["F"] * 2                  # wi, wo
-    )
-    return s["L"] * per_layer + s["D"] * s["V"]
-
-
 def visible_pairs(seq: int, window: int | None) -> int:
     """Query-key pairs a causal (sliding-window) attention over ``seq``
     positions has to score: sum over t of min(t + 1, window)."""
@@ -44,59 +32,21 @@ def visible_pairs(seq: int, window: int | None) -> int:
     return window * (window + 1) // 2 + (seq - window) * window
 
 
-def attention_forward_flops(config: dict, seq: int) -> int:
-    """QK^T and PV over the visible pairs, every head, one sequence, one
-    layer: 2 matmuls x 2 FLOPs x head_dim each pair."""
-    s = weights.sizes(config)
-    return 4 * s["hd"] * s["H"] * visible_pairs(seq, config["sliding_window"])
-
-
-def train_flops_per_token(config: dict, seq: int) -> float:
-    """Forward plus backward (twice the forward), no recompute: the matmul
-    weights at 2 FLOPs each and attention inside the window."""
-    s = weights.sizes(config)
-    forward = 2 * matmul_parameters(config) + (
-        s["L"] * attention_forward_flops(config, seq) / seq
-    )
-    return 3.0 * forward
-
-
 def serve_flops(config: dict, tokens: int) -> float:
     """2 x matmul weights for every prompt and output token processed
     (attention over the context is left out: an undercount)."""
-    return 2.0 * matmul_parameters(config) * tokens
-
-
-def kv_bytes_per_token(config: dict) -> int:
-    s = weights.sizes(config)
-    return s["L"] * 2 * s["KV"] * s["hd"] * _bytes(config["activation_dtype"])
+    return 2.0 * archs.load(config).matmul_parameters(config) * tokens
 
 
 def decode_step_bytes(config: dict, live_tokens: float) -> float:
     """What one decode step has to read: every matmul weight once, and the
     K and V of the tokens alive in the lanes (not the ``max_seq``
     rectangle)."""
+    arch = archs.load(config)
     return (
-        matmul_parameters(config) * _bytes(config["weight_dtype"])
-        + live_tokens * kv_bytes_per_token(config)
+        arch.matmul_parameters(config) * _bytes(config["weight_dtype"])
+        + live_tokens * arch.kv_bytes_per_token(config)
     )
-
-
-def flash_step_work(config: dict, batch: int, seq: int) -> dict:
-    """The flash kernels' needed work in one train step over ``batch``
-    sequences, all layers: forward once and the backward's four matmuls
-    (twice the forward); bytes are Q, K, V, O once forward, and Q, K, V, O,
-    dO in, dQ, dK, dV out backward."""
-    s = weights.sizes(config)
-    act = _bytes(config["activation_dtype"])
-    forward = attention_forward_flops(config, seq)
-    q_bytes = seq * s["H"] * s["hd"] * act
-    kv_bytes = seq * s["KV"] * s["hd"] * act
-    forward_bytes = 2 * q_bytes + 2 * kv_bytes
-    backward_bytes = 4 * q_bytes + 4 * kv_bytes
-    n = batch * s["L"]
-    return {"flops": 3 * forward * n,
-            "bytes": (forward_bytes + backward_bytes) * n}
 
 
 def roofline_seconds(work: dict, peak: dict, chips: int = 1) -> tuple:

@@ -4,7 +4,10 @@
 
 Two jitted programs of a few operations each, a host pause between them,
 under ``jax.profiler``; prints what ``reduce.reduce_dir`` makes of it, which
-is written beside the trace as the expected numbers.
+is written beside the trace as the expected numbers.  The trace carries its
+own map from instruction to ``op_name`` (the device plane's event metadata,
+stat ``tf_op``), which ``reduce.read_scopes`` reads: ``"scopes"`` in what is
+printed is each operation's own time laid to it.
 """
 
 import json

@@ -15,6 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 TINY_CONFIG = {
+    "model_type": "starcoder2",
     "source": "a stand-in for tests, nobody's model",
     "hidden_size": 64, "intermediate_size": 256, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2, "head_dim": 16,

@@ -9,7 +9,6 @@ file.  All such tests live in this one file for the same reason.
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 
@@ -76,8 +75,7 @@ def test_train_16k_step_compiles_with_mosaic_and_fits(topo, one_chip, no_cache,
     from flax import linen as nn
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from benchmarks.suite import program
-    from covalent_tpu_plugin.models import TransformerLM, lm_loss
+    from benchmarks.suite import archs
     from covalent_tpu_plugin.models.train import TrainState, make_train_step
     from covalent_tpu_plugin.ops import attention
     from covalent_tpu_plugin.parallel import MeshPlan, make_mesh
@@ -89,9 +87,7 @@ def test_train_16k_step_compiles_with_mosaic_and_fits(topo, one_chip, no_cache,
     cell = _cell("sc2-3b.train-16k")
     config, job = cell["config"], cell["traffic"]
     mesh = make_mesh(MeshPlan(**job["mesh"]), [topo.devices[0]])
-    lm = TransformerLM(program.model_config(
-        config, max_seq=job["sequence"], attention=job["attention"],
-        remat=job["remat"], mesh=mesh))
+    lm, loss_fn = archs.load(config).program(config, job, mesh)
     tokens = jax.ShapeDtypeStruct(
         (job["batch"], job["sequence"] + 1), jnp.int32,
         sharding=NamedSharding(mesh, PartitionSpec()))
@@ -112,9 +108,7 @@ def test_train_16k_step_compiles_with_mosaic_and_fits(topo, one_chip, no_cache,
     state = jax.tree_util.tree_unflatten(treedef, [
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)
         for x, s in zip(leaves, jax.tree_util.tree_leaves(shardings))])
-    step = make_train_step(
-        functools.partial(lm_loss, vocab_chunk=job["vocab_chunk"]),
-        mesh, shardings)
+    step = make_train_step(loss_fn, mesh, shardings)
     lowered = step.lower(state, {"tokens": tokens})
     assert "tpu_custom_call" in lowered.as_text()
     compiled = lowered.compile()
@@ -128,13 +122,13 @@ def test_reference_train_step_fits_one_chip(one_chip, no_cache):
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.suite import reference, weights
+    from benchmarks.suite import archs, reference
 
     cell = _cell("sc2-3b.train-16k")
     config, job = cell["config"], cell["traffic"]
     f32 = jnp.dtype("float32")
     w = {name: jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
-         for name, shape, _ in weights.leaf_specs(config)}
+         for name, shape, _ in archs.load(config).leaf_specs(config)}
     batch = jax.ShapeDtypeStruct(
         (job["batch"], job["sequence"] + 1), jnp.int32, sharding=one_chip)
     count = jax.ShapeDtypeStruct((), f32, sharding=one_chip)

@@ -10,7 +10,6 @@ The topology is described inside a fixture, never at import (see
 
 from __future__ import annotations
 
-import functools
 import os
 import re
 
@@ -21,7 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 KERNELS = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
 #: Mosaic wants lane-sized heads: the stand-in's block with head_dim 128.
 CONFIG = {
-    "hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 2,
+    "model_type": "starcoder2", "hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 2,
     "num_key_value_heads": 1, "num_hidden_layers": 2, "head_dim": 128,
     "vocab_size": 512, "sliding_window": 256, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-06, "initializer_range": 0.05,
@@ -65,8 +64,7 @@ def test_compiled_step_names_the_three_flash_kernels(topo, no_cache,
     from flax import linen as nn
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from benchmarks.suite import program, reduce
-    from covalent_tpu_plugin.models import TransformerLM, lm_loss
+    from benchmarks.suite import archs, reduce
     from covalent_tpu_plugin.models.train import TrainState, make_train_step
     from covalent_tpu_plugin.ops import attention
     from covalent_tpu_plugin.parallel import MeshPlan, make_mesh
@@ -76,9 +74,7 @@ def test_compiled_step_names_the_three_flash_kernels(topo, no_cache,
     # interpreted; the chip this compiles for runs them through Mosaic.
     monkeypatch.setattr(attention, "default_interpret", lambda: False)
     mesh = make_mesh(MeshPlan(**JOB["mesh"]), [topo.devices[0]])
-    lm = TransformerLM(program.model_config(
-        CONFIG, max_seq=JOB["sequence"], attention=JOB["attention"],
-        remat=JOB["remat"], mesh=mesh))
+    lm, loss_fn = archs.load(CONFIG).program(CONFIG, JOB, mesh)
     tokens = jax.ShapeDtypeStruct(
         (JOB["batch"], JOB["sequence"] + 1), jnp.int32,
         sharding=NamedSharding(mesh, PartitionSpec()))
@@ -97,9 +93,7 @@ def test_compiled_step_names_the_three_flash_kernels(topo, no_cache,
     state = jax.tree_util.tree_unflatten(treedef, [
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)
         for x, s in zip(leaves, jax.tree_util.tree_leaves(shardings))])
-    step = make_train_step(
-        functools.partial(lm_loss, vocab_chunk=JOB["vocab_chunk"]),
-        mesh, shardings)
+    step = make_train_step(loss_fn, mesh, shardings)
     text = step.lower(state, {"tokens": tokens}).compile().as_text()
     calls = [
         line.strip() for line in text.splitlines()

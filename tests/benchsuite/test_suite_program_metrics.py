@@ -12,7 +12,7 @@ import time
 
 import pytest
 
-from benchmarks.suite import run, spec, work
+from benchmarks.suite import archs, run, spec, work
 from benchmarks.suite.readers import (
     flash_part_roofline,
     op_calls,
@@ -43,22 +43,36 @@ def _context(cell, ops=None, op_events=None, steps=4, require_tpu=True):
             "device": {"kind": KIND}}
 
 
+def _part_work(cell, part):
+    return archs.load(cell["config"]).kernel_work(
+        cell["config"], cell["traffic"], flash_part_roofline.KERNELS[part])
+
+
 def _least(cell, part):
-    job = cell["traffic"]
-    return work.roofline_seconds(
-        flash_part_roofline.part_work(
-            cell["config"], job["batch"], job["sequence"], part),
-        work.peaks(KIND))[0]
+    return work.roofline_seconds(_part_work(cell, part), work.peaks(KIND))[0]
 
 
 def test_the_three_parts_sum_to_the_flash_kernels_needed_work(cell):
-    job = cell["traffic"]
-    parts = [flash_part_roofline.part_work(
-        cell["config"], job["batch"], job["sequence"], part)
-        for part in flash_part_roofline.KERNELS]
-    whole = work.flash_step_work(cell["config"], job["batch"], job["sequence"])
-    assert sum(p["flops"] for p in parts) == pytest.approx(whole["flops"])
-    assert sum(p["bytes"] for p in parts) == whole["bytes"]
+    from benchmarks.suite.readers import flash_roofline
+
+    job, arch = cell["traffic"], archs.load(cell["config"])
+    parts = [_part_work(cell, part) for part in flash_part_roofline.KERNELS]
+    # The whole: the forward once and the backward's four matmuls (twice the
+    # forward); Q, K, V, O once forward, Q, K, V, O, dO in and dQ, dK, dV out
+    # backward.
+    s = arch.sizes(cell["config"])
+    n = job["batch"] * s["L"]
+    forward = arch.attention_forward_flops(cell["config"], job["sequence"])
+    q = job["sequence"] * s["H"] * s["hd"] * 2
+    kv = job["sequence"] * s["KV"] * s["hd"] * 2
+    assert sum(p["flops"] for p in parts) == 3 * forward * n
+    assert sum(p["bytes"] for p in parts) == (6 * q + 6 * kv) * n
+    # ... which is what the kernels' joint roofline reads 100% against.
+    least = 3 * forward * n / 197e12
+    name = "jit_step/flash_fwd(tpu_custom_call)"
+    assert flash_roofline.read(
+        _context(cell, {name: 4 * least}), r"\(tpu_custom_call\)$"
+    ) == pytest.approx(100.0)
     fwd, dkdv, dq = parts
     assert dkdv["flops"] == 1.5 * fwd["flops"]
     assert dq["flops"] == 0.5 * fwd["flops"]
@@ -147,8 +161,9 @@ def test_the_new_entries_are_in_the_manifest_with_their_files():
     with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
         bench = json.load(f)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW  # appended, in the issue's order
-    for metric in bench["per_layer"][-len(NEW):]:
+    first = names.index(NEW[0])
+    assert names[first:first + len(NEW)] == NEW  # in the issue's order
+    for metric in bench["per_layer"][first:first + len(NEW)]:
         assert metric["workloads"] == ["sc2-3b.train-16k"]
         assert os.path.exists(os.path.join(
             REPO, bench["paths"][0], "metrics", metric["name"] + ".json"))

@@ -1,14 +1,26 @@
-"""The plain reference against the program's ``TransformerLM`` at a tiny
-size on the CPU (float32 both): the same seed's weights, the same logits;
-and the comparison's arithmetic."""
+"""The plain reference against the program's model at a tiny size on the CPU
+(float32 both; the StarCoder2 block, through ``archs.load``): the same
+seed's weights, the same logits; and the comparison's arithmetic."""
 
 from __future__ import annotations
 
 import numpy as np
 import pytest
 
-from benchmarks.suite import compare, loadgen, reference, weights
+from benchmarks.suite import archs, compare, loadgen, reference, weights
 from tests.benchsuite.standin import TINY_CONFIG
+
+
+def _logits(arch, w, tokens, config):
+    import jax.numpy as jnp
+
+    x = w["embedding"][tokens]
+    for i in range(config["num_hidden_layers"]):
+        lw = {n: w[f"layer_{i}.{n}"] for n in arch.LAYER_LEAVES}
+        x = arch.layer(x, lw, config, jnp.float32)
+    feats = reference.rms_norm(x, w["ln_final"], config["rms_norm_eps"],
+                               jnp.float32)
+    return feats @ w["lm_head"]
 
 
 def test_reference_and_transformer_lm_agree_on_logits():
@@ -16,12 +28,13 @@ def test_reference_and_transformer_lm_agree_on_logits():
     import jax.numpy as jnp
 
     from benchmarks.suite import program
-    from covalent_tpu_plugin.models import TransformerLM
     from covalent_tpu_plugin.parallel.sharding import unbox
 
     config, seed, seq = TINY_CONFIG, 2**31 + 3, 64
-    lm = TransformerLM(program.model_config(
-        config, max_seq=seq, attention="reference"))
+    arch = archs.load(config)
+    lm, _ = arch.program(config, {
+        "sequence": seq, "attention": "reference", "remat": False,
+        "vocab_chunk": config["vocab_size"]}, None)
     template = unbox(jax.eval_shape(lambda: lm.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
     params = program.place_weights(template, config, seed)
@@ -29,13 +42,7 @@ def test_reference_and_transformer_lm_agree_on_logits():
     got = lm.apply({"params": params}, tokens[None])[0]
 
     w = reference.all_leaves(config, seed, jnp.float32)
-    x = w["embedding"][tokens]
-    for i in range(config["num_hidden_layers"]):
-        lw = {n: w[f"layer_{i}.{n}"] for n in reference.LAYER_LEAVES}
-        x = reference.layer(x, lw, config, jnp.float32)
-    feats = reference.rms_norm(x, w["ln_final"], config["rms_norm_eps"],
-                               jnp.float32)
-    want = feats @ w["lm_head"]
+    want = _logits(arch, w, tokens, config)
     assert got.shape == want.shape == (seq, config["vocab_size"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
@@ -52,17 +59,12 @@ def test_serve_gaps_are_zero_for_the_references_own_greedy_tokens():
     # ... and the reference's own argmax reads none.
     import jax.numpy as jnp
 
+    arch = archs.load(config)
     w = reference.all_leaves(config, seed, jnp.float32)
     served = []
     for _ in range(3):
         tokens = jnp.asarray(prompt + served)
-        x = w["embedding"][tokens]
-        for i in range(config["num_hidden_layers"]):
-            lw = {n: w[f"layer_{i}.{n}"] for n in reference.LAYER_LEAVES}
-            x = reference.layer(x, lw, config, jnp.float32)
-        feats = reference.rms_norm(x, w["ln_final"], config["rms_norm_eps"],
-                                   jnp.float32)
-        served.append(int(jnp.argmax(feats[-1] @ w["lm_head"])))
+        served.append(int(jnp.argmax(_logits(arch, w, tokens, config)[-1])))
     gaps = reference.serve_gaps(config, seed, [(prompt, served)], 64, 16)[0]
     assert len(gaps) == 3 and max(gaps) < 1e-5
 
