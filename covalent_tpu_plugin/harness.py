@@ -250,15 +250,16 @@ def _process_start() -> float:
         return _T_LOADED
 
 
-def _jit_totals() -> dict | None:
-    """This process's own account of its compiles (``obs.jitstats``), where
-    the program loaded it; a plain-Python task never did, and nothing is
-    imported to ask."""
-    jitstats = sys.modules.get("covalent_tpu_plugin.obs.jitstats")
-    if jitstats is None:
+def _obs_totals(module: str) -> dict | None:
+    """``totals()`` of one of the program's own accounts (``obs.jitstats``:
+    its compiles; ``obs.modelstats``: what its train steps counted about
+    their model), where the program loaded the module and it has something
+    to say; a plain-Python task never did, and nothing is imported to ask."""
+    account = sys.modules.get(f"covalent_tpu_plugin.obs.{module}")
+    if account is None:
         return None
     try:
-        return jitstats.totals()
+        return account.totals() or None
     except Exception:  # noqa: BLE001 - observability never fails the task
         return None
 
@@ -270,9 +271,10 @@ def _trace_trailer(spans: list) -> bytes:
     of the pair is unaffected; ``utils.serialize.load_result_and_trailer``
     reads both."""
     trailer: dict = {"spans": spans}
-    jit = _jit_totals()
-    if jit is not None:
-        trailer["jit"] = jit
+    for key, module in (("jit", "jitstats"), ("model", "modelstats")):
+        totals = _obs_totals(module)
+        if totals is not None:
+            trailer[key] = totals
     try:
         return b"\n" + json.dumps(trailer, default=repr).encode() + b"\n"
     except (TypeError, ValueError):
@@ -1723,7 +1725,7 @@ def _run_rpc_task(command: dict, fn) -> None:
             heartbeat_stop.set()
     with _WorkerSpan(spans.append, "worker.store", trace):
         data = _pickle_rpc_result(result, exception)
-    jit = _jit_totals()
+    jit = _obs_totals("jitstats")
     _emit_rpc_event(
         spec, task_id, "worker.trace", spans=spans,
         **({"jit": jit} if jit is not None else {}),
@@ -2662,7 +2664,7 @@ class _ServeSession:
             extra["kv_fallbacks"] = self.kv_fallbacks
         if self.prefills:
             extra["prefills"] = self.prefills
-        jit = _jit_totals()
+        jit = _obs_totals("jitstats")
         if jit is not None:
             # Running totals of this runtime's compiles; the dispatcher
             # adds their growth into covalent_tpu_worker_jit_seconds_total.

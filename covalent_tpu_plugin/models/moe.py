@@ -19,9 +19,13 @@ Semantics (Switch Transformer):
 
 from __future__ import annotations
 
+import dataclasses
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from .layers import MlpBlock
 
 
 class MoEMlp(nn.Module):
@@ -107,6 +111,209 @@ class MoEMlp(nn.Module):
         out = jnp.einsum("nec,ecd->nd", combine, expert_out)
         out = out.reshape(batch, seq_len, d_model)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedExpertsConfig:
+    """Top-k of ``n_experts`` by sigmoid score (DeepSeek-V3's router,
+    arXiv:2412.19437, without its group limit), gated experts of ``d_ff``,
+    ``n_shared`` shared ones, and the share of the experts held here."""
+
+    n_experts: int
+    top_k: int
+    d_ff: int
+    n_shared: int = 1
+    routed_scaling: float = 1.0
+    norm_topk: bool = True
+    #: ``(first, count)``: the experts this layer holds and computes (expert
+    #: parallelism's share; the others' part of the result is left out, as
+    #: the chips that hold them would add it).  None = all of them.
+    held: tuple | None = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return (0, self.n_experts) if self.held is None else tuple(self.held)
+
+
+class Router(nn.Module):
+    """Scores in float32 over ALL the experts, whichever are held:
+    ``s = sigmoid(h W_r)``; the ``top_k`` of ``s + b`` are chosen (``b``:
+    the correction bias, which no gradient reaches), weighted ``s[chosen] /
+    sum * routed_scaling``."""
+
+    config: object  # TransformerConfig
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg, ex = self.config, self.config.routed
+        logits = nn.DenseGeneral(
+            features=ex.n_experts, use_bias=False, dtype=jnp.float32,
+            # A choice flips on the scores' last bits: no bf16 pass here.
+            precision=jax.lax.Precision.HIGHEST,
+            param_dtype=cfg.param_dtype, name="gate",
+            kernel_init=nn.with_partitioning(
+                nn.initializers.normal(0.02), ("embed", None)),
+        )(tokens.astype(jnp.float32))
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (ex.n_experts,), jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias), ex.top_k)     # (T, k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if ex.norm_topk:
+            weights = weights / (
+                jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return chosen, weights * ex.routed_scaling
+
+
+# (token, choice) pairs are numbered choice-major (pair ``c T + t``), so
+# that the pairs' own order is ``(k, T, C)``: k whole slabs, summed a token
+# by adding slabs, where token-major would put k on the tiles' second-minor
+# dimension and every reshape would copy.
+
+
+@jax.custom_vjp
+def _group_rows(tokens, order, inverse):
+    """``(T, C)`` -> ``(T k, C)``: row ``r`` is the token of pair
+    ``order[r]``.  The transpose gathers too (``inverse`` undoes
+    ``order``), where a plain gather's would scatter-add."""
+    return tokens[order % tokens.shape[0]]
+
+
+def _group_rows_fwd(tokens, order, inverse):
+    return _group_rows(tokens, order, inverse), (inverse, tokens.shape[0])
+
+
+def _group_rows_bwd(res, g):
+    inverse, n_tokens = res
+    return g[inverse].reshape(-1, n_tokens, g.shape[-1]).sum(
+        axis=0, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_group_rows.defvjp(_group_rows_fwd, _group_rows_bwd)
+
+
+@jax.custom_vjp
+def _ungroup_rows(rows, order, inverse):
+    """``(T k, C)`` rows in grouped order -> the pairs' own order."""
+    return rows[inverse]
+
+
+def _ungroup_rows_fwd(rows, order, inverse):
+    return rows[inverse], (order,)
+
+
+def _ungroup_rows_bwd(res, g):
+    return g[res[0]], None, None
+
+
+_ungroup_rows.defvjp(_ungroup_rows_fwd, _ungroup_rows_bwd)
+
+
+class HeldExperts(nn.Module):
+    """The held experts' part of the routed result.  Every (token, choice)
+    pair whose expert is held becomes a row; rows are grouped by expert (a
+    stable sort, the others' pairs last) and each of the three matmuls is
+    one grouped product over the held experts (``jax.lax.ragged_dot``).
+    The row buffer has room for every pair, so no row is ever dropped,
+    whatever the imbalance; rows past the held ones are masked, not
+    computed on."""
+
+    config: object  # TransformerConfig
+
+    @nn.compact
+    def __call__(self, tokens, chosen, weights):
+        cfg, ex = self.config, self.config.routed
+        first, held = ex.held_range
+        n_tokens, d_model = tokens.shape
+
+        def kernel(name, shape, axes, std):
+            return self.param(
+                name,
+                nn.with_partitioning(nn.initializers.normal(std), axes),
+                shape, cfg.param_dtype).astype(cfg.dtype)
+
+        wg = kernel("wg", (held, d_model, ex.d_ff),
+                    ("expert", "embed", "expert_mlp"), 0.02)
+        wu = kernel("wu", (held, d_model, ex.d_ff),
+                    ("expert", "embed", "expert_mlp"), 0.02)
+        wd = kernel("wd", (held, ex.d_ff, d_model),
+                    ("expert", "expert_mlp", "embed"),
+                    0.02 / (2 * cfg.n_layers) ** 0.5)
+
+        local = chosen.T.reshape(-1) - first          # (k T,), choice-major
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held)                   # others last
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+        n_rows = jnp.sum(group_sizes)
+        # Rows past the held ones belong to no group.
+        live = (jnp.arange(order.shape[0]) < n_rows)[:, None]
+        rows = _group_rows(tokens, order, inverse)
+        # A row's weight goes onto its hidden row (1024 wide) and not onto
+        # its output (3584 wide): scaling rows commutes with the product.
+        # Rows past the held ones are whatever a grouped product leaves
+        # there: each product's are masked, so that nothing of them
+        # reaches a later product or, transposed, a gradient.
+        row_weight = weights.T.reshape(-1)[order][:, None].astype(cfg.dtype)
+
+        def grouped(lhs, kernels):
+            # Masked going in as well: transposed, that zeroes the dead
+            # rows of the product's gradient, which are as undefined (a
+            # NaN there times a masked zero is a NaN in the router).
+            return jnp.where(live, jax.lax.ragged_dot(
+                jnp.where(live, lhs, 0), kernels, group_sizes), 0)
+
+        h = nn.silu(grouped(rows, wg)) * grouped(rows, wu) * row_weight
+        out = grouped(h, wd)
+        routed = jnp.sum(
+            _ungroup_rows(out, order, inverse).reshape(
+                ex.top_k, n_tokens, d_model), axis=0, dtype=jnp.float32,
+        ).astype(cfg.dtype)
+        # What a trace cannot see: the rows this step sent through the held
+        # experts, the most loaded one's over the mean, and the held pairs
+        # that got no row (none, by construction: counted, not assumed).
+        self.sow("intermediates", "moe_stats", jnp.stack([
+            n_rows.astype(jnp.float32),
+            jnp.max(group_sizes) * held / jnp.maximum(n_rows, 1),
+            (jnp.sum(is_held) - jnp.sum(live)).astype(jnp.float32),
+        ]))
+        return routed
+
+
+class RoutedExperts(nn.Module):
+    """Drop-in MLP replacement: ``sum over chosen and held g_e E_e(h) +
+    shared(h)``, ``E`` and ``shared`` gated MLPs.  No balance term: the
+    router's bias is corrected outside the gradient (``noaux_tc``)."""
+
+    config: object  # TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, ex = self.config, self.config.routed
+        batch, seq_len, d_model = x.shape
+        tokens = x.reshape(batch * seq_len, d_model)
+        chosen, weights = Router(cfg, name="router")(tokens)
+        out = HeldExperts(cfg, name="experts")(tokens, chosen, weights)
+        out = out.reshape(batch, seq_len, d_model)
+        if ex.n_shared:
+            out = out + MlpBlock(
+                cfg, d_ff=ex.n_shared * ex.d_ff, name="shared_expert")(x)
+        return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
+
+
+def collect_moe_stats(intermediates):
+    """Every sown ``moe_stats`` triple, stacked ``(layers, 3)``: held rows,
+    peak load over mean, dropped rows; None where no layer sowed one."""
+    found = [
+        jnp.reshape(leaf, (-1, 3))
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            intermediates)[0]
+        if any(getattr(entry, "key", None) == "moe_stats" for entry in path)
+    ]
+    return jnp.concatenate(found) if found else None
 
 
 def collect_moe_aux(intermediates) -> jax.Array:

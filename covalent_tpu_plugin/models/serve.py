@@ -175,11 +175,32 @@ class _AdapterDecoder:
         return out
 
 
+class BlockUnsupported(ValueError):
+    """Typed refusal: continuous serving has no cache for this block form.
+
+    Latent attention would cache its latent, routed experts decode through
+    grouped products, and n residual streams change what a lane's state
+    is: the engine has none of the three, and running such a model through
+    the plain K/V path would serve wrong tokens.  Duck-tagged PERMANENT
+    like :class:`RollingCacheUnsupported`."""
+
+    fault_label = "serve_model_unsupported"
+    fault_transient = False
+
+
 def _require_plain_cache(config, what: str) -> None:
     if config.rolling_cache:
         raise RollingCacheUnsupported(
             f"{what} does not support rolling_cache models "
             "(slot reset assumes the plain cache layout)"
+        )
+    held = [name for name in ("latent", "routed", "streams")
+            if getattr(config, name, None) is not None]
+    if held:
+        raise BlockUnsupported(
+            f"{what} does not serve a model with {', '.join(held)} set "
+            "(latent attention, routed experts, residual streams): the "
+            "train path runs it, the engine has no cache for it yet"
         )
 
 

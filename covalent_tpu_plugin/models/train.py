@@ -19,7 +19,13 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh
 
+from ..obs import modelstats
 from ..parallel.sharding import DEFAULT_RULES, replicated
+
+
+#: The metrics key under which a loss's second return value (what the model
+#: counted in this step) leaves the compiled step.
+MODEL_STATS = "model_stats"
 
 
 class TrainState(train_state.TrainState):
@@ -96,19 +102,30 @@ def make_train_step(
     microbatches this equals the full-batch gradient up to f32
     reduction-order rounding (the accumulator is f32 regardless of param
     dtype).
+
+    A loss may return ``(loss, stats)``: what the model counted in this
+    pass (``lm_loss`` over routed layers) leaves the step beside the
+    metrics and goes to ``obs.modelstats``; under accumulation the counts
+    are not kept.
     """
     def grads_of(params, apply_fn, batch):
         # Scoped so a device trace can tell the loss's forward and backward
         # from the optimizer's update (flax already scopes the modules).
+        def scalar_and_stats(p):
+            out = loss_fn(p, apply_fn, batch)
+            return out if isinstance(out, tuple) else (out, None)
+
         with jax.named_scope("loss"):
-            return jax.value_and_grad(
-                lambda p: loss_fn(p, apply_fn, batch)
-            )(params)
+            (loss, stats), grads = jax.value_and_grad(
+                scalar_and_stats, has_aux=True)(params)
+        return loss, grads, stats
 
     def step(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
         with nn.logical_axis_rules(list(rules)):
+            stats = None
             if accumulate_steps == 1:
-                loss, grads = grads_of(state.params, state.apply_fn, batch)
+                loss, grads, stats = grads_of(
+                    state.params, state.apply_fn, batch)
             else:
                 lead = {
                     leaf.shape[0] for leaf in jax.tree_util.tree_leaves(batch)
@@ -122,7 +139,7 @@ def make_train_step(
 
                 def micro(carry, microbatch):
                     loss_acc, grads_acc = carry
-                    loss, grads = grads_of(
+                    loss, grads, _ = grads_of(
                         state.params, state.apply_fn, microbatch
                     )
                     return (
@@ -149,19 +166,38 @@ def make_train_step(
                 "grad_norm": optax.global_norm(grads),
                 "step": new_state.step,
             }
+            if stats is not None:
+                metrics[MODEL_STATS] = stats
             return new_state, metrics
 
-    metrics_sharding = {
-        "loss": replicated(mesh),
-        "grad_norm": replicated(mesh),
-        "step": replicated(mesh),
-    }
-    return jax.jit(
+    return _TrainStep(jax.jit(
         step,
         in_shardings=(state_shardings, None),
-        out_shardings=(state_shardings, metrics_sharding),
+        # One sharding for the whole metrics dict, whatever a loss adds.
+        out_shardings=(state_shardings, replicated(mesh)),
         donate_argnums=(0,) if donate_state else (),
-    )
+    ))
+
+
+class _TrainStep:
+    """The jitted step, called like it and standing for it (``lower``,
+    ``trace``, ...).  Where the loss returns ``(loss, stats)``, the stats
+    leave the compiled step beside the metrics and go to ``obs.modelstats``
+    (read a step late, so that no step waits for them) in place of the
+    caller's dict."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, state, batch):
+        state, metrics = self._jitted(state, batch)
+        stats = metrics.pop(MODEL_STATS, None)
+        if stats is not None:
+            modelstats.defer(stats)
+        return state, metrics
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
 
 
 def classifier_loss(params, apply_fn, batch):
@@ -172,6 +208,10 @@ def classifier_loss(params, apply_fn, batch):
 def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     """Next-token loss over a {"tokens": (B, S)} batch.
 
+    Where the model's routed layers counted in this pass (``models/moe.py``
+    ``moe_stats``) it returns ``(loss, counts)``, which ``make_train_step``
+    sends to ``obs.modelstats``; the loss alone where no layer counted.
+
     ``vocab_chunk`` switches to the fused cross-entropy (``ops/xent.py``):
     the model returns final FEATURES and the loss walks them in tiles of
     rows, each tile's scores the whole vocabulary wide, forming the
@@ -180,15 +220,22 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     vocabulary) bounds the scores live at once, at B·S x vocab_chunk
     elements.  Requires a plain float lm_head kernel (no lm_head LoRA,
     unquantized)."""
+    from .moe import collect_moe_stats
+
     tokens = batch["tokens"]
+    options = {} if vocab_chunk is None else {"return_features": True}
+    out, sown = apply_fn({"params": params}, tokens[:, :-1],
+                         mutable=["intermediates"], **options)
+    counted = collect_moe_stats(sown.get("intermediates", {}))
+
+    def result(loss):
+        return loss if counted is None else (loss, counted)
+
     if vocab_chunk is None:
-        logits = apply_fn({"params": params}, tokens[:, :-1])
-        return cross_entropy_loss(logits, tokens[:, 1:])
+        return result(cross_entropy_loss(out, tokens[:, 1:]))
     from ..ops.xent import fused_cross_entropy
 
-    feats = apply_fn(
-        {"params": params}, tokens[:, :-1], return_features=True
-    )
+    feats = out
     from flax.core import meta as flax_meta
 
     # The kernel may ride in a flax Partitioned box (sharded init path).
@@ -206,7 +253,7 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
         )
     flat = feats.reshape(-1, feats.shape[-1])
     labels = tokens[:, 1:].reshape(-1)
-    return fused_cross_entropy(flat, kernel, labels, vocab_chunk)
+    return result(fused_cross_entropy(flat, kernel, labels, vocab_chunk))
 
 
 def make_lm_train_step(mesh, state_shardings, rules=DEFAULT_RULES):

@@ -32,8 +32,11 @@ from ..ops.attention import (
     on_tpu,
 )
 from ..ops.ring_attention import sequence_parallel_attention
-from .moe import MoEMlp
+from .latent import LatentAttention, LatentAttentionConfig
+from .layers import MlpBlock, RMSNorm, rotary as _rotary
+from .moe import MoEMlp, RoutedExperts, RoutedExpertsConfig
 from .quant import dense_general
+from .streams import ResidualStreamsConfig, StreamMix
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +68,12 @@ class TransformerConfig:
     #: (jax dots_with_no_batch_dims_saveable) — ~half the recompute FLOPs for
     #: a modest activation-memory increase.
     remat_policy: str = "full"
+    #: False lets XLA merge remat's second forward with the first wherever
+    #: it judges that free, and keep what the first made (unrolled layers:
+    #: it merges every one of a plain block's, so remat then buys no
+    #: memory); True fences each layer's recompute off, which is what
+    #: saves the memory, at the price of running it.
+    remat_prevent_cse: bool = False
     #: lax.scan over the block stack keeps compile time O(1) in depth, but
     #: blocks XLA from fusing/scheduling across block boundaries — unrolled
     #: (False) was ~33% faster on the train step at 12 layers in an earlier
@@ -123,8 +132,37 @@ class TransformerConfig:
     lora_targets: tuple = (
         "q_proj", "k_proj", "v_proj", "out_proj", "wi", "wo",
     )
+    #: gated MLP: ``wo(act(wg x) * (wi x))`` (SwiGLU with "silu") in place
+    #: of ``wo(act(wi x))``.
+    mlp_gated: bool = False
+    mlp_activation: str = "gelu"
+    #: one kind a layer, "dense" (``MlpBlock``) or "moe" (routed experts),
+    #: for a model whose leading layers are dense and the rest expert
+    #: layers; None = every layer alike ("moe" where ``moe_experts`` or
+    #: ``routed`` is set).  Mixed kinds need ``scan_layers=False``.
+    layer_kinds: tuple | None = None
+    #: latent attention (models/latent.py) in place of ``Attention``.
+    latent: LatentAttentionConfig | None = None
+    #: top-k sigmoid-routed gated experts with shared ones and a held share
+    #: (models/moe.py ``RoutedExperts``) in the "moe" layers.
+    routed: RoutedExpertsConfig | None = None
+    #: n residual streams mixed around every sublayer (models/streams.py).
+    streams: ResidualStreamsConfig | None = None
 
     def __post_init__(self):
+        kinds = self.layer_kinds
+        if kinds is not None:
+            if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe"}:
+                raise ValueError(
+                    f"layer_kinds must give 'dense' or 'moe' for each of "
+                    f"{self.n_layers} layers, got {kinds!r}"
+                )
+            if "moe" in kinds and not (self.routed or self.moe_experts > 0):
+                raise ValueError("a 'moe' layer needs routed or moe_experts")
+            if self.scan_layers and len(set(kinds)) > 1:
+                raise ValueError(
+                    "layers of two kinds cannot be scanned: scan_layers=False"
+                )
         if self.sliding_window is not None and self.sliding_window < 1:
             # Validated here (not only in the kernels) because the cached
             # decode path masks the band itself — a 0/negative window there
@@ -146,43 +184,15 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def kind_of(self, layer: int) -> str:
+        if self.layer_kinds is not None:
+            return self.layer_kinds[layer]
+        return "moe" if self.routed or self.moe_experts > 0 else "dense"
+
 
 def lm_125m_config(**overrides) -> TransformerConfig:
     """GPT-2-small-class preset (~125M params with a 32k vocab)."""
     return TransformerConfig(**overrides)
-
-
-def _rotary(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
-    """Rotary position embedding over (B, S, H, D) with D even.
-
-    ``offset`` shifts the position index — incremental decoding applies the
-    embedding for absolute position ``offset + t`` to a length-1 slice.
-    """
-    _, seq_len, _, head_dim = x.shape
-    half = head_dim // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    positions = offset + jnp.arange(seq_len, dtype=jnp.float32)
-    angles = positions[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
-class RMSNorm(nn.Module):
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param(
-            "scale",
-            nn.with_partitioning(nn.initializers.ones_init(), ("embed",)),
-            (x.shape[-1],),
-            jnp.float32,
-        )
-        x32 = x.astype(jnp.float32)
-        norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
-        return (norm * scale).astype(self.dtype)
 
 
 class Attention(nn.Module):
@@ -502,53 +512,39 @@ class Attention(nn.Module):
         return self._out_proj(out.astype(cfg.dtype))
 
 
-class MlpBlock(nn.Module):
+class Block(nn.Module):
+    """Attention, then an MLP of this layer's ``kind``, each behind its own
+    pre-norm: added to the one residual stream, or (``config.streams``)
+    mixed into the n streams ``x`` then is, ``(B, n, S, C)``."""
+
     config: TransformerConfig
+    kind: str = "dense"
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        h = dense_general(
-            cfg.quantized,
-            features=cfg.d_ff,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.initializers.normal(0.02),
-            kernel_axes=("embed", "mlp"),
-            name="wi",
-            lora_rank=cfg.lora_rank if "wi" in cfg.lora_targets else 0,
-            lora_alpha=cfg.lora_alpha,
-        )(x)
-        h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
-        h = nn.gelu(h)
-        h = dense_general(
-            cfg.quantized,
-            features=cfg.d_model,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.initializers.normal(0.02 / (2 * cfg.n_layers) ** 0.5),
-            kernel_axes=("mlp", "embed"),
-            name="wo",
-            lora_rank=cfg.lora_rank if "wo" in cfg.lora_targets else 0,
-            lora_alpha=cfg.lora_alpha,
-        )(h)
-        return nn.with_logical_constraint(h, ("batch", "seq", "embed"))
-
-
-class Block(nn.Module):
-    config: TransformerConfig
-
-    @nn.compact
-    def __call__(self, x):
-        x = x + Attention(self.config, name="attention")(
-            RMSNorm(self.config.dtype, name="ln_attn")(x)
-        )
-        if self.config.moe_experts > 0:
-            mlp = MoEMlp(self.config, name="moe")
+        if cfg.latent is not None:
+            attention = LatentAttention(cfg, name="attention")
         else:
-            mlp = MlpBlock(self.config, name="mlp")
-        x = x + mlp(RMSNorm(self.config.dtype, name="ln_mlp")(x))
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+            attention = Attention(cfg, name="attention")
+        if self.kind != "moe":
+            mlp = MlpBlock(cfg, name="mlp")
+        elif cfg.routed is not None:
+            mlp = RoutedExperts(cfg, name="moe")
+        else:
+            mlp = MoEMlp(cfg, name="moe")
+        for tag, sublayer in (("attn", attention), ("mlp", mlp)):
+            norm = RMSNorm(cfg.dtype, name=f"ln_{tag}")
+            if cfg.streams is None:
+                x = x + sublayer(norm(x))
+                continue
+            streams = StreamMix(cfg, name=f"hc_{tag}")
+            u, coefficients = streams.coefficients(x)
+            x = streams.mix(x, sublayer(norm(u)), coefficients)
+        axes = ("batch", "seq", "embed")
+        if cfg.streams is not None:
+            axes = ("batch", None, "seq", "embed")
+        return nn.with_logical_constraint(x, axes)
 
 
 class TransformerLM(nn.Module):
@@ -572,6 +568,10 @@ class TransformerLM(nn.Module):
         )
         x = jnp.asarray(embedding, cfg.dtype)[tokens]
         x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        if cfg.streams is not None:
+            # Every stream starts as the embedding.
+            x = jnp.broadcast_to(
+                x[:, None], (x.shape[0], cfg.streams.n) + x.shape[1:])
 
         block_cls = Block
         if cfg.remat:
@@ -582,7 +582,8 @@ class TransformerLM(nn.Module):
                 raise ValueError(
                     f"remat_policy must be 'full' or 'dots', got {cfg.remat_policy!r}"
                 )
-            block_cls = nn.remat(Block, prevent_cse=False, policy=policy)
+            block_cls = nn.remat(
+                Block, prevent_cse=cfg.remat_prevent_cse, policy=policy)
         if cfg.scan_layers:
             x, _ = nn.scan(
                 lambda module, carry, _: (module(carry), None),
@@ -592,11 +593,13 @@ class TransformerLM(nn.Module):
                 split_rngs={"params": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(block_cls(cfg, name="layers"), x, None)
+            )(block_cls(cfg, kind=cfg.kind_of(0), name="layers"), x, None)
         else:
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, name=f"layer_{i}")(x)
+                x = block_cls(cfg, kind=cfg.kind_of(i), name=f"layer_{i}")(x)
 
+        if cfg.streams is not None:
+            x = jnp.sum(x.astype(jnp.float32), axis=1).astype(cfg.dtype)
         x = RMSNorm(cfg.dtype, name="ln_final")(x)
         if return_features:
             # The fused-xent training path (ops/xent.py) consumes the

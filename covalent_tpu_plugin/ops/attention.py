@@ -510,10 +510,14 @@ def _flash_forward(
     q, k, v, q_positions, k_positions, causal: bool,
     block_q: int | None, block_k: int | None, interpret: bool,
     out_dtype=None, window: int | None = None, sinks: int = 0,
+    scale: float | None = None,
 ):
+    # Q and K share one width (the scores' contraction), V and the output
+    # another: latent attention scores with 192 dims and mixes values of 128.
     batch, heads, seq_len, head_dim = q.shape
+    value_dim = v.shape[3]
     seq_len_k = k.shape[2]
-    scale = head_dim**-0.5
+    scale = head_dim**-0.5 if scale is None else scale
     # Default (None) blocks adapt to the sequence: the tuned sweep winners
     # shrink by halving until they divide seq_len, so any even-ish length
     # works out of the box.  EXPLICIT blocks stay strict — a user-chosen
@@ -549,16 +553,19 @@ def _flash_forward(
         window is not None and causal and contiguous, sinks,
     )
     grid = (batch, heads, seq_len // block_q, steps)
-    qo_spec = pl.BlockSpec(
-        (1, 1, block_q, head_dim), lambda b, h, i, j: (b, h, i, 0)
-    )
+
+    def q_side(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width), lambda b, h, i, j: (b, h, i, 0))
+
+    def k_side(width):
+        # GQA: each query head reads its group's shared kv head (h // group).
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, h, i, j: (b, h // group, _kj(i, j), 0))
+
     qpos_spec = pl.BlockSpec((block_q, 1), lambda b, h, i, j: (i, 0))
     lse_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
-    # GQA: each query head reads its group's shared kv head (h // group).
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, head_dim),
-        lambda b, h, i, j: (b, h // group, _kj(i, j), 0),
-    )
     kpos_spec = pl.BlockSpec((1, block_k), lambda b, h, i, j: (0, _kj(i, j)))
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=scale, window=window,
@@ -576,22 +583,26 @@ def _flash_forward(
         kernel,
         name="flash_fwd",
         grid=grid,
-        in_specs=[qo_spec, kv_spec, kv_spec, qpos_spec, kpos_spec],
-        out_specs=[qo_spec, lse_spec],
+        in_specs=[q_side(head_dim), k_side(head_dim), k_side(value_dim),
+                  qpos_spec, kpos_spec],
+        out_specs=[q_side(value_dim), lse_spec],
         out_shape=[
             # out_dtype=f32 lets ring callers merge unrounded block partials
-            jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
+            jax.ShapeDtypeStruct(
+                (batch, heads, seq_len, value_dim), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((batch, heads, seq_len, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),        # running max
             pltpu.VMEM((block_q, 1), jnp.float32),        # running sum
-            pltpu.VMEM((block_q, head_dim), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, value_dim), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * batch * heads * seq_len * seq_len_k * head_dim * flops_factor),
-            bytes_accessed=int(4 * batch * heads * seq_len * head_dim * q.dtype.itemsize),
+            flops=int(2 * batch * heads * seq_len * seq_len_k
+                      * (head_dim + value_dim) * flops_factor),
+            bytes_accessed=int(2 * batch * heads * seq_len
+                               * (head_dim + value_dim) * q.dtype.itemsize),
             transcendentals=int(batch * heads * seq_len * seq_len_k * flops_factor),
         ),
     )(q, k, v, qpos, kpos)
@@ -784,14 +795,15 @@ def _flash_bwd_dq_kernel(
 def _flash_backward(
     q, k, v, out, lse, g, q_positions, k_positions, causal: bool,
     interpret: bool, delta=None, grad_dtype=None, window: int | None = None,
-    sinks: int = 0,
+    sinks: int = 0, scale: float | None = None,
 ):
     """FlashAttention-2 backward: two Pallas sweeps, O(S·D) HBM."""
     batch, heads, seq_len, head_dim = q.shape
+    value_dim = v.shape[3]  # V's, O's and dO's width; Q and K share head_dim
     kv_heads = k.shape[1]
     seq_len_k = k.shape[2]
     group = _gqa_group(q, k)
-    scale = head_dim**-0.5
+    scale = head_dim**-0.5 if scale is None else scale
     block_q = _fit_block(_DEFAULT_BWD_BLOCK, seq_len)
     block_k = _fit_block(_DEFAULT_BWD_BLOCK, seq_len_k)
     qpos, kpos = _positions_2d(q_positions, k_positions, seq_len, seq_len_k)
@@ -812,8 +824,10 @@ def _flash_backward(
         # overstate a w<<S kernel by ~S/(2w) and skew latency-hiding.
         flops_factor = min(flops_factor, (window + sinks) / max(seq_len_k, 1))
     cost = pl.CostEstimate(
-        flops=int(10 * batch * heads * seq_len * seq_len_k * head_dim * flops_factor),
-        bytes_accessed=int(8 * batch * heads * seq_len * head_dim * q.dtype.itemsize),
+        flops=int(batch * heads * seq_len * seq_len_k
+                  * (6 * head_dim + 4 * value_dim) * flops_factor),
+        bytes_accessed=int(4 * batch * heads * seq_len
+                           * (head_dim + value_dim) * q.dtype.itemsize),
         transcendentals=int(batch * heads * seq_len * seq_len_k * flops_factor),
     )
 
@@ -833,17 +847,20 @@ def _flash_backward(
     # dk/dv slabs that concatenate back to (B, H_kv, S, D).
     def run_dkdv(kt_offset, kt_n, qi, band, n_inner):
         """One dk/dv pallas_call over key tiles [kt_offset, kt_offset+kt_n)."""
-        qo_spec_q = pl.BlockSpec(
-            (1, 1, block_q, head_dim),
-            lambda b, h, i, gi, j: (b, h * group + gi, qi(i, j), 0),
-        )
-        kv_spec_in = pl.BlockSpec(
-            (1, 1, block_k, head_dim),
-            lambda b, h, i, gi, j: (b, h, i + kt_offset, 0),
-        )
-        kv_spec_out = pl.BlockSpec(
-            (1, 1, block_k, head_dim), lambda b, h, i, gi, j: (b, h, i, 0)
-        )
+        def q_side(width):
+            return pl.BlockSpec(
+                (1, 1, block_q, width),
+                lambda b, h, i, gi, j: (b, h * group + gi, qi(i, j), 0))
+
+        def k_in(width):
+            return pl.BlockSpec(
+                (1, 1, block_k, width),
+                lambda b, h, i, gi, j: (b, h, i + kt_offset, 0))
+
+        def k_out(width):
+            return pl.BlockSpec(
+                (1, 1, block_k, width), lambda b, h, i, gi, j: (b, h, i, 0))
+
         stat_spec_q = pl.BlockSpec(
             (1, 1, block_q, 1),
             lambda b, h, i, gi, j: (b, h * group + gi, qi(i, j), 0),
@@ -861,9 +878,10 @@ def _flash_backward(
             ),
             name="flash_bwd_dkdv",
             grid=(batch, kv_heads, kt_n, group, n_inner),
-            in_specs=[qo_spec_q, kv_spec_in, kv_spec_in, qo_spec_q,
-                      stat_spec_q, stat_spec_q, qpos_spec_q, kpos_spec_k],
-            out_specs=[kv_spec_out, kv_spec_out],
+            in_specs=[q_side(head_dim), k_in(head_dim), k_in(value_dim),
+                      q_side(value_dim), stat_spec_q, stat_spec_q,
+                      qpos_spec_q, kpos_spec_k],
+            out_specs=[k_out(head_dim), k_out(value_dim)],
             out_shape=[
                 # grad_dtype=f32: ring callers sum one partial per hop and
                 # must not pay a bf16 rounding at every hop
@@ -872,13 +890,13 @@ def _flash_backward(
                     grad_dtype or k.dtype,
                 ),
                 jax.ShapeDtypeStruct(
-                    (batch, kv_heads, kt_n * block_k, head_dim),
+                    (batch, kv_heads, kt_n * block_k, value_dim),
                     grad_dtype or v.dtype,
                 ),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, head_dim), jnp.float32),  # dk acc
-                pltpu.VMEM((block_k, head_dim), jnp.float32),  # dv acc
+                pltpu.VMEM((block_k, value_dim), jnp.float32),  # dv acc
             ],
             interpret=interpret,
             cost_estimate=cost,
@@ -916,13 +934,15 @@ def _flash_backward(
         seq_len, seq_len_k, block_q, block_k, window, banded, sinks
     )
 
-    qo_spec_i = pl.BlockSpec(
-        (1, 1, block_q, head_dim), lambda b, h, i, j: (b, h, i, 0)
-    )
-    kv_spec_j = pl.BlockSpec(
-        (1, 1, block_k, head_dim),
-        lambda b, h, i, j: (b, h // group, _kj(i, j), 0),
-    )
+    def q_side_i(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width), lambda b, h, i, j: (b, h, i, 0))
+
+    def k_side_j(width):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, h, i, j: (b, h // group, _kj(i, j), 0))
+
     stat_spec_i = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
     qpos_spec_i = pl.BlockSpec((block_q, 1), lambda b, h, i, j: (i, 0))
     kpos_spec_j = pl.BlockSpec((1, block_k), lambda b, h, i, j: (0, _kj(i, j)))
@@ -933,9 +953,10 @@ def _flash_backward(
         ),
         name="flash_bwd_dq",
         grid=(batch, heads, qt_full, n_inner_kt),
-        in_specs=[qo_spec_i, kv_spec_j, kv_spec_j, qo_spec_i, stat_spec_i,
-                  stat_spec_i, qpos_spec_i, kpos_spec_j],
-        out_specs=qo_spec_i,
+        in_specs=[q_side_i(head_dim), k_side_j(head_dim), k_side_j(value_dim),
+                  q_side_i(value_dim), stat_spec_i, stat_spec_i, qpos_spec_i,
+                  kpos_spec_j],
+        out_specs=q_side_i(head_dim),
         out_shape=jax.ShapeDtypeStruct(q.shape, grad_dtype or q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, head_dim), jnp.float32),  # dq accumulator
@@ -953,31 +974,31 @@ def _pos_zero(positions):
     return jnp.zeros(jnp.shape(positions), dtype=jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, q_positions, k_positions, causal, block_q, block_k,
-           interpret, window, sinks):
+           interpret, window, sinks, scale):
     out, _ = _flash_forward(
         q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret,
-        window=window, sinks=sinks,
+        window=window, sinks=sinks, scale=scale,
     )
     return out
 
 
 def _flash_fwd(q, k, v, q_positions, k_positions, causal, block_q, block_k,
-               interpret, window, sinks):
+               interpret, window, sinks, scale):
     out, lse = _flash_forward(
         q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret,
-        window=window, sinks=sinks,
+        window=window, sinks=sinks, scale=scale,
     )
     return out, (q, k, v, out, lse, q_positions, k_positions)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, window, sinks,
+def _flash_bwd(causal, block_q, block_k, interpret, window, sinks, scale,
                residuals, g):
     q, k, v, out, lse, q_positions, k_positions = residuals
     dq, dk, dv = _flash_backward(
         q, k, v, out, lse, g, q_positions, k_positions, causal, interpret,
-        window=window, sinks=sinks,
+        window=window, sinks=sinks, scale=scale,
     )
     return dq, dk, dv, _pos_zero(q_positions), _pos_zero(k_positions)
 
@@ -998,8 +1019,16 @@ def flash_attention(
     interpret: bool | None = None,
     window: int | None = None,
     sinks: int = 0,
+    scale: float | None = None,
 ) -> jax.Array:
     """Flash attention over (B, H, S, D) inputs.
+
+    ``q`` and ``k`` share one width and ``v`` may have another (latent
+    attention scores with 192 dims a head and mixes values of 128): the
+    output and ``dv`` take ``v``'s width, ``dq`` and ``dk`` the scores'.
+    ``scale`` multiplies the scores (default: the query width's inverse
+    square root); a model whose scale carries more (YaRN's factor) passes
+    its own.
 
     Grouped-query attention: ``k``/``v`` may have fewer heads than ``q``
     (``H_q % H_kv == 0``); kv head ``i`` serves query heads
@@ -1035,9 +1064,14 @@ def flash_attention(
     _check_window(window, causal, sinks)
     if interpret is None:
         interpret = default_interpret()
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"q and k must share the scores' width, got {q.shape[-1]} and "
+            f"{k.shape[-1]}"
+        )
     return _flash(
         q, k, v, q_positions, k_positions, causal, block_q, block_k,
-        interpret, window, sinks,
+        interpret, window, sinks, scale,
     )
 
 

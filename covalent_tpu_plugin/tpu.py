@@ -83,7 +83,7 @@ from .obs.opsserver import (
     unregister_profile_provider,
     unregister_status_provider,
 )
-from .obs import jitstats
+from .obs import jitstats, modelstats
 from .obs.trace import Span, context_of, record_remote_span
 from .parallel.distributed import coordinator_spec
 from .serving.metrics import SERVE_WORKER_SLOTS
@@ -2215,6 +2215,10 @@ class TPUExecutor(RemoteExecutor):
         jitstats.absorb_worker(
             record.get("jit"), seen, source=record.get("pid")
         )
+        if seen is None:
+            # A launch-mode result's trailer: the worker's final word.  A
+            # resident runtime's running totals are not absorbed yet.
+            modelstats.absorb_worker(record.get("model"))
 
     # ------------------------------------------------------------------ #
     # Elastic gangs: checkpoint records, mirroring, resume discovery      #
