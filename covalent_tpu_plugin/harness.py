@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import struct
 import sys
 import threading
@@ -730,8 +731,10 @@ def _to_host(tree):
 def _apply_spec_env(spec: dict) -> None:
     """Apply the task's env contract to THIS process.
 
-    os.environ entries, a sys.path mirror for PYTHONPATH, and the jax
-    platform pin.  Shared by the per-task harness (``run_task``) and RPC
+    os.environ entries, a sys.path mirror for PYTHONPATH, the jax platform
+    pin, and this file's own directory taken out of the file names jax
+    records (``JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX``, where the task's
+    env does not set it).  Shared by the per-task harness (``run_task``) and RPC
     invocations executing inside the resident server — one server serves
     one executor, so ``task_env`` is constant across its invocations and
     the process-wide mutation is idempotent by construction.
@@ -744,6 +747,14 @@ def _apply_spec_env(spec: dict) -> None:
     env = spec.get("env") or {}
     for key, value in env.items():
         os.environ[key] = str(value)
+    # jax writes the Python traceback of every operation into what it lowers,
+    # a Mosaic kernel's payload among it, and that keys the persistent compile
+    # cache.  A frame of this file is in reach of those tracebacks, and its
+    # staged copy lies in a directory the executor picked for this run: taken
+    # out of the file names, the same program finds its cache entry again.
+    os.environ.setdefault(
+        "JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX",
+        re.escape(os.path.dirname(os.path.abspath(__file__)) + os.sep))
     if "PYTHONPATH" in env:
         # The interpreter already started; os.environ alone no longer affects
         # import resolution.  Mirror the entries into sys.path so task_env

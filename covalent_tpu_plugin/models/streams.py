@@ -28,6 +28,22 @@ float32: ``F`` norms its input, so ``dL/du`` is orthogonal to ``u`` and
 ``H_pre``'s gradient ``du . X[j]`` is what is left of a cancellation, which
 a ``u`` or a ``du`` rounded to bfloat16 buries in noise while the streams
 are nearly equal.
+
+**What runs where.**  A sublayer's mixing passes over ``X`` four times:
+``u`` and the coefficients' products before the sublayer, ``X'`` after it,
+and the transposes of both.  Where the shape tiles
+(``ops.stream_mix.token_tile``: the width a multiple of 128, the sequence
+of a tile of 16 to 512 tokens, ``X`` in bfloat16 or float32) each pass is
+one Pallas kernel of ``ops/stream_mix.py``, ``hc_pre_fwd``, ``hc_res_fwd``,
+``hc_res_bwd``, ``hc_pre_bwd``: every operand the size of ``X`` read once,
+the arithmetic in float32 on a tile in VMEM, ``H_pre`` made inside the
+``hc_pre_*`` kernels, and all four terms of ``dX`` (the products', the
+norm's, ``H_pre du`` and what came back through ``H_res``) summed in
+float32 and rounded once.  Any other shape takes the jnp passes below, each
+with its transpose written out; they are the kernels' oracle in the tests.
+What is the coefficients' size, ``(B, 24, S)``, stays here under autodiff
+on either path: the scaling by ``rsqrt``, ``H_post``'s sigmoid and
+Sinkhorn's scan.
 """
 
 from __future__ import annotations
@@ -37,6 +53,8 @@ import dataclasses
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops import stream_mix
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,11 +82,14 @@ def sinkhorn_knopp(m, iters: int, eps: float):
     return jax.lax.scan(one_round, m, None, length=iters)[0]
 
 
-# The three passes over the streams, each with its transpose written out.
-# Left to autodiff, every product of a coefficient and a stream leaves a
-# float32 cotangent the size of a stream behind (twenty of them for one
-# sublayer's mix at n = 4); written out, a pass reads the streams and their
-# cotangent once and writes what it owes in the activations' dtype.
+# The three passes over the streams, each with its transpose written out:
+# the path of a shape that does not tile (``StreamMix.before`` asks), and
+# what ``ops/stream_mix.py``'s kernels are held to.  Left to autodiff, every
+# product of a coefficient and a stream leaves a float32 cotangent the size
+# of a stream behind (twenty of them for one sublayer's mix at n = 4);
+# written out, a pass owes what it writes in the activations' dtype.  XLA
+# still reads ``X`` once a fusion, about 29 times a sublayer over forward,
+# remat and backward, where the kernels move it 14.5 times.
 
 
 @jax.custom_vjp
@@ -200,33 +221,49 @@ class StreamMix(nn.Module):
                 n, dtype=dtype),
             (n, n), jnp.float32)
 
-    def coefficients(self, x):
-        """``X (B, n, S, C)`` -> the sublayer's input ``u (B, S, C)``
-        (float32) and ``(H_post (B, n, S), H_res (B, n, n, S))`` for
-        ``mix``."""
-        cfg, hc = self.config, self.config.streams
+    def before(self, x):
+        """``X (B, n, S, C)`` -> ``u``, the ``X`` that ``mix`` should take
+        (the kernels' op hands the streams through, so that both of their
+        cotangents meet in its one backward pass) and ``(H_post, H_res)``."""
+        hc = self.config.streams
         n = hc.n
         batch, _, seq, _ = x.shape
+        phi = jnp.concatenate([self.phi, self.phi_res], axis=-1)
+        alpha = self.alpha
+        kernels = stream_mix.token_tile(x) is not None
         with jax.named_scope("hc"):
-            raw, mean_square = _project(
-                x, jnp.concatenate([self.phi, self.phi_res], axis=-1))
-            h = raw * jax.lax.rsqrt(mean_square + 1e-6)[:, None, :]  # (B, k, S)
-            alpha = self.alpha
-            pre = jax.nn.sigmoid(
-                alpha[0] * h[:, :n] + self.b_pre[None, :, None])
+            if kernels:
+                raw, mean_square, u, x = stream_mix.pre(
+                    x, phi, alpha[0], self.b_pre)
+            else:
+                raw, mean_square = _project(x, phi)
+            h = raw * jax.lax.rsqrt(
+                mean_square + stream_mix.NORM_EPS)[:, None, :]  # (B, k, S)
+            if not kernels:
+                u = _pre_mix(jax.nn.sigmoid(
+                    alpha[0] * h[:, :n] + self.b_pre[None, :, None]), x)
             post = 2.0 * jax.nn.sigmoid(
                 alpha[1] * h[:, n:2 * n] + self.b_post[None, :, None])
             res = alpha[2] * h[:, 2 * n:].reshape(
                 batch, n, n, seq) + self.b_res[None, :, :, None]
             res = sinkhorn_knopp(
                 jnp.exp(jnp.clip(res, *hc.clamp)), hc.sinkhorn_iters, hc.eps)
-            u = _pre_mix(pre, x)
-        return u, (post, res)
+        return u, x, (post, res)
+
+    def coefficients(self, x):
+        """``X (B, n, S, C)`` -> the sublayer's input ``u (B, S, C)``
+        (float32) and ``(H_post (B, n, S), H_res (B, n, n, S))`` for
+        ``mix``."""
+        u, _, coefficients = self.before(x)
+        return u, coefficients
 
     def mix(self, x, y, coefficients):
         """``X' = H_res X + H_post y``."""
         post, res = coefficients
         with jax.named_scope("hc"):
-            out = _res_mix(res, post, x, y)
+            if stream_mix.token_tile(x) is None:
+                out = _res_mix(res, post, x, y)
+            else:
+                out = stream_mix.res_mix(res, post, x, y)
         return nn.with_logical_constraint(
             out, ("batch", None, "seq", "embed"))

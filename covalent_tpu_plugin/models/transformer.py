@@ -539,7 +539,7 @@ class Block(nn.Module):
                 x = x + sublayer(norm(x))
                 continue
             streams = StreamMix(cfg, name=f"hc_{tag}")
-            u, coefficients = streams.coefficients(x)
+            u, x, coefficients = streams.before(x)
             x = streams.mix(x, sublayer(norm(u)), coefficients)
         axes = ("batch", "seq", "embed")
         if cfg.streams is not None:

@@ -188,6 +188,27 @@ def test_platform_pin_that_cannot_take_is_the_tasks_error(tmp_path, monkeypatch)
     harness._apply_spec_env({"env": {"JAX_PLATFORMS": "cpu"}})
 
 
+def test_the_staged_directory_stays_out_of_what_jax_lowers(monkeypatch):
+    """A worker's compile cache keys carry the file names of the frames that
+    traced a kernel; the harness's own lies in a directory picked per run,
+    so the worker has jax strip that directory (unless the task's env says
+    what to strip)."""
+    import re
+
+    name = "JAX_HLO_SOURCE_FILE_CANONICALIZATION_REGEX"
+    monkeypatch.setenv(name, "")  # so that the writes below are undone
+    monkeypatch.delenv(name)
+    harness._apply_spec_env({})
+    staged = os.path.abspath(harness.__file__)
+    assert re.sub(os.environ[name], "", staged) == os.path.basename(staged)
+    assert re.sub(os.environ[name], "", "/elsewhere/model.py") == (
+        "/elsewhere/model.py")
+    harness._apply_spec_env({"env": {name: "^/checkout/"}})
+    assert os.environ[name] == "^/checkout/"
+    harness._apply_spec_env({})  # a value that is there is not replaced
+    assert os.environ[name] == "^/checkout/"
+
+
 def test_pool_server_refuses_to_fork_once_it_holds_a_backend(capsys):
     """A forked child of a jax-initialised process deadlocks at its first
     computation (and on a TPU the chip has one owner): ``run`` must answer
