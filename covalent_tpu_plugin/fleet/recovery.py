@@ -74,7 +74,7 @@ RECOVERY_ADAPTERS = REGISTRY.counter(
 )
 
 #: The last completed recovery pass, for the ``/status`` recovery
-#: section and the bench drill's assertions.  One dispatcher process
+#: section and the tests' assertions.  One dispatcher process
 #: recovers at most once per incarnation, so a module global is enough.
 _LAST_REPORT: dict | None = None
 
@@ -87,8 +87,7 @@ def last_report() -> dict | None:
 class RecoveryReport(dict):
     """The recovery pass's outcome — a dict, plus the live handles.
 
-    The dict half is JSON-safe (it feeds ``/status`` and the bench
-    drill's artifact); ``supervisors`` and ``requests`` carry the
+    The dict half is JSON-safe (it feeds ``/status``); ``supervisors`` and ``requests`` carry the
     re-adopted runtime objects so the caller can await the resumed
     streams' results directly.
     """

@@ -1402,7 +1402,19 @@ def _spawn_task(command: dict, children: dict) -> None:
                )})
         return
     sys.stdout.flush()
-    pid = os.fork()
+    import signal as _signal
+
+    # A kill can follow the launch before the child has run at all.  A
+    # TERM delivered then finds the server's handler still in place, and
+    # CPython forgets every signal tripped before its after-fork hook has
+    # run: the task would live on.  Blocked across the fork, the signal
+    # waits for the child to take the default disposition below.
+    _signal.pthread_sigmask(_signal.SIG_BLOCK, {_signal.SIGTERM})
+    try:
+        pid = os.fork()
+    except OSError:
+        _signal.pthread_sigmask(_signal.SIG_UNBLOCK, {_signal.SIGTERM})
+        raise
     if pid == 0:
         rc = 1
         try:
@@ -1429,14 +1441,13 @@ def _spawn_task(command: dict, children: dict) -> None:
             global _PROFILE_LOCK
             _PROFILE_LOCK = threading.Lock()
             _PROFILE_ACTIVE.clear()
-            import signal as _signal
-
             _signal.set_wakeup_fd(-1)
             _signal.signal(_signal.SIGCHLD, _signal.SIG_DFL)
             # The serve-preempt notice handler belongs to the server; a
             # task child's own preemption contract (checkpoint + die) is
             # installed by run_task when the spec configures it.
             _signal.signal(_signal.SIGTERM, _signal.SIG_DFL)
+            _signal.pthread_sigmask(_signal.SIG_UNBLOCK, {_signal.SIGTERM})
             os.setsid()
             log_fd = os.open(
                 command.get("log") or os.devnull,
@@ -1456,6 +1467,7 @@ def _spawn_task(command: dict, children: dict) -> None:
             traceback.print_exc()
         finally:
             os._exit(rc)
+    _signal.pthread_sigmask(_signal.SIG_UNBLOCK, {_signal.SIGTERM})
     children[pid] = task_id
     _emit({"event": "started", "id": task_id, "pid": pid})
 
@@ -2322,7 +2334,7 @@ class _ServeSession:
         self._cancelled_pending: set = set()
         #: Worker-side gray chaos (seeded slow tail / jitter on decode
         #: steps), parsed from COVALENT_TPU_CHAOS after the task env is
-        #: applied — how a bench brownouts ONE replica of a set.
+        #: applied — how a test brownouts ONE replica of a set.
         self._gray = None
         self._history_lock = threading.Lock()
         self.slots = 1
@@ -3098,16 +3110,13 @@ class _ServeSession:
                 if self.running:
                     self._pump_engine()
                 else:
-                    # Idle: block on the queue with a short tick so stats
-                    # keep flowing and close() wakes promptly.
-                    import queue as queue_mod
-
-                    try:
-                        command = self.queue.get(timeout=0.1)
-                    except queue_mod.Empty:
-                        command = None
-                    if command is not None:
-                        self.queue.put(command)
+                    # Idle: wait on the queue with a short tick so stats
+                    # keep flowing and close() wakes promptly.  The head
+                    # stays where it is: taken out and put back, it went
+                    # behind whatever had arrived meanwhile.
+                    with self.queue.not_empty:
+                        if not self.queue.queue:
+                            self.queue.not_empty.wait(0.1)
                 # Age-out the coalescing buffer: a token batch must never
                 # wait on MORE tokens to ship once its window expires.
                 _BATCHER.flush_aged()

@@ -26,7 +26,7 @@ from .lora import (
     quantize_then_lora,
 )
 from .quant import QuantDenseGeneral, quantize_lm
-from .serve import continuous_generate, step_accounting
+from .serve import continuous_generate
 from .speculative import speculative_generate, speculative_sample
 from .mlp import MLP, MnistCNN, synthetic_mnist
 from .transformer import TransformerConfig, TransformerLM, lm_125m_config
@@ -54,7 +54,6 @@ __all__ = [
     "beam_search",
     "generate",
     "continuous_generate",
-    "step_accounting",
     "inference_params",
     "init_cache",
     "MoEMlp",

@@ -380,7 +380,7 @@ def generate(
     """Jit-cached wrapper around the traced generate body — see
     `_generate_traced` for the full semantics docstring.  Static knobs
     key a compiled-executable cache, so repeated plain calls (tests,
-    serving oracles, benchmarks) pay one compile per configuration
+    serving oracles) pay one compile per configuration
     instead of eager per-token dispatch."""
     if max_new_tokens <= 0:
         # Preserve the eager identity contract (validation still fires

@@ -1,10 +1,10 @@
 """MNIST-class models (BASELINE configs 3-4) and synthetic data.
 
 Data is generated, not downloaded — the deployment targets are zero-egress
-TPU VMs, and the benchmark measures framework+compute performance, not
+TPU VMs, and what is measured is the framework and the compute, not
 dataset IO.  ``synthetic_mnist`` produces a deterministic, learnable
 class-conditional image distribution so "loss goes down" is a meaningful
-assertion in tests and benchmarks.
+assertion in tests.
 """
 
 from __future__ import annotations

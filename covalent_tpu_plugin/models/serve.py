@@ -22,9 +22,8 @@ TPU-native design — the pieces map to the compilation model:
   makes the bit-equality oracle in the tests possible.  Caveat shared
   with plain batched ``generate()``: on backends whose batched-matmul
   tiling rounds differently than the batch-1 shape (TPU MXU at bf16),
-  near-tie argmaxes can flip vs the batch-1 oracle — benchmarks/
-  serve_bench.py reports both arms' agreement to make the attribution
-  visible; on CPU (f32 and bf16) equality is bit-exact.
+  near-tie argmaxes can flip vs the batch-1 oracle; on CPU (f32 and
+  bf16) equality is bit-exact.
 * **Admission at scan boundaries.**  The device runs ``sync_steps``
   decode steps per jitted call (``lax.scan``); the host only looks at
   the tiny (B,) state vectors between calls, harvests finished rows,
@@ -645,46 +644,6 @@ def _make_run_steps(decoder, temperature, top_k, eos_token_id,
         return state
 
     return run_steps
-
-
-def step_accounting(
-    caps: Sequence[int], max_batch: int, sync_steps: int
-) -> dict[str, int]:
-    """Structural decode-step accounting for a serving workload: the
-    device-step counts that static wave batching and this module's
-    continuous loop pay for per-request budgets ``caps``, independent of
-    model size or transport.  One shared model for every artifact
-    (``bench.py`` ``lm_serve`` and ``benchmarks/serve_bench.py``) so the
-    accounting cannot drift from the admission rule implemented above.
-
-    Per-request cost is ``cap - 1`` decode steps (admission prefill
-    yields the first token; prefill passes are counted separately by the
-    callers).  Static: requests run in arrival-order waves of
-    ``max_batch``, each wave to its LONGEST member's budget.
-    Continuous: greedy slot packing in arrival order; a freed slot
-    re-admits only at the next ``sync_steps`` boundary (the
-    quantization ``continuous_generate``'s host loop actually pays),
-    with ``continuous_steps_ideal`` the unquantized packing bound.
-    """
-    caps = [int(c) for c in caps]
-    waves = [
-        caps[i:i + max_batch] for i in range(0, len(caps), max_batch)
-    ]
-    static = sum(max(w) - 1 for w in waves)
-    ideal = [0] * max_batch
-    free_at = [0] * max_batch
-    finish = [0] * max_batch
-    for cap in caps:
-        k = min(range(max_batch), key=lambda j: ideal[j])
-        ideal[k] += cap - 1
-        k = min(range(max_batch), key=lambda j: free_at[j])
-        finish[k] = free_at[k] + cap - 1
-        free_at[k] = -(-finish[k] // sync_steps) * sync_steps
-    return {
-        "static_wave_steps": static,
-        "continuous_steps_ideal": max(ideal),
-        "continuous_steps_sync": max(finish),
-    }
 
 
 def continuous_generate(

@@ -184,7 +184,7 @@ class EventSink:
 
 _sink_lock = threading.Lock()
 _sink: EventSink | None = None
-#: In-process observers (tests, bench live tailers): called with every
+#: In-process observers (tests, live tailers): called with every
 #: event dict even when no JSONL path is configured.
 _listeners: list[Callable[[dict], None]] = []
 

@@ -114,8 +114,8 @@ class MetricsHistory:
 
         The sampler thread calls this once per ``interval_s`` tick; the
         stride counter makes post-compaction ticks record every Nth call
-        so the ring's spacing stays uniform.  ``force`` (tests, bench
-        phase boundaries) bypasses the stride.
+        so the ring's spacing stays uniform.  ``force`` (tests) bypasses the
+        stride.
         """
         now = self._clock()
         with self._lock:
@@ -558,7 +558,7 @@ def ensure_history(interval_s: float | None = None) -> MetricsHistory | None:
 
     ``interval_s`` overrides ``COVALENT_TPU_HISTORY_S`` (default 1.0
     second); 0 disables sampling and returns None.  Idempotent — the ops
-    server, executors, and the bench all call this freely.
+    server and executors all call this freely.
     """
     global _thread
     interval = (

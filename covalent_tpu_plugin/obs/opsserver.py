@@ -438,12 +438,3 @@ def ensure_ops_server(port: int | None = None) -> OpsServer | None:
         "ops.server_started", host=_server.host, port=_server.port
     )
     return _server
-
-
-def shutdown_ops_server() -> None:
-    """Stop and forget the process-wide server (tests)."""
-    global _server
-    with _server_lock:
-        if _server is not None:
-            _server.close()
-            _server = None

@@ -68,8 +68,8 @@ SLO_BURN_RATE = REGISTRY.gauge(
 )
 
 #: Shipped objectives for the serving + dispatch planes.  Deliberately
-#: loose (these are guardrails, not latency targets — the bench asserts
-#: the targets); deployments tighten them via config/env.
+#: loose (these are guardrails, not latency targets); deployments
+#: tighten them via config/env.
 DEFAULT_SLOS: tuple[dict[str, Any], ...] = (
     {
         "name": "serve_p95_latency",

@@ -9,8 +9,8 @@ sinks:
   JSONL file doubles as a flat trace export with consistent ids;
 * the metrics registry, as one observation in the
   ``covalent_tpu_span_duration_seconds{span="<name>"}`` histogram — which
-  is exactly the per-stage dispatch-overhead distribution the bench
-  report and Prometheus exposition surface.
+  is exactly the per-stage dispatch-overhead distribution the
+  Prometheus exposition surfaces.
 
 Usage::
 

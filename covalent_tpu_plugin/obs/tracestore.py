@@ -126,8 +126,8 @@ class TraceStore:
         """Keep probability for unremarkable traces.
 
         Reads ``COVALENT_TPU_TRACE_SAMPLE`` live (unless constructed with
-        an explicit rate) so the bench and tests can retune the
-        process-wide store after import.
+        an explicit rate) so tests can retune the process-wide store
+        after import.
         """
         if self._sample_override is not None:
             return min(1.0, max(0.0, self._sample_override))
@@ -283,8 +283,8 @@ class TraceStore:
         (parent id set but absent from the trace).  ``segments``
         aggregates the spans that carry a ``segment`` attribute — the
         waterfall tiling the serving path records — and ``coverage`` is
-        their summed share of the root duration, which is how the bench
-        asserts the segments account for the measured end-to-end latency.
+        their summed share of the root duration, which is how tests
+        assert the segments account for the measured end-to-end latency.
         """
         with self._lock:
             trace = self._kept.get(trace_id) or self._pending.get(trace_id)

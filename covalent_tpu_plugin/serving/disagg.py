@@ -161,7 +161,7 @@ class DisaggregatedSet(ReplicaSet):
         self._prefill_opening = 0
         #: prefill work currently in flight per prefill replica id.
         self._prefill_load: collections.Counter = collections.Counter()
-        #: bench-readable transfer accounting (the metrics' raw feed).
+        #: transfer accounting for ``status()`` (the metrics' raw feed).
         self.kv_bytes_total = 0
         self.kv_transfer_s: collections.deque = collections.deque(
             maxlen=4096

@@ -1,7 +1,7 @@
 """Serving-tier metrics: request outcomes, stream latency, session load.
 
-One module so the handle, the fleet status views, and the bench phase all
-move the same series.  Label cardinality is deliberately low: ``outcome``
+One module so the handle and the fleet status views move the same
+series.  Label cardinality is deliberately low: ``outcome``
 is a closed set, and per-session gauges key on the HANDLE sid (stable
 across reconnect generations), not the per-generation remote session id.
 """
@@ -58,7 +58,7 @@ SERVE_HANDOFFS_TOTAL = REGISTRY.counter(
 
 #: Time-to-first-token, submit -> first streamed chunk.  The streaming
 #: side-band's whole point: TTFT must sit near one decode chunk, not at
-#: end-of-response - the bench phase asserts exactly that.
+#: end-of-response.
 SERVE_TTFT_SECONDS = REGISTRY.histogram(
     "covalent_tpu_serve_ttft_seconds",
     "Serving-request time to first streamed token",
@@ -124,8 +124,7 @@ SERVE_ROUTER_QUEUE_DEPTH = REGISTRY.gauge(
     ("tenant",),
 )
 
-#: The router's whole per-request cost: the ``serve_scale`` bench phase
-#: asserts its median under 1 ms — scaling out must not move the
+#: The router's whole per-request cost: scaling out must not move the
 #: dispatch tax it removed back into the routing layer.
 SERVE_ROUTER_DECISION_SECONDS = REGISTRY.histogram(
     "covalent_tpu_serve_router_decision_seconds",

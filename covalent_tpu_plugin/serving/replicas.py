@@ -497,8 +497,8 @@ class ReplicaSet:
         #: drain to finish, then re-warms; it is never dropped.
         self._scale_lock = asyncio.Lock()
         self._pump_tasks: set[asyncio.Task] = set()
-        #: recent router decision walls (the <1ms bench assertion reads
-        #: the same numbers the histogram observes).
+        #: recent router decision walls (``status()`` reads the same
+        #: numbers the histogram observes).
         self.decision_s: collections.deque = collections.deque(maxlen=4096)
         # -- tail-latency hedging ------------------------------------------
         # A deterministic (temperature=0), non-sticky request whose TTFT
@@ -645,7 +645,7 @@ class ReplicaSet:
         )
 
     def status(self) -> dict[str, Any]:
-        """The set's contribution to operator views (bench + smoke)."""
+        """The set's contribution to operator views."""
         decisions = sorted(self.decision_s)
         p50 = decisions[len(decisions) // 2] if decisions else 0.0
         return {

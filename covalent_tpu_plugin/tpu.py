@@ -623,7 +623,7 @@ class TPUExecutor(RemoteExecutor):
             0, int(resolve(rpc_inline_args_max, "rpc_inline_args_max"))
         )
         #: dispatch mode the most recent attempt actually used
-        #: ("rpc"/"launch"); bench and tests assert the fast path engaged.
+        #: ("rpc"/"launch"); tests assert the fast path engaged.
         self.last_dispatch_mode = ""
         #: comma-separated modules the pool server imports once at start.
         self.pool_preload = str(resolve(pool_preload, "pool_preload"))
@@ -719,7 +719,7 @@ class TPUExecutor(RemoteExecutor):
         )
         #: fault-injection plan shared by every transport this executor
         #: dials (None = no chaos wrapper).  A ChaosPlan instance wins so
-        #: tests/bench can script faults and read injection counts back.
+        #: tests can script faults and read injection counts back.
         if isinstance(chaos, ChaosPlan):
             self._chaos: ChaosPlan | None = chaos
         else:
@@ -4285,7 +4285,7 @@ class TPUExecutor(RemoteExecutor):
         # died, a local fallback).  Profile capture (trace stop + tar +
         # fetch, potentially seconds) observes the dispatch rather than
         # being part of it — charging it as overhead would burn the
-        # dispatch_overhead SLO and bench budgets on profiled-but-healthy
+        # dispatch_overhead SLO on profiled-but-healthy
         # traffic.
         not_overhead = ("execute", "profile")
         task_s = self._worker_execute_s.pop(

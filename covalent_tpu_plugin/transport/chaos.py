@@ -47,8 +47,8 @@ silently injecting nothing would fake a green resilience test):
 * ``max_faults``      — process-wide budget across ALL injected faults.
 
 Every injected fault emits a ``chaos.fault`` event and increments
-``covalent_tpu_chaos_faults_total{kind}`` so test assertions and bench
-reports can attribute recovery behavior to the faults that caused it.
+``covalent_tpu_chaos_faults_total{kind}`` so test assertions
+can attribute recovery behavior to the faults that caused it.
 """
 
 from __future__ import annotations

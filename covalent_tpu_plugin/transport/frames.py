@@ -38,7 +38,7 @@ a frame-capable runtime advertises ``"frames": 1`` in its ready event, the
 client (unless ``COVALENT_TPU_AGENT_FRAMES=0``) answers with a ``frames``
 command, and both sides switch.  A silent banner — an old runtime, a
 native-less worker, the kill switch — leaves the channel on JSONL with
-byte-equal results, asserted in the test suite and the bench smoke.
+byte-equal results, asserted in the test suite.
 
 The worker-side mirror of this codec lives in ``harness.py`` (which must
 stay stdlib-only and standalone) and ``native/agent.cc``; the three are

@@ -263,6 +263,10 @@ class StubSet:
 
 
 def make_controller(clock, registry=None, engine=None, **kwargs):
+    # Always an engine of the test's own: given none, the controller
+    # attaches the process-wide one, whose burning SLOs are whatever the
+    # tests before this one in the same process left on the real clock.
+    engine = engine if engine is not None else StubEngine()
     history = StubHistory()
     scheduler = (
         StubScheduler(registry) if registry is not None else None

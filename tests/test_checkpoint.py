@@ -208,7 +208,7 @@ def test_resume_across_electron_dispatches(tmp_path, run_async):
         create_unique_workdir=True,
         remote_workdir=str(tmp_path / "wd"),
         # Workers normally have the package installed; the subprocess in this
-        # test gets it via PYTHONPATH (same pattern as bench.py).
+        # test gets it via PYTHONPATH.
         task_env={
             "PYTHONPATH": repo_root + os.pathsep + os.environ.get("PYTHONPATH", "")
         },

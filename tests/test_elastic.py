@@ -13,9 +13,11 @@ skips incomplete checkpoints and falls back to the previous complete step.
 
 from __future__ import annotations
 
+import asyncio
 import json
 import os
 import pathlib
+import time
 
 from covalent_tpu_plugin import harness as harness_mod
 from covalent_tpu_plugin.obs import events as obs_events
@@ -51,12 +53,17 @@ def make_elastic_executor(tmp_path, **kwargs):
     return make_local_executor(tmp_path, **kwargs)
 
 
-def elastic_train(steps: int, step_s: float, progress_path: str):
+def elastic_train(
+    steps: int, step_s: float, progress_path: str, hold_at: int = -1
+):
     """A checkpoint-cooperative training electron.
 
     Appends every executed step to ``progress_path`` (so the test can
     count recomputation across attempts), registers a snapshot hook, and
-    resumes from the dispatcher-shipped bundle when one exists.
+    resumes from the dispatcher-shipped bundle when one exists.  An
+    attempt that did not resume stands still before step ``hold_at``
+    until the preemption takes it, so the fault finds it mid-run however
+    long the worker took to boot.
     """
     import time
 
@@ -81,6 +88,12 @@ def elastic_train(steps: int, step_s: float, progress_path: str):
     ckpt.register_snapshot(snap)
     try:
         for step in range(start, steps):
+            if resumed is None and step == hold_at:
+                # Held for SIGTERM; bounded so a preemption that never
+                # comes fails the test's assertions instead of hanging.
+                held_until = time.monotonic() + 60.0
+                while time.monotonic() < held_until:
+                    time.sleep(0.01)
             with open(progress_path, "a") as f:
                 f.write(f"{step}\n")
             time.sleep(step_s)
@@ -126,8 +139,18 @@ def test_interval_checkpoints_published_to_cas(tmp_path, run_async):
     cas = tmp_path / "remote" / "cas"
     manifest_path = cas / "ckpt_ckpt-pub_0.json"
     assert manifest_path.exists(), list(cas.iterdir())
-    manifest = json.loads(manifest_path.read_text())
-    history = manifest["history"]
+    # The result lands before the worker stops its checkpointer: a last
+    # save may still be publishing (bundle, then manifest, then the
+    # unlink of what fell off it).  Read once the directory is at rest.
+    at_rest = time.monotonic() + 10.0
+    while True:
+        history = json.loads(manifest_path.read_text())["history"]
+        if (
+            len(list(cas.glob("*.ckpt"))) == len(history)
+            or time.monotonic() > at_rest
+        ):
+            break
+        time.sleep(0.05)
     assert 1 <= len(history) <= 2  # keep_n bounds the completed steps
     for entry in history:
         bundle = pathlib.Path(entry["file"])
@@ -145,7 +168,10 @@ def test_preemption_resume_not_recompute(tmp_path, run_async):
     (not the whole run), ``worker_preempted`` retry label, ``task.resumed``
     event, restores counter moving."""
     steps, step_s = 60, 0.05
-    plan = ChaosPlan(preempt_after=25, preempt_grace=1.0, max_faults=1)
+    # Armed below, once a checkpoint exists: an op count alone may fire
+    # while the worker is still importing, and then there is nothing to
+    # resume from.
+    plan = ChaosPlan(preempt_grace=1.0, max_faults=1)
     ex = make_elastic_executor(
         tmp_path,
         max_task_retries=2,
@@ -166,12 +192,25 @@ def test_preemption_resume_not_recompute(tmp_path, run_async):
         "covalent_tpu_task_retries_total", reason="worker_preempted"
     )
 
+    manifest = tmp_path / "remote" / "cas" / "ckpt_ckpt-resume_0.json"
+
+    async def preempt_after_first_checkpoint():
+        while not (
+            manifest.exists()
+            and json.loads(manifest.read_text()).get("history")
+        ):
+            await asyncio.sleep(0.02)
+        plan.preempt_after = 1  # the channel's next op delivers the notice
+
     async def flow():
+        arm = asyncio.ensure_future(preempt_after_first_checkpoint())
         try:
             return await ex.run(
-                elastic_train, [steps, step_s, str(progress)], {}, metadata
+                elastic_train, [steps, step_s, str(progress), 10], {},
+                metadata,
             )
         finally:
+            arm.cancel()
             await ex.close()
 
     with EventLog() as log:

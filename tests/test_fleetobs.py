@@ -554,6 +554,7 @@ def test_agent_stall_suspicion_confirmed_against_hb_file(
     stall suspicion the agent wait re-reads the .hb snapshot directly and
     a beating worker survives."""
     from covalent_tpu_plugin import agent as agent_mod
+    from covalent_tpu_plugin import tpu as tpu_mod
 
     # No side-band at all: every watch fails (the worst case the review
     # flagged — agent mode with zero streaming feed into the monitor).
@@ -561,33 +562,53 @@ def test_agent_stall_suspicion_confirmed_against_hb_file(
         raise agent_mod.AgentError("watch unsupported")
 
     monkeypatch.setattr(agent_mod.AgentClient, "watch", broken_watch)
-    # Tighten the never-beat launch slack so the suspicion actually fires
-    # within the electron's runtime.
-    monkeypatch.setattr(HeartbeatMonitor, "LAUNCH_SLACK_S", 1.0)
-    # 16 missed beats before suspicion: 0.4s flaked under full-suite load
-    # (a transiently starved beat thread read as a stall), and 0.8s still
-    # did on loaded machines — the .hb staleness tolerance must exceed the
-    # worst beat-thread starvation the suite inflicts, not the cadence.
+    # The monitor's clock is the test's: the silence is declared once the
+    # worker's first beat is on disk, not waited for against a worker
+    # whose boot may take longer than any threshold worth waiting.
+    now = [0.0]
+    monitor = HeartbeatMonitor(clock=lambda: now[0])
+    monkeypatch.setattr(tpu_mod, "MONITOR", monitor)
     ex = make_local_executor(
         tmp_path, use_agent="pool", heartbeat_interval=0.1,
         stall_threshold=1.6, max_task_retries=1, poll_freq=0.1,
     )
+    release = tmp_path / "release"
+    confirmed: list[dict] = []
 
-    def slow(x):
+    def held(x, release_path):
+        import os as _os
         import time as _time
 
-        _time.sleep(3.0)
+        give_up = _time.monotonic() + 60.0
+        while not _os.path.exists(release_path) and _time.monotonic() < give_up:
+            _time.sleep(0.01)
         return x * 3
 
+    async def suspect_then_release():
+        while not any(
+            hb.stat().st_size for hb in (tmp_path / "remote").rglob("*.hb")
+        ):
+            await asyncio.sleep(0.02)
+        now[0] += 3600.0  # an hour without a beat on the side-band
+        # Only the confirmation's read of the .hb file can feed this
+        # monitor; a gang killed instead shows as a second attempt.
+        while not monitor.last("fconfirm_0") and ex.last_attempts == 1:
+            await asyncio.sleep(0.02)
+        confirmed.append(monitor.last("fconfirm_0"))
+        release.write_text("go")
+
     async def flow():
+        helper = asyncio.ensure_future(suspect_then_release())
         try:
-            return await ex.run(slow, [7], {},
+            return await ex.run(held, [7, str(release)], {},
                                 {"dispatch_id": "fconfirm", "node_id": 0})
         finally:
+            helper.cancel()
             await ex.close()
 
     assert run_async(flow()) == 21
     assert ex.last_attempts == 1, "healthy gang was stall-killed"
+    assert confirmed and "localhost" in confirmed[0]
 
 
 NATIVE_AGENT_SKIP = pytest.mark.skipif(

@@ -457,39 +457,54 @@ def test_client_kill_switch_declines_capable_runtime(tmp_path, run_async):
 
 
 def test_e2e_binary_and_jsonl_results_byte_equal(tmp_path, run_async):
-    """The same electron through a frames channel and a JSONL channel must
+    """The same electrons through a frames channel and a JSONL channel must
     produce byte-identical result pickles — and the binary arm must have
-    actually used frames (no silent fallback can pass this)."""
+    actually used frames (no silent fallback can pass this), the JSONL
+    arm none, and the frames must be the fewer bytes on the channel for
+    the same inline arguments (base64 alone is a third more)."""
+    text = "".join(chr(33 + i % 90) for i in range(16 * 1024))
+
+    def framed_invokes():
+        return sum(
+            counter_value(
+                "covalent_tpu_agent_frames_total",
+                verb=verb, encoding="binary",
+            )
+            for verb in ("invoke", "multi_invoke")
+        )
+
+    def measure(value):
+        return len(value), value[:8]
 
     async def run_arm(tag, agent_frames):
         ex = make_rpc_executor(tmp_path / tag, agent_frames=agent_frames)
         try:
-            out = await ex.run(
+            framed0 = framed_invokes()
+            square = await ex.run(
                 _make_square(), [123], {},
                 {"dispatch_id": f"fr{tag}", "node_id": 0},
             )
             assert ex.last_dispatch_mode == "rpc"
-            return out
+            wire0 = counter_value("covalent_tpu_agent_wire_bytes_total")
+            measured = await ex.run(
+                measure, [text], {},
+                {"dispatch_id": f"fr{tag}", "node_id": 1},
+            )
+            assert ex.last_dispatch_mode == "rpc"
+            wire = counter_value("covalent_tpu_agent_wire_bytes_total") - wire0
+            return square, measured, framed_invokes() - framed0, wire
         finally:
             await ex.close()
 
     async def flow():
-        before = counter_value(
-            "covalent_tpu_agent_frames_total",
-            verb="invoke", encoding="binary",
-        )
-        binary = await run_arm("bin", True)
-        after = counter_value(
-            "covalent_tpu_agent_frames_total",
-            verb="invoke", encoding="binary",
-        )
-        jsonl = await run_arm("jsonl", False)
-        return binary, jsonl, after - before
+        return await run_arm("bin", True), await run_arm("jsonl", False)
 
-    binary, jsonl, framed_invokes = run_async(flow())
-    assert binary == jsonl == 123 * 123
-    assert cloudpickle.dumps(binary) == cloudpickle.dumps(jsonl)
-    assert framed_invokes >= 1
+    binary, jsonl = run_async(flow())
+    assert binary[0] == jsonl[0] == 123 * 123
+    assert binary[1] == jsonl[1] == (len(text), text[:8])
+    assert cloudpickle.dumps(binary[:2]) == cloudpickle.dumps(jsonl[:2])
+    assert binary[2] >= 2 and jsonl[2] == 0
+    assert len(text) < binary[3] < jsonl[3], (binary[3], jsonl[3])
 
 
 def test_chaos_transport_faults_apply_to_framed_channel(tmp_path, run_async):

@@ -257,10 +257,9 @@ def test_put_bundle_over_minissh_roundtrip(tmp_path):
 
     os.makedirs(tmp_path / "cas", exist_ok=True)
     items = []
-    body = '{"spec": "payload", "idx": %d}\n' * 64
     for i in range(3):
         local = tmp_path / f"art{i}.json"
-        local.write_text(body % i)
+        local.write_text('{"spec": "payload", "idx": %d}\n' % i * 64)
         digest = hashlib.sha256(local.read_bytes()).hexdigest()
         items.append((str(local), str(tmp_path / "cas" / f"art{i}"), digest))
 

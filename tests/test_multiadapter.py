@@ -10,8 +10,7 @@ quantize_then_lora refusal through a REAL ``open_session`` (PERMANENT,
 one factory invocation — never a retry storm), and the live
 ``serve_attach`` path's fault classification.  The full control plane
 (supervisor journal/replay, recovery re-attach) is covered in
-``test_recovery.py``; the throughput claim in the bench's
-``serve_multilora`` phase.
+``test_recovery.py``.
 """
 
 import os
@@ -281,6 +280,108 @@ def test_kv_bundle_carries_adapter_identity():
     streams = drain(mux, {"kv1": []})
     assert streams["kv1"] == oracle
     mux.close()
+
+
+# ---------------------------------------------------------------------------
+# The base engine's arms: a cheaper road streams what the plain one does
+# ---------------------------------------------------------------------------
+
+
+def drive(engine, prompts, cap=6, params=None):
+    """Admit ``prompts`` as lanes free up and run the engine dry."""
+    queue = list(enumerate(prompts))
+    streams: dict = {}
+    for _ in range(400):
+        while queue and engine.busy < engine.slots:
+            i, prompt = queue.pop(0)
+            streams[f"r{i}"] = []
+            engine.admit(
+                f"r{i}", prompt, {"max_new_tokens": cap, **(params or {})}
+            )
+        for event in engine.step():
+            streams[event["rid"]] += event["tokens"]
+        if not queue and not engine.busy:
+            return streams
+    raise AssertionError("engine never drained")
+
+
+def plain_engine(**kw):
+    model, params = shared()
+    return ContinuousEngine(
+        model, params, max_batch=2, sync_steps=3, max_new_tokens=6,
+        length=40, **kw,
+    )
+
+
+def arm_prefix_reuse():
+    """A shared prefix is prefilled once: equal streams for strictly
+    fewer prefill positions."""
+    prefix = np.asarray([5, 9, 2, 7, 11, 3, 8, 1, 4, 6], np.int32)
+    prompts = [
+        np.concatenate([prefix, np.asarray(tail, np.int32)])
+        for tail in ([12, 13], [20], [31, 32, 33])
+    ]
+    plain, reuse = plain_engine(), plain_engine(shared_prefix=prefix)
+    want, got = drive(plain, prompts), drive(reuse, prompts)
+    assert reuse.stats["prefix_hits"] == len(prompts)
+    assert 0 < reuse.stats["prefill_positions"] < (
+        plain.stats["prefill_positions"]
+    )
+    plain.close()
+    reuse.close()
+    return want, got
+
+
+def arm_kv_split():
+    """Prefill on one engine, decode on another from the bundle: the
+    decode side pays no prefill position, and a prompt seen before rides
+    the prefill side's prefix tree."""
+    prompts = [np.arange(2 + i, 12 + i, dtype=np.int32) for i in range(3)]
+    prompts.append(prompts[0])
+    joint, prefill, decode = plain_engine(), plain_engine(), plain_engine()
+    want = drive(joint, prompts)
+    bundles = [
+        prefill.prefill_only(p, {"max_new_tokens": 6}) for p in prompts
+    ]
+    got: dict = {}
+    for i, bundle in enumerate(bundles):
+        decode.admit_from_kv(f"r{i}", bundle, {"max_new_tokens": 6})
+        got.update(drain(decode, {f"r{i}": []}))
+    assert decode.stats["prefill_positions"] == 0
+    assert decode.stats["kv_admits"] == len(prompts)
+    assert prefill.stats["prefix_hits"] > 0
+    for engine in (joint, prefill, decode):
+        engine.close()
+    return want, got
+
+
+def arm_spec_kv_quant():
+    """Speculative decode on the quantized-cache lane is not bit-equal
+    to full precision by design; its contract is that a repeated greedy
+    drive streams the same tokens."""
+    model, params = shared()
+    engine = plain_engine(
+        decode_modes=("fp", "kv_quant"), draft_model=model,
+        draft_params=params, draft_len=2,
+    )
+    assert engine._spec_refusal is None
+    first = drive(engine, PROMPTS, params={"quality": "kv_quant"})
+    again = drive(engine, PROMPTS, params={"quality": "kv_quant"})
+    assert engine.stats["spec_proposed"] > 0
+    assert engine.stats["mode_tokens_kv_quant"] > 0
+    assert engine.stats["mode_refusals"] == 0
+    engine.close()
+    return first, again
+
+
+@pytest.mark.parametrize(
+    "arm", [arm_prefix_reuse, arm_kv_split, arm_spec_kv_quant],
+    ids=["prefix_reuse", "kv_split", "spec_kv_quant"],
+)
+def test_engine_arms_stream_equal(arm):
+    want, got = arm()
+    assert want and got == want
+    assert all(len(tokens) == 6 for tokens in got.values())
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,13 @@ from covalent_tpu_plugin.fleet import recovery as recovery_mod
 from covalent_tpu_plugin.obs.metrics import REGISTRY
 from covalent_tpu_plugin.serving import open_session
 
-from .test_serving import make_factory, make_serve_executor
+from .test_serving import (
+    GATE_OPEN,
+    allow_steps,
+    make_factory,
+    make_serve_executor,
+    step_gate,
+)
 
 
 def counter_value(name: str, **labels) -> float:
@@ -56,6 +62,31 @@ def crash_dispatcher(ex) -> None:
         client._reader.cancel()
     ex._serve_handles.clear()
     ex._agents.clear()
+
+
+async def eventually(done, what: str) -> None:
+    deadline = time.monotonic() + 60
+    while not done():
+        if time.monotonic() > deadline:
+            raise AssertionError(what)
+        await asyncio.sleep(0.02)
+
+
+async def stream_reaches(request, n: int) -> None:
+    """Wait for a gated stream's first ``n`` tokens: the gate holds it
+    there, so only a stream that never starts can outlast the wait."""
+    await eventually(lambda: len(request.tokens) >= n, "stream never started")
+
+
+async def orphan_published(tmp_path) -> None:
+    """Wait for the crashed incarnation's worker to publish its
+    rendezvous.  A real successor starts seconds after the crash; here it
+    follows at once, and one that dials before the orphan has noticed its
+    dead channel starts a fresh server and finds no session to adopt."""
+    await eventually(
+        (tmp_path / "remote" / "pool_orphan.json").exists,
+        "the worker never went into orphan mode",
+    )
 
 
 @pytest.fixture()
@@ -101,17 +132,18 @@ def test_recover_adopts_orphan_and_resumes_stream_exactly_once(
         journal_mod.configure(journal_dir)
         assert journal_mod.epoch() == 1
         ex_a = make_serve_executor(tmp_path)
+        # The engine steps when the gate says: two steps reach this
+        # incarnation, three more run while nobody listens, and the rest
+        # once the successor has resumed the stream.
+        gate = tmp_path / "gate"
+        allow_steps(gate, 2)
         handle = await open_session(
-            ex_a, make_factory(step_delay=0.2, chunk=2, default_cap=30),
+            ex_a, make_factory(chunk=2, default_cap=30, gate=str(gate)),
             stats_interval_s=0.1,
         )
         sid = handle.sid
         req_a = await handle.request([100], params={"max_new_tokens": 30})
-        deadline = time.monotonic() + 20
-        while len(req_a.tokens) < 4:
-            if time.monotonic() > deadline:
-                raise AssertionError("stream never started")
-            await asyncio.sleep(0.05)
+        await stream_reaches(req_a, 4)
         # A journaled session NO worker holds (its worker is long dead):
         # recovery must reap it, not hang on it.
         journal_mod.record(
@@ -120,7 +152,9 @@ def test_recover_adopts_orphan_and_resumes_stream_exactly_once(
             sync=True,
         )
         crash_dispatcher(ex_a)
+        await orphan_published(tmp_path)
         prefix = list(req_a.tokens)
+        allow_steps(gate, 5)
 
         # -- incarnation 2: fresh journal handle over the same directory
         # replays the dead incarnation's world and bumps the epoch.
@@ -131,6 +165,7 @@ def test_recover_adopts_orphan_and_resumes_stream_exactly_once(
         ex_b = make_serve_executor(tmp_path)
         try:
             report = await ex_b.recover()
+            allow_steps(gate, GATE_OPEN)
             rid = next(
                 r for s, r in report.requests if s == sid
             )
@@ -147,7 +182,8 @@ def test_recover_adopts_orphan_and_resumes_stream_exactly_once(
     assert sid in report["adopted_sessions"]
     assert "ghost" in report["orphaned_sessions"]
     entry = next(r for r in report["resumed_streams"] if r["sid"] == sid)
-    assert entry["state"] in ("streaming", "done")
+    assert entry["state"] == "streaming"
+    assert prefix == [101, 102, 103, 104]
     # The journaled high-water mark is exactly what incarnation 1 had
     # delivered — the splice point.
     assert entry["from"] == len(prefix)
@@ -172,21 +208,24 @@ def test_recovered_session_serves_new_requests(
     async def flow():
         journal_mod.configure(journal_dir)
         ex_a = make_serve_executor(tmp_path)
+        gate = tmp_path / "gate"
+        allow_steps(gate, 1)  # the crash finds the stream mid-flight
         handle = await open_session(
-            ex_a, make_factory(step_delay=0.1, chunk=2, default_cap=6),
+            ex_a, make_factory(chunk=2, default_cap=6, gate=str(gate)),
             stats_interval_s=0.1,
         )
         sid = handle.sid
         req_a = await handle.request([100], params={"max_new_tokens": 20})
-        while len(req_a.tokens) < 2:
-            await asyncio.sleep(0.05)
+        await stream_reaches(req_a, 2)
         crash_dispatcher(ex_a)
+        await orphan_published(tmp_path)
 
         journal_mod.reset()
         journal_mod.configure(journal_dir)
         ex_b = make_serve_executor(tmp_path)
         try:
             report = await ex_b.recover()
+            allow_steps(gate, GATE_OPEN)
             sup = report.supervisors[sid]
             from covalent_tpu_plugin.serving.supervisor import ServeRequest
 
@@ -205,16 +244,17 @@ def test_recovered_session_serves_new_requests(
     assert isinstance(closed, dict)
 
 
-def make_adapter_factory(step_delay=0.0):
+def make_adapter_factory(gate):
     """A stub engine with the duck-typed multi-adapter surface
     (attach/detach/adapter_digests), cloudpickled BY VALUE: adapter
     ``name`` offsets the deterministic stream by the first value of its
     bundle leaves, so a resumed adapter-routed splice is byte-checkable
-    and a stream decoded WITHOUT the adapter is visibly different."""
+    and a stream decoded WITHOUT the adapter is visibly different.  It
+    steps as ``gate`` allows (``test_serving.allow_steps``)."""
+
+    may_step = step_gate(gate)
 
     def factory():
-        import time as time_mod
-
         import numpy as np_mod
 
         class Engine:
@@ -259,8 +299,8 @@ def make_adapter_factory(step_delay=0.0):
                     self.stats[f"adapter_tokens_{name}"] += cap
 
             def step(self):
-                if step_delay:
-                    time_mod.sleep(step_delay)
+                if self.lanes and not may_step():
+                    return []
                 events = []
                 for rid in list(self.lanes):
                     taken = self.lanes[rid][:2]
@@ -296,9 +336,10 @@ def test_recover_reattaches_adapters_and_resumes_byte_equal(
     async def flow():
         journal_mod.configure(journal_dir)
         ex_a = make_serve_executor(tmp_path)
+        gate = tmp_path / "gate"
+        allow_steps(gate, 2)
         handle = await open_session(
-            ex_a, make_adapter_factory(step_delay=0.2),
-            stats_interval_s=0.1,
+            ex_a, make_adapter_factory(str(gate)), stats_interval_s=0.1,
         )
         sid = handle.sid
         for name, offset in (("fr", 1000), ("de", 2000)):
@@ -310,13 +351,11 @@ def test_recover_reattaches_adapters_and_resumes_byte_equal(
         req_a = await handle.request(
             [100], params={"max_new_tokens": 30, "adapter": "fr"}
         )
-        deadline = time.monotonic() + 20
-        while len(req_a.tokens) < 4:
-            if time.monotonic() > deadline:
-                raise AssertionError("stream never started")
-            await asyncio.sleep(0.05)
+        await stream_reaches(req_a, 4)
         crash_dispatcher(ex_a)
+        await orphan_published(tmp_path)
         prefix = list(req_a.tokens)
+        allow_steps(gate, 5)
 
         journal_mod.reset()
         journal = journal_mod.configure(journal_dir)
@@ -325,6 +364,7 @@ def test_recover_reattaches_adapters_and_resumes_byte_equal(
         ex_b = make_serve_executor(tmp_path)
         try:
             report = await ex_b.recover()
+            allow_steps(gate, GATE_OPEN)
             sup = report.supervisors[sid]
             recovered_book = dict(sup.adapters)
             rid = next(r for s, r in report.requests if s == sid)

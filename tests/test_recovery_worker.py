@@ -25,43 +25,7 @@ import cloudpickle
 from covalent_tpu_plugin import harness as harness_mod
 from covalent_tpu_plugin.cache import bytes_digest
 
-
-def _make_factory(step_delay=0.0, slots=2, chunk=2, default_cap=6):
-    """Deterministic closure-local engine (same contract as test_serving):
-    prompt ``[..., base]`` streams ``base+1 .. base+cap``."""
-
-    def factory():
-        import time as time_mod
-
-        class Engine:
-            def __init__(self):
-                self.slots = slots
-                self.lanes = {}
-
-            def admit(self, rid, prompt, params):
-                cap = int((params or {}).get("max_new_tokens", default_cap))
-                base = int(prompt[-1])
-                self.lanes[rid] = [base + i + 1 for i in range(cap)]
-
-            def step(self):
-                if step_delay:
-                    time_mod.sleep(step_delay)
-                events = []
-                for rid in list(self.lanes):
-                    taken = self.lanes[rid][:chunk]
-                    self.lanes[rid] = self.lanes[rid][chunk:]
-                    done = not self.lanes[rid]
-                    if done:
-                        del self.lanes[rid]
-                    events.append({"rid": rid, "tokens": taken, "done": done})
-                return events
-
-            def cancel(self, rid):
-                self.lanes.pop(rid, None)
-
-        return Engine()
-
-    return factory
+from .test_serving import GATE_OPEN, allow_steps, make_factory
 
 
 class Worker:
@@ -249,7 +213,7 @@ class SockChannel:
 
 
 def _open_session(worker, sid="s-rec", **factory_kw):
-    digest, path = worker.stage(_make_factory(**factory_kw))
+    digest, path = worker.stage(make_factory(**factory_kw))
     worker.send(cmd="serve_open", id=sid, digest=digest, path=path,
                 options={"stats_interval_s": 30.0})
     worker.wait_for(
@@ -344,9 +308,13 @@ def test_inventory_reports_sessions_and_streams(tmp_path):
 def test_serve_resume_states(tmp_path):
     worker = Worker(tmp_path)
     try:
+        # One step and no more until the gate opens: r-live stays
+        # mid-decode and r-queued behind it however slow this test runs.
+        gate = tmp_path / "gate"
+        allow_steps(gate, 1)
         sid = _open_session(
-            worker, "s-res", slots=1, step_delay=0.25, chunk=2,
-            default_cap=20,
+            worker, "s-res", slots=1, chunk=2, default_cap=20,
+            gate=str(gate),
         )
         worker.send(cmd="serve_request", id=sid, rid="r-live", prompt=[0])
         worker.send(cmd="serve_request", id=sid, rid="r-queued", prompt=[50])
@@ -363,7 +331,7 @@ def test_serve_resume_states(tmp_path):
             and e.get("rid") == "r-live"
         )
         assert ack["state"] == "streaming"
-        assert ack["from"] == 0 and ack["sent"] >= 2
+        assert ack["from"] == 0 and ack["sent"] == 2
 
         # Queued behind the single slot: pending, nothing re-emitted.
         worker.send(cmd="serve_resume", id=sid, rid="r-queued", **{"from": 0})
@@ -388,6 +356,7 @@ def test_serve_resume_states(tmp_path):
 
         # Drain both, then resume a FINISHED stream from an offset: the
         # bounded finished-ring re-emits the tail plus the done marker.
+        allow_steps(gate, GATE_OPEN)
         worker.wait_for(
             lambda e: e.get("event") == "telemetry"
             and (e.get("data") or {}).get("type") == "serve.token"

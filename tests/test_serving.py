@@ -16,6 +16,7 @@ against the decode oracle in ``test_continuous.py``.
 """
 
 import asyncio
+import os
 import sys
 import time
 
@@ -51,11 +52,16 @@ def make_serve_executor(tmp_path, **kwargs):
     return TPUExecutor(**pin_cpu_task_env(kwargs))
 
 
-def make_factory(step_delay=0.0, slots=2, chunk=2, default_cap=6):
+def make_factory(step_delay=0.0, slots=2, chunk=2, default_cap=6, gate=None):
     """A stub serving engine, cloudpickled BY VALUE (closure-local class:
     the resident worker cannot import the tests package).  Deterministic
     streams — prompt ``[..., base]`` yields ``base+1, base+2, ...`` — so
-    replay splices are byte-checkable."""
+    replay splices are byte-checkable.  ``gate`` names a file holding the
+    number of steps the engine may take in all (:func:`allow_steps`): a
+    test that must find a stream mid-flight says how far it runs, where a
+    ``step_delay`` would race the test under load."""
+
+    may_step = step_gate(gate) if gate else None
 
     def factory():
         import time as time_mod
@@ -73,6 +79,8 @@ def make_factory(step_delay=0.0, slots=2, chunk=2, default_cap=6):
             def step(self):
                 if step_delay:
                     time_mod.sleep(step_delay)
+                if may_step and self.lanes and not may_step():
+                    return []
                 events = []
                 for rid in list(self.lanes):
                     taken = self.lanes[rid][:chunk]
@@ -91,6 +99,42 @@ def make_factory(step_delay=0.0, slots=2, chunk=2, default_cap=6):
         return Engine()
 
     return factory
+
+
+def step_gate(gate):
+    """``may_step()`` for a stub engine: true, and counted, while the
+    number in the file ``gate`` exceeds the steps taken so far.  A closure,
+    so that it is pickled by value with the stub that calls it."""
+    taken = [0]
+
+    def may_step():
+        import time as time_mod
+
+        try:
+            with open(gate) as f:
+                allowed = int(f.read())
+        except (OSError, ValueError):
+            allowed = 0
+        if taken[0] >= allowed:
+            time_mod.sleep(0.005)
+            return False
+        taken[0] += 1
+        return True
+
+    return may_step
+
+
+def allow_steps(gate, n: int) -> None:
+    """Let the engines gated on ``gate`` take ``n`` steps in all (the
+    file is replaced whole: a worker reads it between steps)."""
+    tmp = f"{gate}.tmp"
+    with open(tmp, "w") as f:
+        f.write(str(n))
+    os.replace(tmp, gate)
+
+
+#: More steps than any stub stream needs: the gate stands open.
+GATE_OPEN = 1 << 30
 
 
 def make_unsupported_factory():

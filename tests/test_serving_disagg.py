@@ -356,6 +356,11 @@ def test_disaggregated_set_routes_long_prompts_through_kv(
     positions for them), short prompts go direct, and every stream is
     byte-exact.  Roles land on the role-declared pools."""
 
+    from covalent_tpu_plugin.obs.tracestore import ensure_trace_store
+
+    store = ensure_trace_store()
+    store.sample = 1.0
+
     async def flow():
         pre = make_replica_executor(tmp_path, "pre")
         dec1 = make_replica_executor(tmp_path, "dec1")
@@ -389,6 +394,7 @@ def test_disaggregated_set_routes_long_prompts_through_kv(
                     prompt, params={"max_new_tokens": 4}
                 ))
             results = [await r.result(timeout=30) for r in requests]
+            trace_ids = [r.span.trace_id for r in requests]
             status = dset.status()
             roles = dict(dset._role_of)
             placements = {
@@ -401,10 +407,13 @@ def test_disaggregated_set_routes_long_prompts_through_kv(
             await dec1.close()
             await dec2.close()
         return results, status, roles, placements, long_prompts, \
-            short_prompts
+            short_prompts, trace_ids
 
-    (results, status, roles, placements, long_prompts,
-     short_prompts) = run_async(flow())
+    try:
+        (results, status, roles, placements, long_prompts,
+         short_prompts, trace_ids) = run_async(flow())
+    finally:
+        store._sample_override = None
     for prompt, tokens in zip(long_prompts + short_prompts, results):
         base = prompt[-1]
         assert tokens == [base + j + 1 for j in range(4)], (prompt, tokens)
@@ -416,6 +425,20 @@ def test_disaggregated_set_routes_long_prompts_through_kv(
     # Role-aware placement: the prefill replica landed on the pool that
     # declared role=prefill.
     assert placements["r0"] == "prefill-pool"
+    # One trace a request from the dispatcher through the prefill worker
+    # to the decode worker: every one finalized, no span without its
+    # parent, and the KV road's requests carry the prefill tile.
+    for i, trace_id in enumerate(trace_ids):
+        view = store.waterfall(trace_id)
+        assert view is not None, f"trace {trace_id} never finalized"
+        assert view["root"] == "serve.request"
+        assert not any(s["orphan"] for s in view["spans"]), view["spans"]
+        assert "ttft_wait" in view["segments"]
+        names = {s["name"] for s in view["spans"]}
+        assert "serve.worker.decode" in names
+        on_kv_road = i < len(long_prompts)
+        assert ("prefill" in view["segments"]) == on_kv_road
+        assert ("serve.worker.prefill" in names) == on_kv_road
 
 
 def test_disaggregated_prefill_kill_mid_traffic_degrades_byte_equal(
@@ -624,13 +647,14 @@ def test_disaggregated_kv_rides_cas_road_without_frames(
                 for i in range(2)
             ]
             results = [await r.result(timeout=30) for r in requests]
-            # Wait for a stats record carrying the worker's kv counters.
+            # Wait for the stats record carrying the worker's final kv
+            # counters: one taken between the two admissions counts 1.
             decode_sup = next(
                 sup for rid, sup in dset._replicas.items()
                 if dset._role_of[rid] == "decode"
             )
             for _ in range(100):
-                if decode_sup.stats.get("kv_admits"):
+                if decode_sup.stats.get("kv_admits") == len(requests):
                     break
                 await asyncio.sleep(0.05)
             kv_admits = decode_sup.stats.get("kv_admits")

@@ -189,9 +189,8 @@ def test_banded_backward_gqa_exact():
 
 
 def test_windowed_block_picker():
-    """Windowed defaults follow the r4 hardware sweep winners
-    (benchmarks/WINDOW_SWEEP.md): (512, 512) for w <= 512, (1024, 1024)
-    wider; full-attention calls keep the full-attention defaults; fitted
+    """Windowed defaults follow the r4 hardware sweep's winners:
+    (512, 512) for w <= 512, (1024, 1024) wider; full-attention calls keep the full-attention defaults; fitted
     down for short sequences; explicit blocks always win."""
     from covalent_tpu_plugin.ops.attention import (
         _DEFAULT_BLOCK_K,
