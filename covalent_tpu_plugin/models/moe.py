@@ -20,6 +20,7 @@ Semantics (Switch Transformer):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -171,43 +172,157 @@ class Router(nn.Module):
 # by adding slabs, where token-major would put k on the tiles' second-minor
 # dimension and every reshape would copy.
 
-
-@jax.custom_vjp
-def _group_rows(tokens, order, inverse):
-    """``(T, C)`` -> ``(T k, C)``: row ``r`` is the token of pair
-    ``order[r]``.  The transpose gathers too (``inverse`` undoes
-    ``order``), where a plain gather's would scatter-add."""
-    return tokens[order % tokens.shape[0]]
-
-
-def _group_rows_fwd(tokens, order, inverse):
-    return _group_rows(tokens, order, inverse), (inverse, tokens.shape[0])
+#: The row buffer's room, in even shares: the rows the held experts get
+#: when every expert is chosen alike.  Only the held experts' outputs reach
+#: this chip's loss, so the router drifts toward them: over a window the
+#: mean reads 1.84 even shares and one expert peaks at 2.33 times its own
+#: (PERF.md §5, §6 PR 32).  Four leaves the late steps room; a step that
+#: routes more than that here takes the whole buffer, so no row is dropped.
+BUFFER_EVEN_SHARES = 4
+#: The buffer's rows are a multiple of this, whatever the shares come to.
+BUFFER_ROW_TILE = 512
 
 
-def _group_rows_bwd(res, g):
-    inverse, n_tokens = res
-    return g[inverse].reshape(-1, n_tokens, g.shape[-1]).sum(
-        axis=0, dtype=jnp.float32).astype(g.dtype), None, None
+def buffer_rows(pairs: int, held: int, n_experts: int) -> int:
+    """Rows of the held experts' buffer: ``BUFFER_EVEN_SHARES`` times the
+    even share of the ``pairs``, and never more than all of them."""
+    rows = -(-BUFFER_EVEN_SHARES * pairs * held // n_experts)
+    return min(pairs, -(-rows // BUFFER_ROW_TILE) * BUFFER_ROW_TILE)
+
+
+def _rows_of_pairs(tokens, order, n_rows: int):
+    """``(T, C)`` -> ``(n_rows, C)``: row ``r`` is the token of pair
+    ``order[r]``, for the first ``n_rows`` places of the grouped order."""
+    return tokens[order[:n_rows] % tokens.shape[0]]
+
+
+def _sum_of_pairs(rows, inverse, n_tokens: int, n_live):
+    """``(n_rows, C)`` -> ``(T, C)``: a token's sum, in float32, over its
+    pairs' rows (pair ``p`` stands at place ``inverse[p]``).  Only the
+    first ``n_live`` rows are read: a pair whose place lies past them, or
+    past the rows, reads a zero.  The transpose of :func:`_rows_of_pairs`,
+    as a gather."""
+    n_rows = rows.shape[0]
+    picked = jnp.where(
+        (inverse < jnp.minimum(n_live, n_rows))[:, None],
+        rows[jnp.minimum(inverse, n_rows - 1)], 0)
+    return picked.reshape(-1, n_tokens, rows.shape[-1]).sum(
+        axis=0, dtype=jnp.float32).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _group_rows(n_rows, tokens, order, inverse, n_live):
+    """:func:`_rows_of_pairs`, whose transpose gathers too (``inverse``
+    undoes ``order``), where a plain gather's would scatter-add, and reads
+    the live rows' cotangents alone."""
+    return _rows_of_pairs(tokens, order, n_rows)
+
+
+def _group_rows_fwd(n_rows, tokens, order, inverse, n_live):
+    return (_rows_of_pairs(tokens, order, n_rows),
+            (inverse, tokens.shape[0], n_live))
+
+
+def _group_rows_bwd(n_rows, res, g):
+    inverse, n_tokens, n_live = res
+    return _sum_of_pairs(g, inverse, n_tokens, n_live), None, None, None
 
 
 _group_rows.defvjp(_group_rows_fwd, _group_rows_bwd)
 
 
-@jax.custom_vjp
-def _ungroup_rows(rows, order, inverse):
-    """``(T k, C)`` rows in grouped order -> the pairs' own order."""
-    return rows[inverse]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ungroup_rows(n_tokens, rows, order, inverse, n_live):
+    """Rows in grouped order -> each token's sum over its pairs
+    (:func:`_sum_of_pairs`): no array of one row a pair is left between
+    the gather and the sum for the transpose to broadcast into."""
+    return _sum_of_pairs(rows, inverse, n_tokens, n_live)
 
 
-def _ungroup_rows_fwd(rows, order, inverse):
-    return rows[inverse], (order,)
+def _ungroup_rows_fwd(n_tokens, rows, order, inverse, n_live):
+    return (_sum_of_pairs(rows, inverse, n_tokens, n_live),
+            (order, rows.shape[0]))
 
 
-def _ungroup_rows_bwd(res, g):
-    return g[res[0]], None, None
+def _ungroup_rows_bwd(n_tokens, res, g):
+    order, n_rows = res
+    return _rows_of_pairs(g, order, n_rows), None, None, None
 
 
 _ungroup_rows.defvjp(_ungroup_rows_fwd, _ungroup_rows_bwd)
+
+
+def _held_result(n_rows, tokens, pair_weight, order, inverse, group_sizes,
+                 wg, wu, wd):
+    """The held experts' part of every token's result, over a buffer of
+    the first ``n_rows`` places of the grouped order: right where no more
+    pairs than that chose a held expert.
+
+    Rows past the held ones belong to no group: a grouped product reads
+    none of them and leaves whatever it finds in their place (PR 28: a NaN
+    there reached every gradient).  The hidden rows (1024 wide) are masked
+    where they leave a product and where they enter the last one, so that
+    nothing of them reaches the weights' gradient on the way back; the
+    model-wide rows (3584 wide) are never masked: gathered, they hold some
+    token's numbers, and what a product leaves there is not read
+    (``n_live``), forward or back."""
+    n_live = jnp.sum(group_sizes)
+    live = (jnp.arange(n_rows) < n_live)[:, None]
+    rows = _group_rows(n_rows, tokens, order, inverse, n_live)
+    gate = jnp.where(live, jax.lax.ragged_dot(rows, wg, group_sizes), 0)
+    up = jnp.where(live, jax.lax.ragged_dot(rows, wu, group_sizes), 0)
+    # A row's weight goes onto its hidden row (1024 wide) and not onto its
+    # output (3584 wide): scaling rows commutes with the product.
+    row_weight = pair_weight[order[:n_rows]][:, None].astype(rows.dtype)
+    hidden = jnp.where(live, nn.silu(gate) * up * row_weight, 0)
+    out = jax.lax.ragged_dot(hidden, wd, group_sizes)
+    return _ungroup_rows(tokens.shape[0], out, order, inverse, n_live)
+
+
+# The bounded buffer where the step's held pairs fit it, else the whole
+# one.  A plain ``cond`` under autodiff returns the union of its branches'
+# residuals, each branch filling the other's with zeros (jax 0.9.0,
+# ``_join_cond_outputs``): the bounded branch would write zeros the size of
+# everything the whole buffer keeps.  So the pair has a rule of its own,
+# which keeps its inputs and nothing else (what a conditional hands to a
+# later one is held apart for every layer at once: 0.27 GB of the compiled
+# step for the two hidden factors, PERF.md §6 PR 32), and whose transpose
+# is a ``cond`` over the two branches' own.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _bounded_or_whole(buffers, fits, *operands):
+    """:func:`_held_result` over the first of the two ``buffers`` (their
+    rows: the bounded one's, the whole one's) where ``fits``, else over
+    the second."""
+    bounded, whole = (functools.partial(_held_result, n) for n in buffers)
+    return jax.lax.cond(fits, bounded, whole, *operands)
+
+
+def _bounded_or_whole_fwd(buffers, fits, *operands):
+    return _bounded_or_whole(buffers, fits, *operands), (fits, operands)
+
+
+def _bounded_or_whole_bwd(buffers, res, g):
+    fits, operands = res
+    tokens, pair_weight, order, inverse, group_sizes, wg, wu, wd = operands
+
+    def transposed(n_rows, g):
+        _, transpose = jax.vjp(
+            lambda tokens, pair_weight, wg, wu, wd: _held_result(
+                n_rows, tokens, pair_weight, order, inverse, group_sizes,
+                wg, wu, wd), tokens, pair_weight, wg, wu, wd)
+        return transpose(g)
+
+    bounded, whole = (functools.partial(transposed, n) for n in buffers)
+    # Fenced: the compiler would move the kernels' gradients' way to
+    # float32 into both branches, which then hold both forms of all three.
+    d_tokens, d_weight, d_wg, d_wu, d_wd = jax.lax.optimization_barrier(
+        jax.lax.cond(fits, bounded, whole, g))
+    return None, d_tokens, d_weight, None, None, None, d_wg, d_wu, d_wd
+
+
+_bounded_or_whole.defvjp(_bounded_or_whole_fwd, _bounded_or_whole_bwd)
 
 
 class HeldExperts(nn.Module):
@@ -215,9 +330,10 @@ class HeldExperts(nn.Module):
     pair whose expert is held becomes a row; rows are grouped by expert (a
     stable sort, the others' pairs last) and each of the three matmuls is
     one grouped product over the held experts (``jax.lax.ragged_dot``).
-    The row buffer has room for every pair, so no row is ever dropped,
-    whatever the imbalance; rows past the held ones are masked, not
-    computed on."""
+    The row buffer has room for :func:`buffer_rows` of them; a step that
+    sends more here takes a buffer with room for every pair instead, so no
+    row is ever dropped, whatever the imbalance.  Rows past the held ones
+    are not computed on, and what stands there is never read."""
 
     config: object  # TransformerConfig
 
@@ -248,37 +364,26 @@ class HeldExperts(nn.Module):
         inverse = jnp.argsort(order)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
-        n_rows = jnp.sum(group_sizes)
-        # Rows past the held ones belong to no group.
-        live = (jnp.arange(order.shape[0]) < n_rows)[:, None]
-        rows = _group_rows(tokens, order, inverse)
-        # A row's weight goes onto its hidden row (1024 wide) and not onto
-        # its output (3584 wide): scaling rows commutes with the product.
-        # Rows past the held ones are whatever a grouped product leaves
-        # there: each product's are masked, so that nothing of them
-        # reaches a later product or, transposed, a gradient.
-        row_weight = weights.T.reshape(-1)[order][:, None].astype(cfg.dtype)
-
-        def grouped(lhs, kernels):
-            # Masked going in as well: transposed, that zeroes the dead
-            # rows of the product's gradient, which are as undefined (a
-            # NaN there times a masked zero is a NaN in the router).
-            return jnp.where(live, jax.lax.ragged_dot(
-                jnp.where(live, lhs, 0), kernels, group_sizes), 0)
-
-        h = nn.silu(grouped(rows, wg)) * grouped(rows, wu) * row_weight
-        out = grouped(h, wd)
-        routed = jnp.sum(
-            _ungroup_rows(out, order, inverse).reshape(
-                ex.top_k, n_tokens, d_model), axis=0, dtype=jnp.float32,
-        ).astype(cfg.dtype)
+        n_held = jnp.sum(group_sizes)
+        pairs = order.shape[0]
+        bound = buffer_rows(pairs, held, ex.n_experts)
+        fits = n_held <= bound
+        operands = (tokens, weights.T.reshape(-1), order, inverse,
+                    group_sizes, wg, wu, wd)
+        if bound == pairs:  # always fits: one path, no conditional
+            routed = _held_result(pairs, *operands)
+        else:
+            routed = _bounded_or_whole((bound, pairs), fits, *operands)
         # What a trace cannot see: the rows this step sent through the held
-        # experts, the most loaded one's over the mean, and the held pairs
-        # that got no row (none, by construction: counted, not assumed).
+        # experts, the most loaded one's over the mean, the held pairs that
+        # got no row (none, by construction: counted, not assumed), and
+        # whether the step took the whole buffer here (1) or the bounded.
+        placed = jnp.minimum(n_held, jnp.where(fits, bound, pairs))
         self.sow("intermediates", "moe_stats", jnp.stack([
-            n_rows.astype(jnp.float32),
-            jnp.max(group_sizes) * held / jnp.maximum(n_rows, 1),
-            (jnp.sum(is_held) - jnp.sum(live)).astype(jnp.float32),
+            n_held.astype(jnp.float32),
+            jnp.max(group_sizes) * held / jnp.maximum(n_held, 1),
+            (jnp.sum(is_held) - placed).astype(jnp.float32),
+            (~fits).astype(jnp.float32),
         ]))
         return routed
 
@@ -305,10 +410,11 @@ class RoutedExperts(nn.Module):
 
 
 def collect_moe_stats(intermediates):
-    """Every sown ``moe_stats`` triple, stacked ``(layers, 3)``: held rows,
-    peak load over mean, dropped rows; None where no layer sowed one."""
+    """Every sown ``moe_stats`` record, stacked ``(layers, 4)``: held rows,
+    peak load over mean, dropped rows, whether the layer took the whole
+    buffer; None where no layer sowed one."""
     found = [
-        jnp.reshape(leaf, (-1, 3))
+        jnp.reshape(leaf, (-1, 4))
         for path, leaf in jax.tree_util.tree_flatten_with_path(
             intermediates)[0]
         if any(getattr(entry, "key", None) == "moe_stats" for entry in path)

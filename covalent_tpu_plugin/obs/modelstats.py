@@ -1,7 +1,8 @@
 """What a model counts about its own steps, where no trace can see it: the
 rows a step sent through the routed experts this process holds, how uneven
-they fell, and the rows that got no place (none, by construction: counted,
-not assumed).
+they fell, the rows that got no place (none, by construction: counted,
+not assumed), and the layers whose bounded row buffer the step outgrew, so
+that they took the whole one.
 
 The train step returns the counts as device arrays beside its metrics;
 :func:`defer` keeps them and reads them when the NEXT step's arrive (by
@@ -39,8 +40,9 @@ _pending: list = []
 def _rows(registry: Registry):
     return registry.counter(
         MOE_ROWS, "Rows (token, choice pairs) train steps sent through the "
-        "routed experts held here (held), and held pairs that got no row "
-        "(dropped)", label_names=("kind",))
+        "routed experts held here (held), held pairs that got no row "
+        "(dropped), and layers that took the whole row buffer for the "
+        "bounded one (whole_buffer)", label_names=("kind",))
 
 
 def _steps(registry: Registry):
@@ -56,8 +58,9 @@ def _load(registry: Registry):
 
 
 def defer(stats) -> None:
-    """Keep one step's ``(layers, 3)`` device array (held rows, peak load
-    over mean, dropped rows) and record every earlier step's."""
+    """Keep one step's ``(layers, 4)`` device array (held rows, peak load
+    over mean, dropped rows, whole buffer taken) and record every earlier
+    step's."""
     with _lock:
         ready, _pending[:] = list(_pending), [stats]
     _record(ready)
@@ -70,9 +73,12 @@ def _record(ready: list, registry: Registry = REGISTRY) -> None:
 
     rows, steps, load = _rows(registry), _steps(registry), _load(registry)
     for stats in ready:
-        table = np.asarray(stats, dtype=np.float64).reshape(-1, 3)
+        table = np.asarray(stats, dtype=np.float64)
+        table = table.reshape(-1, table.shape[-1])
         rows.labels(kind="held").inc(float(table[:, 0].sum()))
         rows.labels(kind="dropped").inc(max(0.0, float(table[:, 2].sum())))
+        # A program from before the bounded buffer counts three numbers.
+        rows.labels(kind="whole_buffer").inc(float(table[:, 3:4].sum()))
         steps.inc()
         worst = float(table[:, 1].max())
         load.labels(stat="last").set(worst)
@@ -116,12 +122,14 @@ def absorb_worker(worker_totals, registry: Registry = REGISTRY) -> None:
         WORKER_MOE_STEPS, "Train steps whose routed layers reported their "
         "rows, as workers reported them").inc(steps)
     total = registry.counter(
-        WORKER_MOE_ROWS, "Rows through held routed experts (held) and held "
-        "pairs without a row (dropped), as workers reported them",
+        WORKER_MOE_ROWS, "Rows through held routed experts (held), held "
+        "pairs without a row (dropped) and layers that took the whole row "
+        "buffer (whole_buffer), as workers reported them",
         label_names=("kind",))
     per_step = registry.gauge(
-        WORKER_MOE_ROWS_PER_STEP, "Rows a train step, by kind, of the worker "
-        "that reported last", label_names=("kind",))
+        WORKER_MOE_ROWS_PER_STEP, "Rows (held, dropped) and layers "
+        "(whole_buffer) a train step, of the worker that reported last",
+        label_names=("kind",))
     for kind, value in rows.items():
         total.labels(kind=kind).inc(value)
         per_step.labels(kind=kind).set(value / steps)

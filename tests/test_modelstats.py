@@ -21,19 +21,21 @@ def test_no_routed_layer_no_totals():
 
 def test_totals_flush_the_pending_step_and_absorb_makes_the_worker_series():
     worker, dispatcher = Registry(), Registry()
-    steps = [np.array([[100.0, 1.2, 0.0], [120.0, 1.5, 0.0]]),
-             np.array([[90.0, 1.1, 0.0], [110.0, 1.3, 0.0]])]
+    # The second layer outgrew its bounded buffer in the first step.
+    steps = [np.array([[100.0, 1.2, 0.0, 0.0], [120.0, 1.5, 0.0, 1.0]]),
+             np.array([[90.0, 1.1, 0.0, 0.0], [110.0, 1.3, 0.0, 0.0]])]
     modelstats._record(steps, worker)
     totals = modelstats.totals(worker)
     assert totals["steps"] == 2
-    assert totals["rows"] == {"held": 420.0, "dropped": 0.0}
+    assert totals["rows"] == {
+        "held": 420.0, "dropped": 0.0, "whole_buffer": 1.0}
     assert totals["load_ratio"] == {"last": 1.3, "peak": 1.5}
     json.dumps(totals)
     modelstats.absorb_worker(totals, dispatcher)
     snap = dispatcher.snapshot()["metrics"]
     per_step = {e["labels"]["kind"]: e["value"] for e in
                 snap["covalent_tpu_worker_moe_rows_per_step"]["series"]}
-    assert per_step == {"held": 210.0, "dropped": 0.0}
+    assert per_step == {"held": 210.0, "dropped": 0.0, "whole_buffer": 0.5}
     ratio = {e["labels"]["stat"]: e["value"] for e in
              snap["covalent_tpu_worker_moe_load_ratio"]["series"]}
     assert ratio["peak"] == 1.5
@@ -45,22 +47,42 @@ def test_totals_flush_the_pending_step_and_absorb_makes_the_worker_series():
         modelstats.absorb_worker(bad, dispatcher)
 
 
+def test_three_numbers_from_an_older_program_still_record_and_absorb():
+    worker, dispatcher = Registry(), Registry()
+    modelstats._record([np.array([[100.0, 1.2, 0.0], [120.0, 1.5, 0.0]])],
+                       worker)
+    totals = modelstats.totals(worker)
+    assert totals["rows"] == {
+        "held": 220.0, "dropped": 0.0, "whole_buffer": 0.0}
+    # And what an older worker sends home has no such kind at all.
+    del totals["rows"]["whole_buffer"]
+    modelstats.absorb_worker(totals, dispatcher)
+    per_step = {e["labels"]["kind"]: e["value"] for e in
+                dispatcher.snapshot()["metrics"][
+                    "covalent_tpu_worker_moe_rows_per_step"]["series"]}
+    assert per_step == {"held": 220.0, "dropped": 0.0}
+
+
 def test_deferred_counts_reach_the_registry_a_step_late():
     before = modelstats.totals().get("steps", 0)
-    modelstats.defer(np.array([[10.0, 1.0, 0.0]]))
-    modelstats.defer(np.array([[12.0, 1.0, 0.0]]))
+    whole = modelstats.totals().get("rows", {}).get("whole_buffer", 0)
+    modelstats.defer(np.array([[10.0, 1.0, 0.0, 1.0]]))
+    modelstats.defer(np.array([[12.0, 1.0, 0.0, 1.0]]))
     from covalent_tpu_plugin.obs import REGISTRY
 
     # One step recorded, one pending; asking for the totals reads it too.
     assert REGISTRY.get(modelstats.MOE_STEPS).value == before + 1
-    assert modelstats.totals()["steps"] == before + 2
+    totals = modelstats.totals()
+    assert totals["steps"] == before + 2
+    assert totals["rows"]["whole_buffer"] == whole + 2
 
 
 def test_the_result_trailer_carries_the_models_counts():
-    modelstats.defer(np.array([[5.0, 1.0, 0.0]]))
+    modelstats.defer(np.array([[5.0, 1.0, 0.0, 0.0]]))
     trailer = json.loads(harness._trace_trailer([]).decode())
     assert trailer["model"]["steps"] >= 1
-    assert set(trailer["model"]["rows"]) == {"held", "dropped"}
+    assert set(trailer["model"]["rows"]) == {
+        "held", "dropped", "whole_buffer"}
 
 
 @pytest.mark.parametrize("field", ["latent", "routed", "streams"])
