@@ -25,7 +25,6 @@ import math
 
 import flax.linen as nn
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops.attention import (
     flash_attention,
@@ -33,7 +32,7 @@ from ..ops.attention import (
     mha_reference,
     on_tpu,
 )
-from .layers import RMSNorm, rotary
+from .layers import RMSNorm, YarnConfig, rotary, yarn_inv_freq
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,31 +69,16 @@ class LatentAttentionConfig:
         return _yarn_mscale(self.rope_factor, self.rope_mscale) / _yarn_mscale(
             self.rope_factor, self.rope_mscale_all_dim)
 
+    @property
+    def yarn(self) -> YarnConfig:
+        return YarnConfig(
+            factor=self.rope_factor, original_max=self.rope_original_max,
+            beta_fast=self.rope_beta_fast, beta_slow=self.rope_beta_slow,
+            attention_factor=self.rope_amplitude)
+
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-def yarn_inv_freq(dim: int, base: float, lat: LatentAttentionConfig):
-    """The ``dim // 2`` inverse frequencies: ``base``'s geometric run, each
-    divided by ``factor`` where its wavelength exceeds the original context
-    (fewer than ``beta_slow`` turns in it), kept where it makes more than
-    ``beta_fast`` turns, a linear ramp between."""
-    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if lat.rope_factor <= 1:
-        return plain.astype(np.float32)
-
-    def turns_at(turns):
-        return dim * math.log(lat.rope_original_max / (turns * 2 * math.pi)) / (
-            2 * math.log(base))
-
-    low = max(math.floor(turns_at(lat.rope_beta_fast)), 0)
-    high = min(math.ceil(turns_at(lat.rope_beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
-    return (plain / lat.rope_factor * ramp + plain * (1 - ramp)).astype(
-        np.float32)
 
 
 class LatentProjections(nn.Module):
@@ -127,12 +111,11 @@ class LatentProjections(nn.Module):
         k_nope, v = (kv[..., : lat.qk_nope_head_dim],
                      kv[..., lat.qk_nope_head_dim:])
 
-        freqs = yarn_inv_freq(lat.qk_rope_head_dim, cfg.rope_base, lat)
-        amplitude = lat.rope_amplitude
-        q_rope = rotary(q[..., lat.qk_nope_head_dim:], freqs=freqs)
-        k_rope = rotary(k_rope, freqs=freqs)
-        if amplitude != 1.0:
-            q_rope, k_rope = q_rope * amplitude, k_rope * amplitude
+        yarn = lat.yarn
+        freqs = yarn_inv_freq(lat.qk_rope_head_dim, cfg.rope_base, yarn)
+        q_rope = rotary(q[..., lat.qk_nope_head_dim:], freqs=freqs,
+                        amplitude=yarn.attention_factor)
+        k_rope = rotary(k_rope, freqs=freqs, amplitude=yarn.attention_factor)
         q = jnp.concatenate([q[..., : lat.qk_nope_head_dim], q_rope], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1]

@@ -116,9 +116,10 @@ class MoEMlp(nn.Module):
 
 @dataclasses.dataclass(frozen=True)
 class RoutedExpertsConfig:
-    """Top-k of ``n_experts`` by sigmoid score (DeepSeek-V3's router,
-    arXiv:2412.19437, without its group limit), gated experts of ``d_ff``,
-    ``n_shared`` shared ones, and the share of the experts held here."""
+    """Top-k of ``n_experts`` by score, gated experts of ``d_ff``,
+    ``n_shared`` shared ones, and the share of the experts held here.  The
+    defaults are DeepSeek-V3's router (arXiv:2412.19437) without its group
+    limit: sigmoid scores and a correction bias on the choice."""
 
     n_experts: int
     top_k: int
@@ -126,10 +127,20 @@ class RoutedExpertsConfig:
     n_shared: int = 1
     routed_scaling: float = 1.0
     norm_topk: bool = True
+    #: "sigmoid": each expert's own; "softmax": over all ``n_experts``.
+    score: str = "sigmoid"
+    #: a bias leaf added to the scores for the choice alone, which no
+    #: gradient reaches (corrected outside it); False = no such leaf.
+    correction_bias: bool = True
     #: ``(first, count)``: the experts this layer holds and computes (expert
     #: parallelism's share; the others' part of the result is left out, as
     #: the chips that hold them would add it).  None = all of them.
     held: tuple | None = None
+
+    def __post_init__(self):
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"score must be 'sigmoid' or 'softmax', got {self.score!r}")
 
     @property
     def held_range(self) -> tuple[int, int]:
@@ -138,9 +149,10 @@ class RoutedExpertsConfig:
 
 class Router(nn.Module):
     """Scores in float32 over ALL the experts, whichever are held:
-    ``s = sigmoid(h W_r)``; the ``top_k`` of ``s + b`` are chosen (``b``:
-    the correction bias, which no gradient reaches), weighted ``s[chosen] /
-    sum * routed_scaling``."""
+    ``s = sigmoid(h W_r)``, or ``softmax(h W_r)`` over them all; the
+    ``top_k`` of ``s + b`` are chosen (``b``: the correction bias, which no
+    gradient reaches, where the configuration has one), weighted
+    ``s[chosen] / sum * routed_scaling``."""
 
     config: object  # TransformerConfig
 
@@ -155,11 +167,17 @@ class Router(nn.Module):
             kernel_init=nn.with_partitioning(
                 nn.initializers.normal(0.02), ("embed", None)),
         )(tokens.astype(jnp.float32))
-        bias = self.param(
-            "bias", nn.initializers.zeros_init(), (ex.n_experts,), jnp.float32)
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias), ex.top_k)     # (T, k)
+        if ex.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+        biased = scores
+        if ex.correction_bias:
+            bias = self.param(
+                "bias", nn.initializers.zeros_init(), (ex.n_experts,),
+                jnp.float32)
+            biased = scores + jax.lax.stop_gradient(bias)
+        _, chosen = jax.lax.top_k(biased, ex.top_k)             # (T, k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if ex.norm_topk:
             weights = weights / (
@@ -390,8 +408,9 @@ class HeldExperts(nn.Module):
 
 class RoutedExperts(nn.Module):
     """Drop-in MLP replacement: ``sum over chosen and held g_e E_e(h) +
-    shared(h)``, ``E`` and ``shared`` gated MLPs.  No balance term: the
-    router's bias is corrected outside the gradient (``noaux_tc``)."""
+    shared(h)``, ``E`` and ``shared`` gated MLPs.  No balance term: where
+    there is a correction bias it is corrected outside the gradient
+    (``noaux_tc``)."""
 
     config: object  # TransformerConfig
 

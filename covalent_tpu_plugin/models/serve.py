@@ -178,9 +178,10 @@ class BlockUnsupported(ValueError):
     """Typed refusal: continuous serving has no cache for this block form.
 
     Latent attention would cache its latent, routed experts decode through
-    grouped products, and n residual streams change what a lane's state
-    is: the engine has none of the three, and running such a model through
-    the plain K/V path would serve wrong tokens.  Duck-tagged PERMANENT
+    grouped products, n residual streams change what a lane's state is,
+    and attention that differs by layer wants a cache a layer type: the
+    engine has none of the four, and running such a model through the
+    plain K/V path would serve wrong tokens.  Duck-tagged PERMANENT
     like :class:`RollingCacheUnsupported`."""
 
     fault_label = "serve_model_unsupported"
@@ -193,13 +194,15 @@ def _require_plain_cache(config, what: str) -> None:
             f"{what} does not support rolling_cache models "
             "(slot reset assumes the plain cache layout)"
         )
-    held = [name for name in ("latent", "routed", "streams")
+    held = [name
+            for name in ("latent", "routed", "streams", "attention_types")
             if getattr(config, name, None) is not None]
     if held:
         raise BlockUnsupported(
             f"{what} does not serve a model with {', '.join(held)} set "
-            "(latent attention, routed experts, residual streams): the "
-            "train path runs it, the engine has no cache for it yet"
+            "(latent attention, routed experts, residual streams, attention "
+            "by layer): the train path runs it, the engine has no cache for "
+            "it yet"
         )
 
 

@@ -216,10 +216,9 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     the model returns final FEATURES and the loss walks them in tiles of
     rows, each tile's scores the whole vocabulary wide, forming the
     gradients in the same pass, so the (B, S, vocab) logits tensor is
-    never materialised in HBM.  ``vocab_chunk`` (a divisor of the
-    vocabulary) bounds the scores live at once, at B·S x vocab_chunk
-    elements.  Requires a plain float lm_head kernel (no lm_head LoRA,
-    unquantized)."""
+    never materialised in HBM.  ``vocab_chunk`` bounds the scores live at
+    once, at B·S x vocab_chunk elements.  Requires a plain float lm_head
+    kernel (no lm_head LoRA, unquantized)."""
     from .moe import collect_moe_stats
 
     tokens = batch["tokens"]

@@ -17,6 +17,7 @@ stacked on a ``layers`` axis) and optionally rematerialised
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any
 
@@ -33,10 +34,34 @@ from ..ops.attention import (
 )
 from ..ops.ring_attention import sequence_parallel_attention
 from .latent import LatentAttention, LatentAttentionConfig
-from .layers import MlpBlock, RMSNorm, rotary as _rotary
+from .layers import (
+    MlpBlock,
+    RMSNorm,
+    YarnConfig,
+    rotary as _rotary,
+    yarn_inv_freq,
+)
 from .moe import MoEMlp, RoutedExperts, RoutedExpertsConfig
 from .quant import dense_general
 from .streams import ResidualStreamsConfig, StreamMix
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionType:
+    """What one type of layer's attention may set for itself, for a model
+    that mixes window and full layers.  ``name`` is the ``jax.named_scope``
+    the type's kernel call stands under, forward and backward."""
+
+    name: str
+    n_heads: int
+    sliding_window: int | None = None
+    rope_base: float = 10000.0
+    #: the leading share of a head's dims that turn; the rest pass.
+    rope_share: float = 1.0
+    yarn: YarnConfig | None = None
+    #: a gate a head on the kernel's output, ahead of the out projection:
+    #: ``sigmoid(x W_g)`` of the sublayer's input (arXiv:2505.06708).
+    gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,13 +168,40 @@ class TransformerConfig:
     layer_kinds: tuple | None = None
     #: latent attention (models/latent.py) in place of ``Attention``.
     latent: LatentAttentionConfig | None = None
-    #: top-k sigmoid-routed gated experts with shared ones and a held share
-    #: (models/moe.py ``RoutedExperts``) in the "moe" layers.
+    #: top-k routed gated experts (sigmoid or softmax scores) with shared
+    #: ones and a held share (models/moe.py ``RoutedExperts``) in the "moe"
+    #: layers.
     routed: RoutedExpertsConfig | None = None
     #: n residual streams mixed around every sublayer (models/streams.py).
     streams: ResidualStreamsConfig | None = None
+    #: a head's width; None = ``d_model // n_heads``.  A width of its own
+    #: where heads x width is not the model's (48 x 128 over 3072).
+    head_dim: int | None = None
+    #: attention that differs by layer: the types, and each layer's by
+    #: name.  They replace ``n_heads``, ``sliding_window`` and ``rope_base``
+    #: in ``Attention`` (train path; mixed types need ``scan_layers=False``).
+    #: None = every layer alike, by the fields above.
+    attention_types: tuple | None = None
+    attention_kinds: tuple | None = None
 
     def __post_init__(self):
+        if (self.attention_types is None) != (self.attention_kinds is None):
+            raise ValueError(
+                "attention_types and attention_kinds are given together")
+        if self.attention_types is not None:
+            names = [t.name for t in self.attention_types]
+            if len(self.attention_kinds) != self.n_layers or set(
+                    self.attention_kinds) - set(names):
+                raise ValueError(
+                    f"attention_kinds must name one of {names} for each of "
+                    f"{self.n_layers} layers, got {self.attention_kinds!r}")
+            if self.latent is not None or self.decode:
+                raise ValueError(
+                    "attention by layer runs Attention on the train path")
+            if self.scan_layers and len(set(self.attention_kinds)) > 1:
+                raise ValueError(
+                    "layers of two attention types cannot be scanned: "
+                    "scan_layers=False")
         kinds = self.layer_kinds
         if kinds is not None:
             if len(kinds) != self.n_layers or set(kinds) - {"dense", "moe"}:
@@ -181,8 +233,14 @@ class TransformerConfig:
                 raise ValueError("attention_sinks require sliding_window")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def head_width(self) -> int:
+        return self.head_dim or self.d_model // self.n_heads
+
+    def attention_of(self, layer: int) -> AttentionType | None:
+        if self.attention_types is None:
+            return None
+        name = self.attention_kinds[layer]
+        return next(t for t in self.attention_types if t.name == name)
 
     def kind_of(self, layer: int) -> str:
         if self.layer_kinds is not None:
@@ -197,10 +255,13 @@ def lm_125m_config(**overrides) -> TransformerConfig:
 
 class Attention(nn.Module):
     config: TransformerConfig
+    #: this layer's type where attention differs by layer; None = the
+    #: configuration's own heads, window and rotary base.
+    kind: AttentionType | None = None
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.config
+        cfg, kind = self.config, self.kind
         dense = lambda name, features, axes: dense_general(  # noqa: E731
             cfg.quantized,
             features=features,
@@ -213,18 +274,20 @@ class Attention(nn.Module):
             lora_rank=cfg.lora_rank if name in cfg.lora_targets else 0,
             lora_alpha=cfg.lora_alpha,
         )
-        kv_heads = cfg.n_kv_heads or cfg.n_heads
-        if cfg.n_heads % kv_heads:
+        n_heads = cfg.n_heads if kind is None else kind.n_heads
+        window = cfg.sliding_window if kind is None else kind.sliding_window
+        kv_heads = cfg.n_kv_heads or n_heads
+        if n_heads % kv_heads:
             raise ValueError(
-                f"n_heads {cfg.n_heads} must be divisible by n_kv_heads {kv_heads}"
+                f"n_heads {n_heads} must be divisible by n_kv_heads {kv_heads}"
             )
         # GQA kv projections take the "kv_heads" logical axis (replicated
         # across tensor shards by DEFAULT_RULES) — the small kv head count
         # generally doesn't divide the tensor axis the way "heads" must.
-        kv_axis = "heads" if kv_heads == cfg.n_heads else "kv_heads"
-        q = dense("q_proj", (cfg.n_heads, cfg.head_dim), ("embed", "heads", "kv"))(x)
-        k = dense("k_proj", (kv_heads, cfg.head_dim), ("embed", kv_axis, "kv"))(x)
-        v = dense("v_proj", (kv_heads, cfg.head_dim), ("embed", kv_axis, "kv"))(x)
+        kv_axis = "heads" if kv_heads == n_heads else "kv_heads"
+        q = dense("q_proj", (n_heads, cfg.head_width), ("embed", "heads", "kv"))(x)
+        k = dense("k_proj", (kv_heads, cfg.head_width), ("embed", kv_axis, "kv"))(x)
+        v = dense("v_proj", (kv_heads, cfg.head_width), ("embed", kv_axis, "kv"))(x)
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", kv_axis, "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", kv_axis, "kv"))
@@ -232,8 +295,17 @@ class Attention(nn.Module):
         if cfg.decode:
             return self._decode_step(q, k, v, kv_heads)
 
-        q = _rotary(q, base=cfg.rope_base)
-        k = _rotary(k, base=cfg.rope_base)
+        if kind is None:
+            q = _rotary(q, base=cfg.rope_base)
+            k = _rotary(k, base=cfg.rope_base)
+            scope = contextlib.nullcontext()
+        else:
+            turned = int(cfg.head_width * kind.rope_share)
+            amplitude = 1.0 if kind.yarn is None else kind.yarn.attention_factor
+            freqs = yarn_inv_freq(turned, kind.rope_base, kind.yarn)
+            q, k = (_rotary(t, freqs=freqs, rotary_dim=turned,
+                            amplitude=amplitude) for t in (q, k))
+            scope = jax.named_scope(kind.name)
 
         # (B, S, H, D) -> (B, H, S, D) for the attention kernels
         qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
@@ -252,11 +324,11 @@ class Attention(nn.Module):
                     "attention_sinks are unsupported with attention='ring'"
                     " — use attention='ulysses'"
                 )
-            if impl == "ring" and kv_heads != cfg.n_heads:
+            if impl == "ring" and kv_heads != n_heads:
                 # Ring shards over sequence, not heads: materialising the
                 # group repeat is cheap relative to the ring's kv transfers.
                 # (Ulysses repeats internally only when needed.)
-                group = cfg.n_heads // kv_heads
+                group = n_heads // kv_heads
                 kh = jnp.repeat(kh, group, axis=1)
                 vh = jnp.repeat(vh, group, axis=1)
             # sliding_window composes: the banded ring masks each hop by
@@ -264,31 +336,39 @@ class Attention(nn.Module):
             # to the hops intersecting the band; ulysses swaps
             # sequence<->heads and runs the banded full-sequence kernel
             # locally (ops/ring_attention.py).
-            out = sequence_parallel_attention(
-                qh, kh, vh, cfg.mesh, causal=True,
-                window=cfg.sliding_window, sinks=cfg.attention_sinks,
-                impl="ulysses" if impl == "ulysses" else None,
-            )
-        elif impl == "flash":
-            if cfg.mesh is not None:
-                # Bare pallas_call is opaque to sharding propagation — under
-                # a sharded jit it would all-gather Q/K/V to every device;
-                # the shard_map wrapper keeps each (batch, head) block local.
-                out = flash_attention_sharded(
+            with scope:
+                out = sequence_parallel_attention(
                     qh, kh, vh, cfg.mesh, causal=True,
-                    window=cfg.sliding_window, sinks=cfg.attention_sinks,
+                    window=window, sinks=cfg.attention_sinks,
+                    impl="ulysses" if impl == "ulysses" else None,
                 )
-            else:
-                out = flash_attention(
-                    qh, kh, vh, causal=True, window=cfg.sliding_window,
+        elif impl == "flash":
+            with scope:
+                if cfg.mesh is not None:
+                    # Bare pallas_call is opaque to sharding propagation —
+                    # under a sharded jit it would all-gather Q/K/V to every
+                    # device; the shard_map wrapper keeps each (batch, head)
+                    # block local.
+                    out = flash_attention_sharded(
+                        qh, kh, vh, cfg.mesh, causal=True,
+                        window=window, sinks=cfg.attention_sinks,
+                    )
+                else:
+                    out = flash_attention(
+                        qh, kh, vh, causal=True, window=window,
+                        sinks=cfg.attention_sinks,
+                    )
+        else:
+            with scope:
+                out = mha_reference(
+                    qh, kh, vh, causal=True, window=window,
                     sinks=cfg.attention_sinks,
                 )
-        else:
-            out = mha_reference(
-                qh, kh, vh, causal=True, window=cfg.sliding_window,
-                sinks=cfg.attention_sinks,
-            )
         out = out.transpose(0, 2, 1, 3)
+        if kind is not None and kind.gate:
+            gate = dense("gate_proj", n_heads, ("embed", "heads"))(x)
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate)[..., None]
 
         out = self._out_proj(out)
         return nn.with_logical_constraint(out, ("batch", "seq", "embed"))
@@ -344,11 +424,11 @@ class Attention(nn.Module):
         kv_dtype = jnp.int8 if quant_kv else cfg.dtype
         cached_k = self.variable(
             "cache", "cached_k", jnp.zeros,
-            (batch, cache_len, kv_heads, cfg.head_dim), kv_dtype,
+            (batch, cache_len, kv_heads, cfg.head_width), kv_dtype,
         )
         cached_v = self.variable(
             "cache", "cached_v", jnp.zeros,
-            (batch, cache_len, kv_heads, cfg.head_dim), kv_dtype,
+            (batch, cache_len, kv_heads, cfg.head_width), kv_dtype,
         )
         if quant_kv:
             # One f32 scale per (batch, slot, kv head): zero-init means
@@ -474,11 +554,11 @@ class Attention(nn.Module):
                 slot_pos.value if rolling else jnp.arange(cache_len)
             )
         group = cfg.n_heads // kv_heads
-        qg = q.reshape(batch, slab, kv_heads, group, cfg.head_dim)
+        qg = q.reshape(batch, slab, kv_heads, group, cfg.head_width)
         scores = jnp.einsum(
             "bqhgd,bshd->bhgqs", qg, attend_k.astype(cfg.dtype),
             preferred_element_type=jnp.float32,
-        ) * (cfg.head_dim**-0.5)
+        ) * (cfg.head_width**-0.5)
         if quant_kv:
             # The scale is constant over D, so it factors out of the dot:
             # apply per-(b, s, h) AFTER the matmul — HBM reads stay int8.
@@ -508,7 +588,7 @@ class Attention(nn.Module):
             "bhgqs,bshd->bqhgd", probs, attend_v.astype(cfg.dtype),
             preferred_element_type=jnp.float32,
         )
-        out = out.reshape(batch, slab, cfg.n_heads, cfg.head_dim)
+        out = out.reshape(batch, slab, cfg.n_heads, cfg.head_width)
         return self._out_proj(out.astype(cfg.dtype))
 
 
@@ -519,6 +599,7 @@ class Block(nn.Module):
 
     config: TransformerConfig
     kind: str = "dense"
+    attention: AttentionType | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -526,7 +607,7 @@ class Block(nn.Module):
         if cfg.latent is not None:
             attention = LatentAttention(cfg, name="attention")
         else:
-            attention = Attention(cfg, name="attention")
+            attention = Attention(cfg, kind=self.attention, name="attention")
         if self.kind != "moe":
             mlp = MlpBlock(cfg, name="mlp")
         elif cfg.routed is not None:
@@ -593,10 +674,13 @@ class TransformerLM(nn.Module):
                 split_rngs={"params": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(block_cls(cfg, kind=cfg.kind_of(0), name="layers"), x, None)
+            )(block_cls(cfg, kind=cfg.kind_of(0), attention=cfg.attention_of(0),
+                        name="layers"), x, None)
         else:
             for i in range(cfg.n_layers):
-                x = block_cls(cfg, kind=cfg.kind_of(i), name=f"layer_{i}")(x)
+                x = block_cls(cfg, kind=cfg.kind_of(i),
+                              attention=cfg.attention_of(i),
+                              name=f"layer_{i}")(x)
 
         if cfg.streams is not None:
             x = jnp.sum(x.astype(jnp.float32), axis=1).astype(cfg.dtype)

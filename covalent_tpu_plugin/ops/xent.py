@@ -42,11 +42,8 @@ import numpy as np
 def _row_tiles(tokens: int, vocab: int, chunk: int) -> tuple[int, int]:
     """(rows a tile, tiles): the largest power of two of rows whose scores,
     the whole vocabulary wide, stay within tokens x chunk elements; all the
-    rows in one tile where that bound allows it."""
-    if vocab % chunk:
-        raise ValueError(
-            f"vocab size {vocab} must be divisible by chunk {chunk}"
-        )
+    rows in one tile where that bound allows it.  ``chunk`` need not divide
+    the vocabulary (an eighth of 100,352 is 49 x 256): it only bounds."""
     bound = max(1, tokens * chunk // vocab)
     tile = tokens if bound >= tokens else 1 << (bound.bit_length() - 1)
     return tile, -(-tokens // tile)
@@ -92,8 +89,8 @@ def fused_cross_entropy(x, w, labels, chunk: int = 8192):
     """Mean cross-entropy of ``softmax(x @ w)`` against integer labels.
 
     ``x``: (T, d) features (bf16 on TPU), ``w``: (d, V) lm_head kernel,
-    ``labels``: (T,) int32, ``chunk``: a divisor of V, the bound on the
-    live scores (T x chunk elements).  It matches a bf16-input,
+    ``labels``: (T,) int32, ``chunk``: the bound on the live scores (T x
+    chunk elements).  It matches a bf16-input,
     f32-accumulated logits matmul followed by a stable log-softmax — NOT
     the f32-input matmul path (which is the point: that path runs at
     half MXU rate and writes the full logits tensor).

@@ -75,14 +75,15 @@ def test_yarn_frequencies_and_scale_match_the_reference():
     config = xing4_toy.CONFIG
     lat = arch.model_config(config, max_seq=64).latent
     np.testing.assert_allclose(
-        yarn_inv_freq(config["qk_rope_head_dim"], config["rope_theta"], lat),
+        yarn_inv_freq(config["qk_rope_head_dim"], config["rope_theta"],
+                      lat.yarn),
         arch.yarn_frequencies(config), rtol=1e-6)
     # Published sizes: 64 rope dims, factor 64 over 4096 positions.
     full = LatentAttentionConfig(768, 512, 128, 64, 128, rope_factor=64,
                                  rope_mscale=1, rope_mscale_all_dim=1)
     assert full.softmax_scale == pytest.approx(
         192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
-    freqs = yarn_inv_freq(64, 10000.0, full)
+    freqs = yarn_inv_freq(64, 10000.0, full.yarn)
     plain = 10000.0 ** (-np.arange(0, 64, 2) / 64)
     assert freqs[0] == pytest.approx(plain[0])            # fast dims kept
     assert freqs[-1] == pytest.approx(plain[-1] / 64)     # slow dims scaled

@@ -143,12 +143,22 @@ def test_fused_xent_bf16_inputs_match_dense_bf16():
         assert float(jnp.abs(f - r).max() / jnp.abs(r).max()) < 0.05
 
 
-def test_fused_xent_rejects_ragged_vocab():
-    x = jnp.zeros((4, 8))
-    w = jnp.zeros((8, 100))
-    labels = jnp.zeros((4,), jnp.int32)
-    with pytest.raises(ValueError, match="divisible"):
-        fused_cross_entropy(x, w, labels, 64)
+def test_fused_xent_takes_a_chunk_that_does_not_divide_the_vocabulary():
+    """The chunk bounds the live scores and nothing else: an eighth of a
+    100,352-word vocabulary is 49 x 256 under a chunk of 8,192."""
+    from covalent_tpu_plugin.ops.xent import _row_tiles
+
+    assert _row_tiles(16384, 12544, 8192) == (8192, 2)
+    x, w, labels = _inputs(48, 32, 100)
+    lf, gf = jax.value_and_grad(
+        lambda x, w: fused_cross_entropy(x, w, labels, 64), argnums=(0, 1)
+    )(x, w)
+    lr, gr = jax.value_and_grad(
+        lambda x, w: _ref(x, w, labels), argnums=(0, 1)
+    )(x, w)
+    assert abs(float(lf) - float(lr)) < 1e-5
+    for f, r in zip(gf, gr):
+        assert float(jnp.abs(f - r).max()) < 1e-5
 
 
 def test_lm_loss_fused_path_matches_standard():
