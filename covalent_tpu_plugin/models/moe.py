@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import routed_rows
 from .layers import MlpBlock
 
 
@@ -228,37 +229,89 @@ def _sum_of_pairs(rows, inverse, n_tokens: int, n_live):
         axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
+def _places(order, n_rows: int, group_sizes, tokens):
+    """What the read-back of an ``n_rows`` buffer needs of the grouped
+    order.  Where the buffer has a row for every pair, one row a pair is
+    what there is, and where the shape does not tile
+    (``routed_rows.token_tile``) no kernel runs: ``inverse``, which undoes
+    ``order``, for :func:`_sum_of_pairs`.  Else what the kernel
+    ``moe_readback`` takes (its docstring has each): the walk, which is the
+    live rows sorted by token, a token's in its pairs' own order (so its
+    float32 sum adds the same numbers in the same order either way); and
+    the streams, one for every held expert and choice: inside an expert's
+    group the rows stand in the pairs' order, which is choice-major, so
+    the tokens of one choice's rows ascend.  A sort of ``n_rows`` keys,
+    where ``inverse`` is one of all the pairs."""
+    n_tokens, width = tokens.shape
+    pairs = order.shape[0]
+    k = pairs // n_tokens
+    streams = group_sizes.shape[0] * k
+    tile = routed_rows.token_tile(
+        jax.ShapeDtypeStruct((n_rows, width), tokens.dtype), n_tokens,
+        streams)
+    if n_rows == pairs or tile is None:
+        return jnp.argsort(order)
+    row = jnp.arange(n_rows, dtype=jnp.int32)
+    live = row < jnp.sum(group_sizes)
+    pair = order[:n_rows]
+    choice = pair // n_tokens
+    expert = jnp.sum(
+        row[:, None] >= jnp.cumsum(group_sizes)[None, :], axis=1,
+        dtype=jnp.int32)
+    stream = jnp.where(live, expert * k + choice, streams)
+    key, source = jax.lax.sort(
+        (jnp.where(live, pair % n_tokens * k + choice, pairs),
+         stream << routed_rows.row_bits(n_rows) | row), num_keys=1)
+    token = key // k                              # a dead row's: n_tokens
+    starts = jnp.sum(
+        token[:, None] < jnp.arange(0, n_tokens + 1, tile)[None, :],
+        axis=0, dtype=jnp.int32)
+    bounds = jnp.sum(
+        stream[:, None] < jnp.arange(streams + 1)[None, :],
+        axis=0, dtype=jnp.int32)
+    return starts, source, token, bounds
+
+
+def _sum_by_token(rows, places, n_tokens: int, n_live):
+    """``(n_rows, C)`` -> ``(T, C)``: a token's sum, in float32 and rounded
+    once, over its live rows, in the form ``places`` was made for
+    (:func:`_places`)."""
+    if isinstance(places, tuple):
+        return routed_rows.moe_readback(rows, *places, n_tokens=n_tokens)
+    return _sum_of_pairs(rows, places, n_tokens, n_live)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _group_rows(n_rows, tokens, order, inverse, n_live):
-    """:func:`_rows_of_pairs`, whose transpose gathers too (``inverse``
-    undoes ``order``), where a plain gather's would scatter-add, and reads
-    the live rows' cotangents alone."""
+def _group_rows(n_rows, tokens, order, places, n_live):
+    """:func:`_rows_of_pairs`, whose transpose is :func:`_sum_by_token`,
+    where a plain gather's would scatter-add, and reads the live rows'
+    cotangents alone."""
     return _rows_of_pairs(tokens, order, n_rows)
 
 
-def _group_rows_fwd(n_rows, tokens, order, inverse, n_live):
+def _group_rows_fwd(n_rows, tokens, order, places, n_live):
     return (_rows_of_pairs(tokens, order, n_rows),
-            (inverse, tokens.shape[0], n_live))
+            (places, tokens.shape[0], n_live))
 
 
 def _group_rows_bwd(n_rows, res, g):
-    inverse, n_tokens, n_live = res
-    return _sum_of_pairs(g, inverse, n_tokens, n_live), None, None, None
+    places, n_tokens, n_live = res
+    return _sum_by_token(g, places, n_tokens, n_live), None, None, None
 
 
 _group_rows.defvjp(_group_rows_fwd, _group_rows_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _ungroup_rows(n_tokens, rows, order, inverse, n_live):
-    """Rows in grouped order -> each token's sum over its pairs
-    (:func:`_sum_of_pairs`): no array of one row a pair is left between
-    the gather and the sum for the transpose to broadcast into."""
-    return _sum_of_pairs(rows, inverse, n_tokens, n_live)
+def _ungroup_rows(n_tokens, rows, order, places, n_live):
+    """Rows in grouped order -> each token's sum over its live rows
+    (:func:`_sum_by_token`): no array of one row a pair is left for the
+    transpose to broadcast into."""
+    return _sum_by_token(rows, places, n_tokens, n_live)
 
 
-def _ungroup_rows_fwd(n_tokens, rows, order, inverse, n_live):
-    return (_sum_of_pairs(rows, inverse, n_tokens, n_live),
+def _ungroup_rows_fwd(n_tokens, rows, order, places, n_live):
+    return (_sum_by_token(rows, places, n_tokens, n_live),
             (order, rows.shape[0]))
 
 
@@ -270,7 +323,7 @@ def _ungroup_rows_bwd(n_tokens, res, g):
 _ungroup_rows.defvjp(_ungroup_rows_fwd, _ungroup_rows_bwd)
 
 
-def _held_result(n_rows, tokens, pair_weight, order, inverse, group_sizes,
+def _held_result(n_rows, tokens, pair_weight, order, group_sizes,
                  wg, wu, wd):
     """The held experts' part of every token's result, over a buffer of
     the first ``n_rows`` places of the grouped order: right where no more
@@ -286,7 +339,8 @@ def _held_result(n_rows, tokens, pair_weight, order, inverse, group_sizes,
     (``n_live``), forward or back."""
     n_live = jnp.sum(group_sizes)
     live = (jnp.arange(n_rows) < n_live)[:, None]
-    rows = _group_rows(n_rows, tokens, order, inverse, n_live)
+    places = _places(order, n_rows, group_sizes, tokens)
+    rows = _group_rows(n_rows, tokens, order, places, n_live)
     gate = jnp.where(live, jax.lax.ragged_dot(rows, wg, group_sizes), 0)
     up = jnp.where(live, jax.lax.ragged_dot(rows, wu, group_sizes), 0)
     # A row's weight goes onto its hidden row (1024 wide) and not onto its
@@ -294,7 +348,7 @@ def _held_result(n_rows, tokens, pair_weight, order, inverse, group_sizes,
     row_weight = pair_weight[order[:n_rows]][:, None].astype(rows.dtype)
     hidden = jnp.where(live, nn.silu(gate) * up * row_weight, 0)
     out = jax.lax.ragged_dot(hidden, wd, group_sizes)
-    return _ungroup_rows(tokens.shape[0], out, order, inverse, n_live)
+    return _ungroup_rows(tokens.shape[0], out, order, places, n_live)
 
 
 # The bounded buffer where the step's held pairs fit it, else the whole
@@ -323,12 +377,12 @@ def _bounded_or_whole_fwd(buffers, fits, *operands):
 
 def _bounded_or_whole_bwd(buffers, res, g):
     fits, operands = res
-    tokens, pair_weight, order, inverse, group_sizes, wg, wu, wd = operands
+    tokens, pair_weight, order, group_sizes, wg, wu, wd = operands
 
     def transposed(n_rows, g):
         _, transpose = jax.vjp(
             lambda tokens, pair_weight, wg, wu, wd: _held_result(
-                n_rows, tokens, pair_weight, order, inverse, group_sizes,
+                n_rows, tokens, pair_weight, order, group_sizes,
                 wg, wu, wd), tokens, pair_weight, wg, wu, wd)
         return transpose(g)
 
@@ -337,7 +391,7 @@ def _bounded_or_whole_bwd(buffers, res, g):
     # float32 into both branches, which then hold both forms of all three.
     d_tokens, d_weight, d_wg, d_wu, d_wd = jax.lax.optimization_barrier(
         jax.lax.cond(fits, bounded, whole, g))
-    return None, d_tokens, d_weight, None, None, None, d_wg, d_wu, d_wd
+    return None, d_tokens, d_weight, None, None, d_wg, d_wu, d_wd
 
 
 _bounded_or_whole.defvjp(_bounded_or_whole_fwd, _bounded_or_whole_bwd)
@@ -379,15 +433,14 @@ class HeldExperts(nn.Module):
         is_held = (local >= 0) & (local < held)
         key = jnp.where(is_held, local, held)                   # others last
         order = jnp.argsort(key, stable=True)
-        inverse = jnp.argsort(order)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
         n_held = jnp.sum(group_sizes)
         pairs = order.shape[0]
         bound = buffer_rows(pairs, held, ex.n_experts)
         fits = n_held <= bound
-        operands = (tokens, weights.T.reshape(-1), order, inverse,
-                    group_sizes, wg, wu, wd)
+        operands = (tokens, weights.T.reshape(-1), order, group_sizes,
+                    wg, wu, wd)
         if bound == pairs:  # always fits: one path, no conditional
             routed = _held_result(pairs, *operands)
         else:
