@@ -251,28 +251,36 @@ def _process_start() -> float:
         return _T_LOADED
 
 
-def _obs_totals(module: str) -> dict | None:
-    """``totals()`` of one of the program's own accounts (``obs.jitstats``:
+def _obs_call(module: str, function: str, *args, **kwargs):
+    """``function`` of one of the program's own accounts (``obs.jitstats``:
     its compiles; ``obs.modelstats``: what its train steps counted about
-    their model), where the program loaded the module and it has something
-    to say; a plain-Python task never did, and nothing is imported to ask."""
+    their model; ``obs.stepstats``: how the host drove those steps), where
+    the program loaded the module; a plain-Python task never did, and
+    nothing is imported to ask.  None where it is not loaded or failed."""
     account = sys.modules.get(f"covalent_tpu_plugin.obs.{module}")
     if account is None:
         return None
     try:
-        return account.totals() or None
+        return getattr(account, function)(*args, **kwargs)
     except Exception:  # noqa: BLE001 - observability never fails the task
         return None
 
 
+def _obs_totals(module: str) -> dict | None:
+    """``totals()`` of one of the program's own accounts, where it has
+    something to say."""
+    return _obs_call(module, "totals") or None
+
+
 def _trace_trailer(spans: list) -> bytes:
     """What follows the ``(result, exception)`` pickle in a launch-mode
-    result file: one JSON line with the worker's spans and its compile
-    counters.  ``pickle.load`` stops at the pickle's end, so every reader
-    of the pair is unaffected; ``utils.serialize.load_result_and_trailer``
-    reads both."""
+    result file: one JSON line with the worker's spans, its compile
+    counters and what its train steps recorded.  ``pickle.load`` stops at
+    the pickle's end, so every reader of the pair is unaffected;
+    ``utils.serialize.load_result_and_trailer`` reads both."""
     trailer: dict = {"spans": spans}
-    for key, module in (("jit", "jitstats"), ("model", "modelstats")):
+    for key, module in (("jit", "jitstats"), ("model", "modelstats"),
+                        ("steps", "stepstats")):
         totals = _obs_totals(module)
         if totals is not None:
             trailer[key] = totals
@@ -299,7 +307,9 @@ def live_backend() -> str:
     return jax.default_backend()
 
 
-def _heartbeat_payload(metrics_file: str) -> dict:
+def _heartbeat_payload(
+    metrics_file: str, late_s: float | None = None, stacks_file: str = "",
+) -> dict:
     """One heartbeat's body: process vitals + user-published progress.
 
     Everything best-effort and stdlib-only.  The user function publishes
@@ -308,8 +318,14 @@ def _heartbeat_payload(metrics_file: str) -> dict:
     in verbatim.  jax device-memory stats are read ONLY when the task
     already imported jax AND a backend is live — the heartbeat thread must
     never be the thing that triggers (or races) backend initialization.
+    ``late_s``: how far past its due time the previous beat finished.
+    Where the program's train steps are recorded (``obs.stepstats``),
+    ``steps`` says how many were steady and how long the one in progress
+    has run, and stall dumps go to ``stacks_file``.
     """
     payload: dict = {}
+    if late_s is not None:
+        payload["late_s"] = late_s
     try:
         import resource
 
@@ -317,9 +333,11 @@ def _heartbeat_payload(metrics_file: str) -> dict:
         # ru_maxrss is KiB on Linux, bytes on macOS.
         scale = 1 if sys.platform == "darwin" else 1024
         payload["rss_bytes"] = int(usage.ru_maxrss) * scale
-        payload["cpu_s"] = round(usage.ru_utime + usage.ru_stime, 3)
     except Exception:  # noqa: BLE001 - vitals are best-effort
         pass
+    steps = _obs_call("stepstats", "beat", stacks_path=stacks_file or None)
+    if steps:
+        payload["steps"] = steps
     if metrics_file:
         try:
             with open(metrics_file, encoding="utf-8") as f:
@@ -370,7 +388,12 @@ def _start_heartbeat(spec: dict):
       round trip, so the poll path gets liveness for free.
 
     The first beat fires immediately so even sub-second electrons leave
-    one, and the dispatcher's stall detector has a baseline to age.
+    one, and the dispatcher's stall detector has a baseline to age.  Beats
+    are due on a fixed cadence of the monotonic clock; each measures how
+    far past its due time it finished, its own work (device memory stats,
+    the two writes) included, carries that in the next beat (``late_s``)
+    and hands it to the train step record (``obs.stepstats``), where a
+    stall's stacks go to ``<pid_file>.stacks``.
     """
     try:
         interval = float(spec.get("heartbeat_s") or 0)
@@ -387,6 +410,7 @@ def _start_heartbeat(spec: dict):
     metrics_file = (
         os.path.abspath(f"{pid_file}.metrics") if pid_file else ""
     )
+    stacks_file = os.path.abspath(f"{pid_file}.stacks") if pid_file else ""
     if metrics_file:
         # The user function's progress-publishing hook.
         os.environ["COVALENT_TPU_WORKER_METRICS_PATH"] = metrics_file
@@ -397,6 +421,8 @@ def _start_heartbeat(spec: dict):
 
     def beat_loop() -> None:
         hb_seq = 0
+        due = time.monotonic()
+        late = None
         while True:
             hb_seq += 1
             # ONE event, one seq, two sinks: the streamed telemetry line
@@ -406,7 +432,7 @@ def _start_heartbeat(spec: dict):
             event = _build_worker_event(
                 spec, "worker.heartbeat",
                 hb_seq=hb_seq, interval_s=interval,
-                **_heartbeat_payload(metrics_file),
+                **_heartbeat_payload(metrics_file, late, stacks_file),
             )
             if telemetry_paths:
                 _append_event_line(event, telemetry_paths)
@@ -418,7 +444,12 @@ def _start_heartbeat(spec: dict):
                     os.replace(tmp, hb_file)
                 except (OSError, TypeError, ValueError):
                     pass  # liveness reporting must never fail the task
-            if stop.wait(interval):
+            now = time.monotonic()
+            late = round(max(0.0, now - due), 6)
+            _obs_call("stepstats", "beat", late_s=late)
+            # A late beat does not make the next ones hurry to catch up.
+            due = max(due + interval, now)
+            if stop.wait(due - now):
                 return
 
     thread = threading.Thread(

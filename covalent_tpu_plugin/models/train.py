@@ -19,7 +19,7 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh
 
-from ..obs import modelstats
+from ..obs import modelstats, stepstats
 from ..parallel.sharding import DEFAULT_RULES, replicated
 
 
@@ -184,16 +184,19 @@ class _TrainStep:
     ``trace``, ...).  Where the loss returns ``(loss, stats)``, the stats
     leave the compiled step beside the metrics and go to ``obs.modelstats``
     (read a step late, so that no step waits for them) in place of the
-    caller's dict."""
+    caller's dict.  Every call is recorded by ``obs.stepstats``."""
 
     def __init__(self, jitted):
         self._jitted = jitted
 
     def __call__(self, state, batch):
-        state, metrics = self._jitted(state, batch)
-        stats = metrics.pop(MODEL_STATS, None)
-        if stats is not None:
-            modelstats.defer(stats)
+        with stepstats.step() as call:
+            with call.dispatch():
+                state, metrics = self._jitted(state, batch)
+            stats = metrics.pop(MODEL_STATS, None)
+            if stats is not None:
+                with call.stats():
+                    modelstats.defer(stats)
         return state, metrics
 
     def __getattr__(self, name):

@@ -26,6 +26,9 @@ compiles that asked the persistent cache (none is counted while the cache
 is off).  A miss is a request that no hit answered by the time its
 ``backend_compile`` ended: jax's own miss event fires only for entries it
 then writes, which leaves out programs under its size and time thresholds.
+
+:func:`activity` counts every event of these, so that a caller can tell
+whether jit did anything between two moments (``obs.stepstats``).
 """
 
 from __future__ import annotations
@@ -35,7 +38,7 @@ import threading
 from .metrics import REGISTRY, Registry
 
 __all__ = [
-    "watch_jit", "totals", "absorb_worker",
+    "watch_jit", "totals", "absorb_worker", "activity",
     "JIT_SECONDS", "COMPILE_CACHE", "WORKER_JIT_SECONDS",
     "WORKER_COMPILE_CACHE",
 ]
@@ -56,6 +59,7 @@ _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 
 _watch_lock = threading.Lock()
 _watching = False
+_events = 0
 
 
 class _PerThread(threading.local):
@@ -82,15 +86,26 @@ def _cache(registry: Registry = REGISTRY):
         "jax's persistent compilation cache", label_names=("result",))
 
 
+def activity() -> int:
+    """How many compile events the listeners have seen in this process: a
+    number that moves whenever jit traces, lowers, compiles or asks the
+    persistent cache."""
+    return _events
+
+
 def _on_start(event: str, _value, **_kwargs) -> None:
+    global _events
     if event in _PHASES:
+        _events += 1
         _local.depth[event] = _local.depth.get(event, 0) + 1
 
 
 def _on_duration(event: str, duration: float, **_kwargs) -> None:
+    global _events
     phase = _PHASES.get(event)
     if phase is None:
         return
+    _events += 1
     open_now = _local.depth.get(event, 0)
     if open_now > 1:
         # Nested inside an event of the same phase, whose own duration
@@ -105,6 +120,9 @@ def _on_duration(event: str, duration: float, **_kwargs) -> None:
 
 
 def _on_event(event: str, **_kwargs) -> None:
+    global _events
+    if event in (_CACHE_REQUEST, _CACHE_HIT):
+        _events += 1
     if event == _CACHE_REQUEST:
         _local.asked = True
     elif event == _CACHE_HIT:

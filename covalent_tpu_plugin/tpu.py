@@ -83,7 +83,7 @@ from .obs.opsserver import (
     unregister_profile_provider,
     unregister_status_provider,
 )
-from .obs import jitstats, modelstats
+from .obs import jitstats, modelstats, stepstats
 from .obs.trace import Span, context_of, record_remote_span
 from .parallel.distributed import coordinator_spec
 from .serving.metrics import SERVE_WORKER_SLOTS
@@ -337,6 +337,21 @@ def _split_host_port(hostport: str) -> tuple[str, int | None]:
     if sep and port.isdigit() and ":" not in host:
         return host, int(port)
     return hostport, None
+
+
+def _steps_of_beat(beat: dict) -> str:
+    """What a stalled worker's last heartbeat said of its train steps
+    (``obs.stepstats``), for the stall's error: whether it was in the
+    middle of one."""
+    steps = beat.get("steps") if isinstance(beat, dict) else None
+    if not isinstance(steps, dict) or "step_age_s" not in steps:
+        return "its last beat names no train step"
+    late = steps.get("late_max_s")
+    return (
+        f"its last beat: {steps.get('steady_steps')} steady train steps, "
+        f"the step in progress {steps['step_age_s']}s old, worst beat "
+        f"lateness {late if late is not None else '?'}s"
+    )
 
 
 class TaskStatus(str, Enum):
@@ -2195,17 +2210,21 @@ class TPUExecutor(RemoteExecutor):
         seconds are kept for the epilogue's ``wall_overhead``, and the
         worker's compile counters are added into this process's
         ``covalent_tpu_worker_*`` series (``seen``: a resident runtime's
-        running totals).  Never raises: the record crossed a process
-        boundary.
+        running totals); its train steps' record too, its stalls as
+        ``worker.stall`` events under ``worker.execute``.  Never raises:
+        the record crossed a process boundary.
         """
         if not isinstance(record, dict):
             return
         spans = record.get("spans")
+        execute = {}
         for span in spans if isinstance(spans, list) else ():
             if not isinstance(span, dict):
                 continue
             record_remote_span(span)
             if span.get("name") == "worker.execute":
+                execute = {"trace_id": span.get("trace_id"),
+                           "parent_id": span.get("span_id")}
                 try:
                     self._worker_execute_s[operation_id] = float(
                         span.get("duration_s") or 0.0
@@ -2219,6 +2238,7 @@ class TPUExecutor(RemoteExecutor):
             # A launch-mode result's trailer: the worker's final word.  A
             # resident runtime's running totals are not absorbed yet.
             modelstats.absorb_worker(record.get("model"))
+            stepstats.absorb_worker(record.get("steps"), trace=execute)
 
     # ------------------------------------------------------------------ #
     # Elastic gangs: checkpoint records, mirroring, resume discovery      #
@@ -3079,6 +3099,22 @@ class TPUExecutor(RemoteExecutor):
             return ""
         return result.stdout.strip()
 
+    async def _remote_stacks_tail(
+        self, conn: Transport, staged: StagedTask, process_id: int
+    ) -> str:
+        """The threads' stacks a stalled worker's train step record dumped
+        (``<pid_file>.stacks``, ``obs.stepstats``), for the stall's
+        report: where the worker stood while it went silent.  Best-effort:
+        an empty string when no dump was written or the tail fails."""
+        path = f"{staged.remote_pid_file}.{process_id}.stacks"
+        try:
+            result = await conn.run(
+                f"tail -c 4096 {shlex.quote(path)} 2>/dev/null; true"
+            )
+        except (TransportError, OSError):
+            return ""
+        return result.stdout.strip()
+
     def attempts_of(self, operation_id: str) -> int:
         """Attempts the given (base) operation consumed; pops the record.
 
@@ -3280,6 +3316,7 @@ class TPUExecutor(RemoteExecutor):
                 staged.remote_telemetry_file(process_id),
                 staged.remote_hb_file(process_id),
                 f"{staged.remote_pid_file}.{process_id}.metrics",
+                f"{staged.remote_pid_file}.{process_id}.stacks",
             ]
             if staged.resume_artifact is not None:
                 # Op-scoped resume bundle (shipped outside the CAS): the
@@ -4076,6 +4113,11 @@ class TPUExecutor(RemoteExecutor):
                     telemetry_tail = await self._remote_telemetry_tail(
                         conns[blamed], staged, blamed
                     )
+                    stacks = ""
+                    if status is TaskStatus.STALLED:
+                        stacks = await self._remote_stacks_tail(
+                            conns[blamed], staged, blamed
+                        )
                     last_beats = MONITOR.last(operation_id)
                     obs_events.emit(
                         "task.failed",
@@ -4094,6 +4136,7 @@ class TPUExecutor(RemoteExecutor):
                             if last_beats
                             else {}
                         ),
+                        **({"stall_stacks": stacks} if stacks else {}),
                     )
                     if status is TaskStatus.TIMEOUT:
                         failure_msg = (
@@ -4103,16 +4146,18 @@ class TPUExecutor(RemoteExecutor):
                             f"{log_tail}"
                         )
                     elif status is TaskStatus.STALLED:
-                        silence = last_beats.get(addresses[blamed], {}).get(
-                            "age_s"
-                        )
+                        last_beat = last_beats.get(addresses[blamed], {})
+                        silence = last_beat.get("age_s")
                         failure_msg = (
                             f"remote task {operation_id} stalled on "
                             f"{addresses[blamed]}: process alive but no "
                             f"heartbeat for "
                             f"{silence if silence is not None else '?'}s "
-                            f"(threshold {self._stall_after():.1f}s); gang "
+                            f"(threshold {self._stall_after():.1f}s); "
+                            f"{_steps_of_beat(last_beat)}; gang "
                             f"killed; log tail:\n{log_tail}"
+                            + (f"\nthe stalled step's stacks:\n{stacks}"
+                               if stacks else "")
                         )
                     else:
                         failure_msg = (
@@ -4197,10 +4242,12 @@ class TPUExecutor(RemoteExecutor):
             except (TransportError, OSError) as err:
                 # A control-plane channel died mid-task: drop the pooled
                 # transports so the next electron redials (the reference
-                # would silently reuse nothing — it never pooled).
-                # mark=False: failure cleanup, not a user cancel.
-                await self.cancel(operation_id, mark=False)
+                # would silently reuse nothing — it never pooled), THEN
+                # kill the gang over a fresh channel: through the dead one
+                # the kill never lands, and the orphan runs on beside the
+                # retry.  mark=False: failure cleanup, not a user cancel.
                 await self._discard_workers(conns)
+                await self.cancel(operation_id, mark=False)
                 retry = self._plan_retry(
                     attempt, deadline,
                     reason=(
@@ -5238,8 +5285,9 @@ class TPUExecutor(RemoteExecutor):
                 elif verdict == "stalled":
                     failure_msg = (
                         f"RPC task {operation_id} stalled on {address}: no "
-                        f"heartbeat for {self._stall_after():.1f}s; resident "
-                        "runtime torn down"
+                        f"heartbeat for {self._stall_after():.1f}s; "
+                        f"{_steps_of_beat(last_beats.get(address, {}))}; "
+                        "resident runtime torn down"
                     )
                 else:
                     failure_msg = (

@@ -300,6 +300,17 @@ def test_trace_id_survives_retry_with_attempt_attrs(
     gang retry — fresh attempt, same trace, attempt attr preserved."""
     from covalent_tpu_plugin.transport.chaos import ChaosPlan
 
+    class DropOnceAttemptZeroStarted(ChaosPlan):
+        """The probe channel dies only once attempt 0's worker has left a
+        record: dropped earlier, the gang's teardown kills that worker
+        before it writes anything."""
+
+        def take_fault(self, kind, **detail):
+            if not any(e["type"] == "worker.task_started"
+                       for e in read_events(events_file)):
+                return False
+            return super().take_fault(kind, **detail)
+
     ex = make_local_executor(
         tmp_path,
         max_task_retries=2,
@@ -307,7 +318,8 @@ def test_trace_id_survives_retry_with_attempt_attrs(
         heartbeat_interval=0.1,
         # Kill exactly one status-probe channel mid-poll: attempt 0 dies
         # transiently, attempt 1 completes.
-        chaos=ChaosPlan(drop_match="if test -f", max_faults=1),
+        chaos=DropOnceAttemptZeroStarted(
+            drop_match="if test -f", max_faults=1),
     )
     out = run_async(ex.run(lambda x: x + 1, [1], {},
                            {"dispatch_id": "ftrace", "node_id": 0}))
