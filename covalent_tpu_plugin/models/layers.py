@@ -10,6 +10,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .quant import dense_general
 
@@ -127,12 +128,16 @@ class MlpBlock(nn.Module):
                 lora_alpha=cfg.lora_alpha,
             )
 
+        # The products are named for a checkpoint that keeps them (the
+        # fenced one, models/transformer.py): the rest is elementwise.
         h = dense("wi", d_ff, ("embed", "mlp"), 0.02)(x)
-        h = nn.with_logical_constraint(h, ("batch", "seq", "mlp"))
+        h = checkpoint_name(
+            nn.with_logical_constraint(h, ("batch", "seq", "mlp")), "mlp_up")
         if cfg.mlp_gated:
             gate = dense("wg", d_ff, ("embed", "mlp"), 0.02)(x)
-            h = act(nn.with_logical_constraint(
-                gate, ("batch", "seq", "mlp"))) * h
+            gate = checkpoint_name(nn.with_logical_constraint(
+                gate, ("batch", "seq", "mlp")), "mlp_gate")
+            h = act(gate) * h
         else:
             h = act(h)
         h = dense("wo", cfg.d_model, ("mlp", "embed"),

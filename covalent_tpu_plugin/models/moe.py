@@ -25,6 +25,7 @@ import functools
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import routed_rows
 from .layers import MlpBlock
@@ -168,10 +169,7 @@ class Router(nn.Module):
             kernel_init=nn.with_partitioning(
                 nn.initializers.normal(0.02), ("embed", None)),
         )(tokens.astype(jnp.float32))
-        if ex.score == "softmax":
-            scores = jax.nn.softmax(logits, axis=-1)
-        else:
-            scores = jax.nn.sigmoid(logits)
+        scores = _scores(ex.score, logits)
         biased = scores
         if ex.correction_bias:
             bias = self.param(
@@ -179,11 +177,35 @@ class Router(nn.Module):
                 jnp.float32)
             biased = scores + jax.lax.stop_gradient(bias)
         _, chosen = jax.lax.top_k(biased, ex.top_k)             # (T, k)
+        chosen = checkpoint_name(chosen, "router_chosen")
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if ex.norm_topk:
             weights = weights / (
                 jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-        return chosen, weights * ex.routed_scaling
+        return chosen, checkpoint_name(
+            weights * ex.routed_scaling, "router_weights")
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(0,))
+def _scores(score: str, logits):
+    """``softmax`` of the logits over the experts, or each one's
+    ``sigmoid``."""
+    if score == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    return jax.nn.sigmoid(logits)
+
+
+@_scores.defjvp
+def _scores_jvp(score, primals, tangents):
+    # jax's own rules, with the tangent read off the named scores: theirs
+    # read an unnamed copy, which a checkpoint that keeps the name would
+    # make again from the logits (a float32 product at full precision).
+    (logits,), (dlogits,) = primals, tangents
+    scores = checkpoint_name(_scores(score, logits), "router_scores")
+    if score == "softmax":
+        return scores, scores * (
+            dlogits - jnp.sum(scores * dlogits, axis=-1, keepdims=True))
+    return scores, dlogits * (scores * (1 - scores))
 
 
 # (token, choice) pairs are numbered choice-major (pair ``c T + t``), so
@@ -432,9 +454,12 @@ class HeldExperts(nn.Module):
         local = chosen.T.reshape(-1) - first          # (k T,), choice-major
         is_held = (local >= 0) & (local < held)
         key = jnp.where(is_held, local, held)                   # others last
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.sum(
-            key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+        # Named ahead of the conditional, whose rule keeps its operands: a
+        # checkpoint that keeps these sorts nothing again.
+        order = checkpoint_name(jnp.argsort(key, stable=True), "moe_order")
+        group_sizes = checkpoint_name(jnp.sum(
+            key[:, None] == jnp.arange(held)[None, :], axis=0,
+            dtype=jnp.int32), "moe_group_sizes")
         n_held = jnp.sum(group_sizes)
         pairs = order.shape[0]
         bound = buffer_rows(pairs, held, ex.n_experts)

@@ -97,7 +97,8 @@ class TransformerConfig:
     #: it judges that free, and keep what the first made (unrolled layers:
     #: it merges every one of a plain block's, so remat then buys no
     #: memory); True fences each layer's recompute off, which is what
-    #: saves the memory, at the price of running it.
+    #: saves the memory, at the price of running it, and under "full"
+    #: keeps the products ``FENCE_KEEPS`` names, which it then skips.
     remat_prevent_cse: bool = False
     #: lax.scan over the block stack keeps compile time O(1) in depth, but
     #: blocks XLA from fusing/scheduling across block boundaries — unrolled
@@ -246,6 +247,21 @@ class TransformerConfig:
         if self.layer_kinds is not None:
             return self.layer_kinds[layer]
         return "moe" if self.routed or self.moe_experts > 0 else "dense"
+
+
+#: What a fenced checkpoint keeps of a layer's forward, by the names the
+#: sites give (``jax.checkpoint_name``); the rest it recomputes.  Each is
+#: a product or a kernel the recompute would otherwise run again:
+#: attention's output and log-sum-exp (``ops/attention.py``), a gated
+#: MLP's two products (``MlpBlock``: a dense layer's, a shared expert's),
+#: the router's scores, choice and weights and the held experts' grouped
+#: order (``models/moe.py``, ahead of the conditional: nothing is kept
+#: from inside it).  An unfenced checkpoint keeps nothing, since the
+#: compiler merges its recompute with the forward anyway.
+FENCE_KEEPS = (
+    "flash_out", "flash_lse", "mlp_gate", "mlp_up", "router_scores",
+    "router_chosen", "router_weights", "moe_order", "moe_group_sizes",
+)
 
 
 def lm_125m_config(**overrides) -> TransformerConfig:
@@ -663,6 +679,9 @@ class TransformerLM(nn.Module):
                 raise ValueError(
                     f"remat_policy must be 'full' or 'dots', got {cfg.remat_policy!r}"
                 )
+            elif cfg.remat_prevent_cse:
+                policy = jax.checkpoint_policies.save_only_these_names(
+                    *FENCE_KEEPS)
             block_cls = nn.remat(
                 Block, prevent_cse=cfg.remat_prevent_cse, policy=policy)
         if cfg.scan_layers:
