@@ -251,16 +251,16 @@ class TransformerConfig:
 
 #: What a fenced checkpoint keeps of a layer's forward, by the names the
 #: sites give (``jax.checkpoint_name``); the rest it recomputes.  Each is
-#: a product or a kernel the recompute would otherwise run again:
-#: attention's output and log-sum-exp (``ops/attention.py``), a gated
-#: MLP's two products (``MlpBlock``: a dense layer's, a shared expert's),
-#: the router's scores, choice and weights and the held experts' grouped
+#: a product the recompute would otherwise make again: a gated MLP's two
+#: products (``MlpBlock``: a dense layer's, a shared expert's), the
+#: router's scores, choice and weights and the held experts' grouped
 #: order (``models/moe.py``, ahead of the conditional: nothing is kept
-#: from inside it).  An unfenced checkpoint keeps nothing, since the
-#: compiler merges its recompute with the forward anyway.
+#: from inside it).  Attention's flash forward still runs again in the
+#: recompute.  An unfenced checkpoint keeps nothing, since the compiler
+#: merges its recompute with the forward anyway.
 FENCE_KEEPS = (
-    "flash_out", "flash_lse", "mlp_gate", "mlp_up", "router_scores",
-    "router_chosen", "router_weights", "moe_order", "moe_group_sizes",
+    "mlp_gate", "mlp_up", "router_scores", "router_chosen",
+    "router_weights", "moe_order", "moe_group_sizes",
 )
 
 

@@ -30,7 +30,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -991,10 +990,6 @@ def _flash_fwd(q, k, v, q_positions, k_positions, causal, block_q, block_k,
         q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret,
         window=window, sinks=sinks, scale=scale,
     )
-    # Named, the output the layer reads on and the residual alike: a
-    # checkpoint that keeps both names has no use for the kernel again.
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse, q_positions, k_positions)
 
 

@@ -1,16 +1,17 @@
 """What a fenced checkpoint keeps (``models/transformer.py`` ``FENCE_KEEPS``):
-the flash kernel's output and log-sum-exp, a gated MLP's two products, the
-router's scores, choice and weights and the held experts' grouped order.
+a gated MLP's two products, the router's scores, choice and weights and the
+held experts' grouped order.
 
 At toy widths on the CPU: a stack like ``laguna`` (a gated dense layer with
 full attention, then a routed layer with a shared expert under a band) and
 one like ``xing4_0`` (latent attention, four residual streams, a routed layer
 by sigmoid score) take the same gradients with the names kept as with
-nothing kept, and the kept grad runs the flash forward once a layer where
-it ran it twice; an unfenced checkpoint lowers to the text it lowered to
-before the names.  At the cell's own size, for a described (not attached)
-v5e: ``laguna-s.train-16k``'s step holds no more than its limit and runs the
-forward kernel once a layer under both attention scopes.
+nothing kept, and the kept grad runs the router's top-k once a routed layer
+where it ran it twice, and the kept products' matmuls once; an unfenced
+checkpoint lowers to the text it lowered to before the names.  At the
+cell's own size, for a described (not attached) v5e:
+``laguna-s.train-16k``'s step holds no more than its limit, and remat's
+recompute sorts and multiplies less than with nothing kept.
 """
 
 from __future__ import annotations
@@ -95,13 +96,17 @@ def _grad_of(config: tf.TransformerConfig):
     return jax.grad(loss), params
 
 
-def _kernel_calls(jaxpr, name: str) -> int:
+def _calls(jaxpr, name: str) -> int:
+    """Equations of the primitive ``name``, or of the kernel ``name``,
+    nested ones included."""
     calls = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call" and eqn.params["name"] == name:
+        if eqn.primitive.name == name or (
+                eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == name):
             calls += 1
         for inner in jax_core.jaxprs_in_params(eqn.params):
-            calls += _kernel_calls(inner, name)
+            calls += _calls(inner, name)
     return calls
 
 
@@ -134,17 +139,27 @@ def test_the_router_scores_differentiate_as_jaxs_own(score):
 
 
 @pytest.mark.parametrize("stack", sorted(STACKS))
-def test_the_kept_grad_runs_the_flash_forward_once_a_layer(
-        stack, monkeypatch):
+def test_the_kept_grad_routes_once_a_layer(stack, monkeypatch):
     config = STACKS[stack]()
+    routed = config.layer_kinds.count("moe")
     grad, params = _grad_of(config)
     kept = jax.make_jaxpr(grad)(params).jaxpr
-    assert _kernel_calls(kept, "flash_fwd") == config.n_layers
-    assert _kernel_calls(kept, "flash_bwd_dkdv") == config.n_layers
     monkeypatch.setattr(tf, "FENCE_KEEPS", ())
     grad, _ = _grad_of(config)
     recomputed = jax.make_jaxpr(grad)(params).jaxpr
-    assert _kernel_calls(recomputed, "flash_fwd") == 2 * config.n_layers
+    assert _calls(kept, "top_k") == routed
+    assert _calls(recomputed, "top_k") == 2 * routed
+    # The grouped order's sort, a routed layer.
+    assert _calls(recomputed, "sort") - _calls(kept, "sort") == routed
+    # A dense layer's two products; a routed layer's shared expert's two
+    # and its router's logits.
+    dense = config.layer_kinds.count("dense")
+    assert (_calls(recomputed, "dot_general")
+            - _calls(kept, "dot_general")) == 2 * dense + 3 * routed
+    # Attention's output is not kept: the recompute runs its kernel again.
+    for grad_jaxpr in (kept, recomputed):
+        assert _calls(grad_jaxpr, "flash_fwd") == 2 * config.n_layers
+        assert _calls(grad_jaxpr, "flash_bwd_dkdv") == config.n_layers
 
 
 def _sc2_like() -> tf.TransformerConfig:
@@ -174,7 +189,7 @@ def test_an_unfenced_checkpoint_lowers_as_it_did_without_the_names(
 
     named = lowered()
     unnamed = lambda x, name: x  # noqa: E731 - the parent's: no name
-    for module in (attention, layers, moe):
+    for module in (layers, moe):
         monkeypatch.setattr(module, "checkpoint_name", unnamed)
     monkeypatch.setattr(moe, "_scores", lambda score, logits: (
         jax.nn.softmax(logits, axis=-1) if score == "softmax"
@@ -182,7 +197,7 @@ def test_an_unfenced_checkpoint_lowers_as_it_did_without_the_names(
     assert lowered() == named
 
 
-def test_train_16k_step_keeps_the_flash_output_and_fits(
+def test_train_16k_step_fits_and_recomputes_attentions_projections_alone(
         topo, no_cache, monkeypatch):
     import optax
     from flax import linen as nn
@@ -223,12 +238,24 @@ def test_train_16k_step_keeps_the_flash_output_and_fits(
     held = _held(compiled)
     print(json.dumps({"laguna-s.train-16k": {"held": held}}))
     assert held <= LIMIT
-    calls = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text().splitlines()
+    recompute = [line for line in text if "rematted_computation" in line]
+    # The matmuls the recompute runs again are attention's q, k, v, gate
+    # and out projections, a layer: the MLPs' and the router's are kept.
+    products = [re.search(r'op_name="([^"]*)"', line).group(1)
+                for line in recompute if re.search(r"= \S+ convolution\(", line)]
+    assert all(re.search(r"/attention/.*_proj/dot_general$", name)
+               for name in products), products
+    assert len(products) == 5 * sum(SCOPES.values())
+    # The router's choice and the held experts' order are kept: no sort.
+    assert not any(" sort(" in line.split(" metadata=")[0]
+                   for line in recompute)
+    calls = [line for line in text
              if 'custom_call_target="tpu_custom_call"' in line]
     for scope, n_layers in SCOPES.items():
         mine = [reduce._plain(line.strip()) for line in calls
                 if re.search(rf'op_name="[^"]*/attention/{scope}/', line)]
-        # The forward alone: the recompute reads what it kept.
-        assert mine.count("flash_fwd(tpu_custom_call)") == n_layers, mine
+        # The forward and remat's: attention's output is not kept.
+        assert mine.count("flash_fwd(tpu_custom_call)") == 2 * n_layers, mine
         assert mine.count("flash_bwd_dkdv(tpu_custom_call)") == n_layers
         assert mine.count("flash_bwd_dq(tpu_custom_call)") == n_layers
