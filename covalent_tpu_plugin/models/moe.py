@@ -169,7 +169,10 @@ class Router(nn.Module):
             kernel_init=nn.with_partitioning(
                 nn.initializers.normal(0.02), ("embed", None)),
         )(tokens.astype(jnp.float32))
-        scores = _scores(ex.score, logits)
+        if ex.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
         biased = scores
         if ex.correction_bias:
             bias = self.param(
@@ -184,28 +187,6 @@ class Router(nn.Module):
                 jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
         return chosen, checkpoint_name(
             weights * ex.routed_scaling, "router_weights")
-
-
-@functools.partial(jax.custom_jvp, nondiff_argnums=(0,))
-def _scores(score: str, logits):
-    """``softmax`` of the logits over the experts, or each one's
-    ``sigmoid``."""
-    if score == "softmax":
-        return jax.nn.softmax(logits, axis=-1)
-    return jax.nn.sigmoid(logits)
-
-
-@_scores.defjvp
-def _scores_jvp(score, primals, tangents):
-    # jax's own rules, with the tangent read off the named scores: theirs
-    # read an unnamed copy, which a checkpoint that keeps the name would
-    # make again from the logits (a float32 product at full precision).
-    (logits,), (dlogits,) = primals, tangents
-    scores = checkpoint_name(_scores(score, logits), "router_scores")
-    if score == "softmax":
-        return scores, scores * (
-            dlogits - jnp.sum(scores * dlogits, axis=-1, keepdims=True))
-    return scores, dlogits * (scores * (1 - scores))
 
 
 # (token, choice) pairs are numbered choice-major (pair ``c T + t``), so

@@ -253,14 +253,17 @@ class TransformerConfig:
 #: sites give (``jax.checkpoint_name``); the rest it recomputes.  Each is
 #: a product the recompute would otherwise make again: a gated MLP's two
 #: products (``MlpBlock``: a dense layer's, a shared expert's), the
-#: router's scores, choice and weights and the held experts' grouped
-#: order (``models/moe.py``, ahead of the conditional: nothing is kept
-#: from inside it).  Attention's flash forward still runs again in the
-#: recompute.  An unfenced checkpoint keeps nothing, since the compiler
-#: merges its recompute with the forward anyway.
+#: router's choice and weights and the held experts' grouped order
+#: (``models/moe.py``, ahead of the conditional: nothing is kept from
+#: inside it).  The router's scores are recomputed: kept beside the MLPs'
+#: products, they move the held experts' token rows out of VMEM in
+#: ``xing4-29b.train-8k``, and the rows' gathers there run three times as
+#: long.  Attention's flash forward still runs again in the recompute.  An
+#: unfenced checkpoint keeps nothing, since the compiler merges its
+#: recompute with the forward anyway.
 FENCE_KEEPS = (
-    "mlp_gate", "mlp_up", "router_scores", "router_chosen",
-    "router_weights", "moe_order", "moe_group_sizes",
+    "mlp_gate", "mlp_up", "router_chosen", "router_weights", "moe_order",
+    "moe_group_sizes",
 )
 
 

@@ -4,7 +4,8 @@ described (not attached) v5e chip: the bytes the step holds with a second
 branch in every expert layer, one conditional a pass under the ``experts``
 scope, both branches' products inside them, and the read-back's kernel
 (``moe_readback``) three times a layer in the bounded branch alone, where no
-array of one row a pair is left.  Costs no chip time.
+array of one row a pair is left, and the branch's row gathers reading the
+tokens from VMEM.  Costs no chip time.
 
 The fixtures are ``tests/benchsuite/test_suite_xing4_v5e.py``'s, by import
 (that file is the benchmark's; this one is the program's): the topology is
@@ -28,9 +29,9 @@ from tests.benchsuite.test_suite_xing4_v5e import (  # noqa: F401 - a fixture
 #: The step held 12,211,270,656 bytes with the gather form's read-back in
 #: both branches (PERF.md §4's 12.21 GB) and 12,213,365,248 with the kernel
 #: in the bounded one.  The fenced checkpoint's kept set
-#: (``FENCE_KEEPS``: the gated MLPs' products, the router's choice; 0.45 GB
-#: by its shapes) takes it to 12,550,518,272.
-HELD_BEFORE = 12.555e9
+#: (``FENCE_KEEPS``: the gated MLPs' products, the router's choice and
+#: weights, the held experts' order; 0.39 GB) takes it to 12,602,117,120.
+HELD_BEFORE = 12.605e9
 
 
 @pytest.fixture(scope="module")
@@ -156,3 +157,32 @@ def test_no_array_of_one_row_a_pair_is_left_in_the_bounded_branch(
             inside[int(found.group(1))].append(line.strip()[:200])
     assert not inside[1], inside[1][:3]
     assert inside[0]  # the whole buffer's own rows: the pattern finds them
+
+
+def test_the_bounded_branch_gathers_its_rows_from_vmem(compiled_step):
+    """The bounded branch gathers its buffer's rows out of the layer's
+    tokens, in the backward's rule and in remat's forward: from VMEM
+    (memory space 1) 0.34 ms a gather on the chip, 1.08 from HBM, where a
+    fenced checkpoint that keeps the router's scores beside the MLPs'
+    products puts the tokens."""
+    config, compiled = compiled_step
+    text = compiled.as_text().splitlines()
+    shapes = {}
+    for line in text:
+        found = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\S+) ", line)
+        if found:
+            shapes[found.group(1)] = found.group(2)
+    a_row = rf"bf16\[\d+,{config['hidden_size']}\]"
+    gathers = []
+    for line in text:
+        found = re.match(rf"\s+(?:ROOT )?%[\w.\-]+ = {a_row}\S* "
+                         r"fusion\(%([\w.\-]+), ", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if (found and name and "kind=kCustom" in line and re.search(
+                r"/experts/cond/branch_1_fun/(jvp\(\)/)?gather$",
+                name.group(1))):
+            gathers.append((name.group(1), shapes[found.group(1)]))
+    # Forward, backward and remat's forward, a sparse layer.
+    assert len(gathers) >= 3 * len(_sparse_layers(config)), gathers
+    assert all("S(1)" in shape for _, shape in gathers), [
+        name for name, shape in gathers if "S(1)" not in shape]
