@@ -54,11 +54,13 @@ def yarn_inv_freq(dim: int, base: float, yarn: YarnConfig | None):
 
 def rotary(x: jax.Array, base: float = 10000.0, offset=0,
            freqs=None, rotary_dim: int | None = None,
-           amplitude: float = 1.0) -> jax.Array:
+           amplitude: float = 1.0, positions=None) -> jax.Array:
     """Rotary position embedding over (B, S, H, D) with D even.
 
     ``offset`` shifts the position index — incremental decoding applies the
-    embedding for absolute position ``offset + t`` to a length-1 slice.
+    embedding for absolute position ``offset + t`` to a length-1 slice;
+    ``positions`` ((S,)) gives each row's position outright (two copies
+    of a sequence that both turn from 0).
     ``freqs`` ((D/2,) inverse frequencies) replaces ``base``'s geometric
     run, for a model that scales them (YaRN); ``amplitude`` multiplies cos
     and sin (YaRN's attention factor).  ``rotary_dim`` under D turns the
@@ -66,14 +68,17 @@ def rotary(x: jax.Array, base: float = 10000.0, offset=0,
     """
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         turned = rotary(x[..., :rotary_dim], base, offset, freqs,
-                        amplitude=amplitude)
+                        amplitude=amplitude, positions=positions)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     _, seq_len, _, head_dim = x.shape
     half = head_dim // 2
     if freqs is None:
         freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     freqs = jnp.asarray(freqs, jnp.float32)
-    positions = offset + jnp.arange(seq_len, dtype=jnp.float32)
+    if positions is None:
+        positions = offset + jnp.arange(seq_len, dtype=jnp.float32)
+    else:
+        positions = jnp.asarray(positions, jnp.float32)
     angles = positions[:, None] * freqs[None, :]
 
     def table(fn):
@@ -86,13 +91,18 @@ def rotary(x: jax.Array, base: float = 10000.0, offset=0,
 
 
 class RMSNorm(nn.Module):
+    """RMSNorm over the last axis; ``axis_name`` is that axis's logical
+    name (a head's width where a per-head norm takes it)."""
+
     dtype: Any = jnp.bfloat16
+    axis_name: str = "embed"
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
             "scale",
-            nn.with_partitioning(nn.initializers.ones_init(), ("embed",)),
+            nn.with_partitioning(nn.initializers.ones_init(),
+                                 (self.axis_name,)),
             (x.shape[-1],),
             jnp.float32,
         )

@@ -179,10 +179,12 @@ class BlockUnsupported(ValueError):
 
     Latent attention would cache its latent, routed experts decode through
     grouped products, n residual streams change what a lane's state is,
-    and attention that differs by layer wants a cache a layer type: the
-    engine has none of the four, and running such a model through the
-    plain K/V path would serve wrong tokens.  Duck-tagged PERMANENT
-    like :class:`RollingCacheUnsupported`."""
+    attention that differs by layer wants a cache a layer type, and a
+    block-diffusion model denoises a block of tokens over several passes
+    where the engine yields one token a step: the engine has none of the
+    five, and running such a model through the plain K/V path would serve
+    wrong tokens.  Duck-tagged PERMANENT like
+    :class:`RollingCacheUnsupported`."""
 
     fault_label = "serve_model_unsupported"
     fault_transient = False
@@ -195,14 +197,15 @@ def _require_plain_cache(config, what: str) -> None:
             "(slot reset assumes the plain cache layout)"
         )
     held = [name
-            for name in ("latent", "routed", "streams", "attention_types")
+            for name in ("latent", "routed", "streams", "attention_types",
+                         "diffusion_block")
             if getattr(config, name, None) is not None]
     if held:
         raise BlockUnsupported(
             f"{what} does not serve a model with {', '.join(held)} set "
             "(latent attention, routed experts, residual streams, attention "
-            "by layer): the train path runs it, the engine has no cache for "
-            "it yet"
+            "by layer, block diffusion): the train path runs it, the engine "
+            "has no cache or step for it yet"
         )
 
 

@@ -238,6 +238,15 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
     from ..ops.xent import fused_cross_entropy
 
     feats = out
+    flat = feats.reshape(-1, feats.shape[-1])
+    labels = tokens[:, 1:].reshape(-1)
+    return result(fused_cross_entropy(flat, head_kernel(params), labels,
+                                      vocab_chunk))
+
+
+def head_kernel(params):
+    """The lm_head kernel the fused loss (``ops/xent.py``) takes in place
+    of the model's head."""
     from flax.core import meta as flax_meta
 
     # The kernel may ride in a flax Partitioned box (sharded init path).
@@ -253,9 +262,7 @@ def lm_loss(params, apply_fn, batch, vocab_chunk: int | None = None):
             "vocab_chunk needs a plain float lm_head kernel "
             "(quantized/LoRA heads take the standard path)"
         )
-    flat = feats.reshape(-1, feats.shape[-1])
-    labels = tokens[:, 1:].reshape(-1)
-    return result(fused_cross_entropy(flat, kernel, labels, vocab_chunk))
+    return kernel
 
 
 def make_lm_train_step(mesh, state_shardings, rules=DEFAULT_RULES):

@@ -184,8 +184,23 @@ class TransformerConfig:
     #: None = every layer alike, by the fields above.
     attention_types: tuple | None = None
     attention_kinds: tuple | None = None
+    #: an RMSNorm over each query and key head's width, ahead of rotary.
+    qk_norm: bool = False
+    #: block diffusion (``models/diffusion.py``): the model takes a noisy
+    #: and a clean copy of each row, ``[noisy ; clean]``, both turned from
+    #: position 0, under the block-diffusion mask over blocks of this many
+    #: tokens (``ops/attention.py`` ``diffusion_visible``); ``max_seq``
+    #: bounds the two copies together.  None = causal.
+    diffusion_block: int | None = None
 
     def __post_init__(self):
+        if self.diffusion_block is not None and (
+                self.diffusion_block < 1 or self.decode
+                or self.sliding_window is not None or self.latent is not None
+                or self.attention in ("ring", "ulysses")):
+            raise ValueError(
+                "diffusion_block is a block length >= 1 on the train path: "
+                "no decode, window, latent attention or ring/ulysses")
         if (self.attention_types is None) != (self.attention_kinds is None):
             raise ValueError(
                 "attention_types and attention_kinds are given together")
@@ -310,20 +325,30 @@ class Attention(nn.Module):
         q = nn.with_logical_constraint(q, ("batch", "seq", "heads", "kv"))
         k = nn.with_logical_constraint(k, ("batch", "seq", kv_axis, "kv"))
         v = nn.with_logical_constraint(v, ("batch", "seq", kv_axis, "kv"))
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.dtype, axis_name="kv", name="q_norm")(q)
+            k = RMSNorm(cfg.dtype, axis_name="kv", name="k_norm")(k)
 
         if cfg.decode:
             return self._decode_step(q, k, v, kv_heads)
 
+        # Block diffusion: both copies of the row turn from position 0.
+        positions = None
+        masking = {}
+        if cfg.diffusion_block is not None:
+            positions = jnp.arange(x.shape[1]) % (x.shape[1] // 2)
+            masking = {"diffusion_block": cfg.diffusion_block}
         if kind is None:
-            q = _rotary(q, base=cfg.rope_base)
-            k = _rotary(k, base=cfg.rope_base)
+            q = _rotary(q, base=cfg.rope_base, positions=positions)
+            k = _rotary(k, base=cfg.rope_base, positions=positions)
             scope = contextlib.nullcontext()
         else:
             turned = int(cfg.head_width * kind.rope_share)
             amplitude = 1.0 if kind.yarn is None else kind.yarn.attention_factor
             freqs = yarn_inv_freq(turned, kind.rope_base, kind.yarn)
             q, k = (_rotary(t, freqs=freqs, rotary_dim=turned,
-                            amplitude=amplitude) for t in (q, k))
+                            amplitude=amplitude, positions=positions)
+                    for t in (q, k))
             scope = jax.named_scope(kind.name)
 
         # (B, S, H, D) -> (B, H, S, D) for the attention kernels
@@ -370,18 +395,18 @@ class Attention(nn.Module):
                     # block local.
                     out = flash_attention_sharded(
                         qh, kh, vh, cfg.mesh, causal=True,
-                        window=window, sinks=cfg.attention_sinks,
+                        window=window, sinks=cfg.attention_sinks, **masking,
                     )
                 else:
                     out = flash_attention(
                         qh, kh, vh, causal=True, window=window,
-                        sinks=cfg.attention_sinks,
+                        sinks=cfg.attention_sinks, **masking,
                     )
         else:
             with scope:
                 out = mha_reference(
                     qh, kh, vh, causal=True, window=window,
-                    sinks=cfg.attention_sinks,
+                    sinks=cfg.attention_sinks, **masking,
                 )
         out = out.transpose(0, 2, 1, 3)
         if kind is not None and kind.gate:

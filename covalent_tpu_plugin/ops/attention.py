@@ -30,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -132,6 +133,20 @@ def _band_tile_needed(qpos_tile, kpos_tile, causal: bool, window: int | None,
             behind_ok = jnp.logical_or(behind_ok, jnp.min(kpos_tile) < sinks)
         needed = jnp.logical_and(needed, behind_ok)
     return needed
+
+
+def diffusion_visible(qn, kn, seq: int, block: int):
+    """Block-diffusion visibility (BD3-LM, arXiv:2503.09573) over the
+    ``[noisy ; clean]`` concatenation of ``2 * seq`` positions, on
+    broadcastable index grids: position ``n`` lies in block
+    ``(n % seq) // block`` of its half.  A noisy query sees the noisy keys
+    of its own block and the clean keys of earlier blocks; a clean query
+    sees the clean keys of its block and earlier ones, and no noisy key."""
+    q_noisy, k_noisy = qn < seq, kn < seq
+    qb, kb = (qn % seq) // block, (kn % seq) // block
+    return jnp.where(
+        q_noisy, jnp.where(k_noisy, qb == kb, kb < qb),
+        jnp.logical_and(jnp.logical_not(k_noisy), kb <= qb))
 
 
 def _check_window(window, causal, sinks: int = 0) -> None:
@@ -336,6 +351,7 @@ def mha_reference(
     scale: float | None = None,
     window: int | None = None,
     sinks: int = 0,
+    diffusion_block: int | None = None,
 ) -> jax.Array:
     """Dense multi-head attention oracle.  Shapes: (B, H, S, D).
 
@@ -344,7 +360,9 @@ def mha_reference(
     heads, matching the flash kernel's convention.  ``window=w`` masks to
     the sliding causal band: row ``i`` sees columns ``(i-w, i]``;
     ``sinks=k`` (StreamingLLM) keeps the first ``k`` columns visible to
-    every row alongside the band.
+    every row alongside the band.  ``diffusion_block=B`` masks by
+    :func:`diffusion_visible` in place of the causal band (``causal`` is
+    then not read): the S positions are ``[noisy ; clean]``, S / 2 each.
     """
     _check_window(window, causal, sinks)
     if k.shape[1] != q.shape[1]:
@@ -356,7 +374,14 @@ def mha_reference(
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
-    if causal:
+    if diffusion_block is not None:
+        s_q = q.shape[2]
+        qi = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_q), 0)
+        ki = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_q), 1)
+        scores = jnp.where(
+            diffusion_visible(qi, ki, s_q // 2, diffusion_block), scores,
+            _NEG_INF)
+    elif causal:
         s_q, s_k = q.shape[2], k.shape[2]
         qi = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
@@ -366,6 +391,136 @@ def mha_reference(
         "bhqk,bhkd->bhqd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32
     )
     return out.astype(q.dtype)
+
+
+def _fwd_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, visible):
+    """One key tile into a query tile's online softmax (running max ``m``,
+    normaliser ``l``, accumulator ``acc``).  ``visible``: None (every pair
+    of the tile is seen) or a thunk giving the (BQ, BK) mask, made after
+    the scores."""
+    # Matmul inputs stay in the INPUT dtype (bf16 on TPU) with f32
+    # accumulation — casting to f32 first would push the hot matmuls
+    # off the MXU's native bf16 path (measured 3-4x slower end to end).
+    q = q_ref[0, 0, :, :]
+    k_tile = k_ref[0, 0, :, :]
+    v_tile = v_ref[0, 0, :, :]
+
+    s = jax.lax.dot_general(
+        q, k_tile,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (BQ, BK) f32
+
+    if visible is not None:
+        # Masking reads GLOBAL positions — (BQ,1) against (1,BK) —
+        # so striped/rotated layouts (ring attention) mask correctly;
+        # contiguous arange positions reproduce the classic diagonal.
+        mask = visible()
+        s = jnp.where(mask, s, _NEG_INF)
+
+    m_prev = m_ref[:]
+    l_prev = l_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    if visible is not None:
+        p = jnp.where(mask, p, 0.0)
+    m_ref[:] = m_new
+    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v_tile.dtype), v_tile,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    acc_ref[:] = acc_ref[:] * alpha + pv
+
+
+def _fwd_init(m_ref, l_ref, acc_ref):
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _fwd_finalise(o_ref, lse_ref, m_ref, l_ref, acc_ref):
+    o_ref[0, 0, :, :] = (
+        acc_ref[:] / jnp.maximum(l_ref[:], 1e-37)
+    ).astype(o_ref.dtype)
+    # Per-row log-sum-exp — the only softmax statistic the backward
+    # kernels need to recompute any probability tile.  Kept in the
+    # (BQ, 1) sublane layout the scratch already uses.
+    lse_ref[0, 0, :, :] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-37))
+
+
+def _dkdv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+               dv_acc, scale, visible):
+    """One query tile's share of a key tile's dK and dV, the probability
+    tile recomputed from (q, k, lse); ``visible`` as in ``_fwd_tile``."""
+    q = q_ref[0, 0, :, :]
+    k_tile = k_ref[0, 0, :, :]
+    v_tile = v_ref[0, 0, :, :]
+    do = do_ref[0, 0, :, :]
+    lse = lse_ref[0, 0, :, :]      # (BQ, 1) f32
+    delta = delta_ref[0, 0, :, :]  # (BQ, 1) f32
+
+    s = jax.lax.dot_general(
+        q, k_tile,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (BQ, BK) f32
+    p = jnp.exp(s - lse)  # exactly the forward's normalised probabilities
+    if visible is not None:
+        p = jnp.where(visible(), p, 0.0)
+
+    # dV += P^T dO ; dP = dO V^T ; dS = P*(dP - delta)*scale ; dK += dS^T Q
+    dv_acc[:] += jax.lax.dot_general(
+        p.astype(do.dtype), do,
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    dp = jax.lax.dot_general(
+        do, v_tile,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta) * scale
+    dk_acc[:] += jax.lax.dot_general(
+        ds.astype(q.dtype), q,
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc, scale,
+             visible):
+    """One key tile's share of a query tile's dQ; ``visible`` as in
+    ``_fwd_tile``."""
+    q = q_ref[0, 0, :, :]
+    k_tile = k_ref[0, 0, :, :]
+    v_tile = v_ref[0, 0, :, :]
+    do = do_ref[0, 0, :, :]
+    lse = lse_ref[0, 0, :, :]
+    delta = delta_ref[0, 0, :, :]
+
+    s = jax.lax.dot_general(
+        q, k_tile,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    p = jnp.exp(s - lse)
+    if visible is not None:
+        p = jnp.where(visible(), p, 0.0)
+
+    dp = jax.lax.dot_general(
+        do, v_tile,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta) * scale
+    dq_acc[:] += jax.lax.dot_general(
+        ds.astype(k_tile.dtype), k_tile,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref,
@@ -384,11 +539,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref,
     kt = pl.program_id(3)
     num_k_tiles = pl.num_programs(3)
 
-    @pl.when(kt == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    pl.when(kt == 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
 
     # A tile whose every key position is in the future of every query
     # position contributes nothing under causal masking: skip its MXU work.
@@ -412,41 +563,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref,
         )
 
     def _tile_body(masked: bool):
-        # Matmul inputs stay in the INPUT dtype (bf16 on TPU) with f32
-        # accumulation — casting to f32 first would push the hot matmuls
-        # off the MXU's native bf16 path (measured 3-4x slower end to end).
-        q = q_ref[0, 0, :, :]
-        k_tile = k_ref[0, 0, :, :]
-        v_tile = v_ref[0, 0, :, :]
-
-        s = jax.lax.dot_general(
-            q, k_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (BQ, BK) f32
-
-        if causal and masked:
-            # Masking reads GLOBAL positions — (BQ,1) against (1,BK) —
-            # so striped/rotated layouts (ring attention) mask correctly;
-            # contiguous arange positions reproduce the classic diagonal.
-            mask = _band_visible(qpos_ref[:, :], kpos_ref[:, :], window, sinks)
-            s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        if causal and masked:
-            p = jnp.where(mask, p, 0.0)
-        m_ref[:] = m_new
-        l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_tile.dtype), v_tile,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = acc_ref[:] * alpha + pv
+        _fwd_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale, (
+            lambda: _band_visible(qpos_ref[:, :], kpos_ref[:, :], window,
+                                  sinks)) if causal and masked else None)
 
     if band is None:
         pl.when(needed)(lambda: _tile_body(True))
@@ -463,15 +582,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref,
             lambda: _tile_body(True)
         )
 
-    @pl.when(kt == num_k_tiles - 1)
-    def _finalise():
-        o_ref[0, 0, :, :] = (
-            acc_ref[:] / jnp.maximum(l_ref[:], 1e-37)
-        ).astype(o_ref.dtype)
-        # Per-row log-sum-exp — the only softmax statistic the backward
-        # kernels need to recompute any probability tile.  Kept in the
-        # (BQ, 1) sublane layout the scratch already uses.
-        lse_ref[0, 0, :, :] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-37))
+    pl.when(kt == num_k_tiles - 1)(
+        lambda: _fwd_finalise(o_ref, lse_ref, m_ref, l_ref, acc_ref))
 
 
 def _fit_block(requested: int, seq_len: int) -> int:
@@ -660,42 +772,11 @@ def _flash_bwd_dkdv_kernel(
         )
 
     def _tile_body(masked: bool):
-        q = q_ref[0, 0, :, :]
-        k_tile = k_ref[0, 0, :, :]
-        v_tile = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]      # (BQ, 1) f32
-        delta = delta_ref[0, 0, :, :]  # (BQ, 1) f32
-
-        s = jax.lax.dot_general(
-            q, k_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (BQ, BK) f32
-        p = jnp.exp(s - lse)  # exactly the forward's normalised probabilities
-        if causal and masked:
-            p = jnp.where(
-                _band_visible(qpos_ref[:, :], kpos_ref[:, :], window, sinks),
-                p, 0.0,
-            )
-
-        # dV += P^T dO ; dP = dO V^T ; dS = P*(dP - delta)*scale ; dK += dS^T Q
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _dkdv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc,
+                   dv_acc, scale, (
+                       lambda: _band_visible(qpos_ref[:, :], kpos_ref[:, :],
+                                             window, sinks))
+                   if causal and masked else None)
 
     if band is None:
         pl.when(needed)(lambda: _tile_body(True))
@@ -744,36 +825,11 @@ def _flash_bwd_dq_kernel(
         )
 
     def _tile_body(masked: bool):
-        q = q_ref[0, 0, :, :]
-        k_tile = k_ref[0, 0, :, :]
-        v_tile = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-
-        s = jax.lax.dot_general(
-            q, k_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        p = jnp.exp(s - lse)
-        if causal and masked:
-            p = jnp.where(
-                _band_visible(qpos_ref[:, :], kpos_ref[:, :], window, sinks),
-                p, 0.0,
-            )
-
-        dp = jax.lax.dot_general(
-            do, v_tile,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dq_acc[:] += jax.lax.dot_general(
-            ds.astype(k_tile.dtype), k_tile,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_acc,
+                 scale, (
+                     lambda: _band_visible(qpos_ref[:, :], kpos_ref[:, :],
+                                           window, sinks))
+                 if causal and masked else None)
 
     if band is None:
         pl.when(needed)(lambda: _tile_body(True))
@@ -1006,6 +1062,360 @@ def _flash_bwd(causal, block_q, block_k, interpret, window, sinks, scale,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# --- Block-diffusion grid ------------------------------------------------
+#
+# The block-diffusion mask (``diffusion_visible``) is no band over one
+# position line: noisy queries see their own noisy block and the clean
+# keys of earlier blocks, clean queries the clean keys up to their block.
+# About a quarter of the (2S)^2 square is visible, and a causal grid over
+# the concatenation would visit twice that.  So each sweep walks a static
+# list of the live (query tile, key tile) pairs, prefetched into SMEM:
+# grid (batch, heads, live pairs), the index maps read the pair's tiles,
+# and the list's flags say where a query (dq, forward) or key (dK/dV)
+# tile's run starts and ends and whether the tile is only partly visible
+# (masked) or interior (every pair seen, no mask).  Tiles never straddle
+# the two halves, so a tile's half is its index's and the mask is a
+# comparison of block indices, fed in like the band's positions.
+
+_FIRST, _LAST, _MASKED = 1, 2, 4
+
+
+def diffusion_tiles(seq: int, block: int, block_q: int,
+                    block_k: int) -> list[tuple[int, int, bool]]:
+    """``(query tile, key tile, masked)`` for every tile of the block-
+    diffusion mask over ``2 * seq`` positions that holds a visible pair,
+    query tile by query tile; ``masked`` where not every pair of it is
+    visible.  ``seq`` is a multiple of both tile sizes."""
+
+    def span(tile: int, size: int):
+        first = tile * size % seq
+        return tile * size < seq, first // block, (first + size - 1) // block
+
+    out = []
+    for i in range(2 * seq // block_q):
+        q_noisy, q_lo, q_hi = span(i, block_q)
+        for j in range(2 * seq // block_k):
+            k_noisy, k_lo, k_hi = span(j, block_k)
+            if q_noisy and k_noisy:      # the same block
+                live = k_lo <= q_hi and q_lo <= k_hi
+                whole = q_lo == q_hi == k_lo == k_hi
+            elif q_noisy:                # clean keys of earlier blocks
+                live, whole = k_lo < q_hi, k_hi < q_lo
+            elif not k_noisy:            # clean keys up to its own block
+                live, whole = k_lo <= q_hi, k_hi <= q_lo
+            else:                        # no clean query sees a noisy key
+                continue
+            if live:
+                out.append((i, j, not whole))
+    return out
+
+
+def _diffusion_table(runs: list[tuple]) -> tuple:
+    """Prefetched SMEM columns of a sweep's steps: each step's leading
+    fields, then its flags (``_FIRST`` / ``_LAST`` of the run of steps
+    that share the first field, ``_MASKED``)."""
+    table = np.asarray([step[:-1] for step in runs], np.int32)
+    flags = np.asarray([_MASKED if step[-1] else 0 for step in runs],
+                       np.int32)
+    lead = table[:, 0]
+    flags[np.r_[True, lead[1:] != lead[:-1]]] |= _FIRST
+    flags[np.r_[lead[1:] != lead[:-1], True]] |= _LAST
+    return tuple(jnp.asarray(column) for column in table.T) + (
+        jnp.asarray(flags),)
+
+
+def _diffusion_mask(qblk_ref, kblk_ref, q_tile, k_tile, q_noisy_tiles: int,
+                    k_noisy_tiles: int):
+    """The (BQ, BK) mask of a partly visible tile: ``lo <= qb - kb <= hi``
+    on the rows' and columns' block indices, ``(lo, hi)`` by the tiles'
+    halves: (0, 0) noisy to noisy, (1, .) noisy to clean, (0, .) clean to
+    clean."""
+    k_noisy = k_tile < k_noisy_tiles
+    lo = jnp.logical_and(q_tile < q_noisy_tiles,
+                         jnp.logical_not(k_noisy)).astype(jnp.int32)
+    hi = jnp.where(k_noisy, 0, jnp.iinfo(jnp.int32).max)
+    behind = qblk_ref[:, :] - kblk_ref[:, :]
+    return jnp.logical_and(behind >= lo, behind <= hi)
+
+
+def _diffusion_fwd_kernel(qt_ref, kt_ref, flag_ref, q_ref, k_ref, v_ref,
+                          qblk_ref, kblk_ref, o_ref, lse_ref, m_ref, l_ref,
+                          acc_ref, *, scale: float, noisy: tuple[int, int]):
+    """One live (query tile, key tile) step of the forward."""
+    step = pl.program_id(2)
+    flag = flag_ref[step]
+    pl.when(flag & _FIRST != 0)(lambda: _fwd_init(m_ref, l_ref, acc_ref))
+
+    def visible():
+        return _diffusion_mask(qblk_ref, kblk_ref, qt_ref[step],
+                               kt_ref[step], *noisy)
+
+    for masked in (False, True):
+        pl.when((flag & _MASKED != 0) == masked)(functools.partial(
+            _fwd_tile, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, scale,
+            visible if masked else None))
+    pl.when(flag & _LAST != 0)(
+        lambda: _fwd_finalise(o_ref, lse_ref, m_ref, l_ref, acc_ref))
+
+
+def _diffusion_dq_kernel(qt_ref, kt_ref, flag_ref, q_ref, k_ref, v_ref,
+                         do_ref, lse_ref, delta_ref, qblk_ref, kblk_ref,
+                         dq_ref, dq_acc, *, scale: float,
+                         noisy: tuple[int, int]):
+    """One live (query tile, key tile) step of the dq sweep."""
+    step = pl.program_id(2)
+    flag = flag_ref[step]
+
+    @pl.when(flag & _FIRST != 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def visible():
+        return _diffusion_mask(qblk_ref, kblk_ref, qt_ref[step],
+                               kt_ref[step], *noisy)
+
+    for masked in (False, True):
+        pl.when((flag & _MASKED != 0) == masked)(functools.partial(
+            _dq_tile, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            dq_acc, scale, visible if masked else None))
+
+    @pl.when(flag & _LAST != 0)
+    def _finalise():
+        dq_ref[0, 0, :, :] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _diffusion_dkdv_kernel(kt_ref, gi_ref, qt_ref, flag_ref, q_ref, k_ref,
+                           v_ref, do_ref, lse_ref, delta_ref, qblk_ref,
+                           kblk_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                           scale: float, noisy: tuple[int, int]):
+    """One live (key tile, group member, query tile) step of the dK/dV
+    sweep: a key tile's run covers every query head its kv head serves."""
+    step = pl.program_id(2)
+    flag = flag_ref[step]
+
+    @pl.when(flag & _FIRST != 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def visible():
+        return _diffusion_mask(qblk_ref, kblk_ref, qt_ref[step],
+                               kt_ref[step], *noisy)
+
+    for masked in (False, True):
+        pl.when((flag & _MASKED != 0) == masked)(functools.partial(
+            _dkdv_tile, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            dk_acc, dv_acc, scale, visible if masked else None))
+
+    @pl.when(flag & _LAST != 0)
+    def _finalise():
+        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _diffusion_blocks(q, block: int):
+    """Each position's block index within its half, as the kernels read
+    it: rows (2S, 1) and columns (1, 2S)."""
+    n = q.shape[2]
+    if n % 2:
+        raise ValueError(f"the [noisy ; clean] rows must be even, got {n}")
+    blocks = jnp.arange(n, dtype=jnp.int32) % (n // 2) // block
+    return blocks.reshape(n, 1), blocks.reshape(1, n)
+
+
+def _diffusion_fit(requested: int | None, default: int, half: int) -> int:
+    """A tile size that divides each half (no tile straddles the two)."""
+    size = _fit_block(default, half) if requested is None else requested
+    if half % size:
+        raise ValueError(
+            f"tile {size} does not divide the half of {half} positions")
+    return size
+
+
+def _diffusion_cost(q, v, pairs: int, factor: int):
+    batch, heads, _, head_dim = q.shape
+    return pl.CostEstimate(
+        flops=int(batch * heads * pairs * factor * (head_dim + v.shape[3])),
+        bytes_accessed=int(2 * batch * heads * q.shape[2]
+                           * (head_dim + v.shape[3]) * q.dtype.itemsize),
+        transcendentals=int(batch * heads * pairs),
+    )
+
+
+def _diffusion_forward(q, k, v, block: int, block_q, block_k,
+                       interpret: bool, scale):
+    batch, heads, n, head_dim = q.shape
+    value_dim = v.shape[3]
+    half = n // 2
+    block_q = _diffusion_fit(block_q, _DEFAULT_BLOCK_Q, half)
+    block_k = _diffusion_fit(block_k, _DEFAULT_BLOCK_K, half)
+    scale = head_dim**-0.5 if scale is None else scale
+    group = _gqa_group(q, k)
+    live = diffusion_tiles(half, block, block_q, block_k)
+    qt, kt, flags = _diffusion_table(live)
+    qblk, kblk = _diffusion_blocks(q, block)
+
+    def q_side(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, s, qt, kt, f: (b, h, qt[s], 0))
+
+    def k_side(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
+
+    out, lse = pl.pallas_call(
+        functools.partial(_diffusion_fwd_kernel, scale=scale,
+                          noisy=(half // block_q, half // block_k)),
+        name="flash_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch, heads, len(live)),
+            in_specs=[
+                q_side(head_dim), k_side(head_dim), k_side(value_dim),
+                pl.BlockSpec((block_q, 1),
+                             lambda b, h, s, qt, kt, f: (qt[s], 0)),
+                pl.BlockSpec((1, block_k),
+                             lambda b, h, s, qt, kt, f: (0, kt[s])),
+            ],
+            out_specs=[
+                q_side(value_dim),
+                pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b, h, s, qt, kt, f: (b, h, qt[s], 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, heads, n, value_dim), q.dtype),
+            jax.ShapeDtypeStruct((batch, heads, n, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        cost_estimate=_diffusion_cost(
+            q, v, len(live) * block_q * block_k, 2),
+    )(qt, kt, flags, q, k, v, qblk, kblk)
+    return out, lse
+
+
+def _diffusion_backward(q, k, v, out, lse, g, block: int, interpret: bool,
+                        scale):
+    batch, heads, n, head_dim = q.shape
+    value_dim = v.shape[3]
+    kv_heads = k.shape[1]
+    group = _gqa_group(q, k)
+    half = n // 2
+    block_q = _diffusion_fit(None, _DEFAULT_BWD_BLOCK, half)
+    block_k = _diffusion_fit(None, _DEFAULT_BWD_BLOCK, half)
+    scale = head_dim**-0.5 if scale is None else scale
+    noisy = (half // block_q, half // block_k)
+    qblk, kblk = _diffusion_blocks(q, block)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    live = diffusion_tiles(half, block, block_q, block_k)
+    cost = _diffusion_cost(q, v, len(live) * block_q * block_k, 5)
+
+    # dK/dV: key tile by key tile, and inside a key tile's run every
+    # member of its kv head's group over the live query tiles.
+    runs = sorted((j, gi, i, masked) for i, j, masked in live
+                  for gi in range(group))
+    kt, gi, qt, flags = _diffusion_table(runs)
+
+    def q_in(width):
+        return pl.BlockSpec(
+            (1, 1, block_q, width),
+            lambda b, h, s, kt, gi, qt, f: (b, h * group + gi[s], qt[s], 0))
+
+    def k_in(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, s, kt, gi, qt, f: (b, h, kt[s], 0))
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_diffusion_dkdv_kernel, scale=scale, noisy=noisy),
+        name="flash_bwd_dkdv",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(batch, kv_heads, len(runs)),
+            in_specs=[
+                q_in(head_dim), k_in(head_dim), k_in(value_dim),
+                q_in(value_dim), q_in(1), q_in(1),
+                pl.BlockSpec((block_q, 1),
+                             lambda b, h, s, kt, gi, qt, f: (qt[s], 0)),
+                pl.BlockSpec((1, block_k),
+                             lambda b, h, s, kt, gi, qt, f: (0, kt[s])),
+            ],
+            out_specs=[k_in(head_dim), k_in(value_dim)],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, head_dim), jnp.float32),
+                pltpu.VMEM((block_k, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+        cost_estimate=cost,
+    )(kt, gi, qt, flags, q, k, v, g, lse, delta, qblk, kblk)
+
+    # dQ: query tile by query tile, as the forward walks them.
+    qt, kt, flags = _diffusion_table(live)
+
+    def q_side(width):
+        return pl.BlockSpec((1, 1, block_q, width),
+                            lambda b, h, s, qt, kt, f: (b, h, qt[s], 0))
+
+    def k_side(width):
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
+
+    dq = pl.pallas_call(
+        functools.partial(_diffusion_dq_kernel, scale=scale, noisy=noisy),
+        name="flash_bwd_dq",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch, heads, len(live)),
+            in_specs=[
+                q_side(head_dim), k_side(head_dim), k_side(value_dim),
+                q_side(value_dim), q_side(1), q_side(1),
+                pl.BlockSpec((block_q, 1),
+                             lambda b, h, s, qt, kt, f: (qt[s], 0)),
+                pl.BlockSpec((1, block_k),
+                             lambda b, h, s, qt, kt, f: (0, kt[s])),
+            ],
+            out_specs=q_side(head_dim),
+            scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        cost_estimate=cost,
+    )(qt, kt, flags, q, k, v, g, lse, delta, qblk, kblk)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_diffusion(q, k, v, block, block_q, block_k, interpret, scale):
+    out, _ = _diffusion_forward(q, k, v, block, block_q, block_k, interpret,
+                                scale)
+    return out
+
+
+def _flash_diffusion_fwd(q, k, v, block, block_q, block_k, interpret, scale):
+    out, lse = _diffusion_forward(q, k, v, block, block_q, block_k,
+                                  interpret, scale)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_diffusion_bwd(block, block_q, block_k, interpret, scale,
+                         residuals, g):
+    q, k, v, out, lse = residuals
+    return _diffusion_backward(q, k, v, out, lse, g, block, interpret, scale)
+
+
+_flash_diffusion.defvjp(_flash_diffusion_fwd, _flash_diffusion_bwd)
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1020,6 +1430,7 @@ def flash_attention(
     window: int | None = None,
     sinks: int = 0,
     scale: float | None = None,
+    diffusion_block: int | None = None,
 ) -> jax.Array:
     """Flash attention over (B, H, S, D) inputs.
 
@@ -1060,6 +1471,12 @@ def flash_attention(
     band as a sink-tile run + band run, and the dk/dv sweep splits into
     a full-sweep call over the sink key tiles plus a banded call over
     the rest, so all three sweeps stay O(S·w) with sinks on.
+
+    ``diffusion_block=B`` masks by :func:`diffusion_visible` in place of
+    ``causal``: the S rows of ``q``, ``k`` and ``v`` are a noisy and a
+    clean copy of S / 2 positions, ``[noisy ; clean]``.  The three sweeps
+    visit only the live tiles (:func:`diffusion_tiles`); ``block_q`` and
+    ``block_k`` must divide each half.  No window, sinks or positions.
     """
     _check_window(window, causal, sinks)
     if interpret is None:
@@ -1069,6 +1486,14 @@ def flash_attention(
             f"q and k must share the scores' width, got {q.shape[-1]} and "
             f"{k.shape[-1]}"
         )
+    if diffusion_block is not None:
+        if (window is not None or q_positions is not None
+                or k_positions is not None or q.shape[2] != k.shape[2]):
+            raise ValueError(
+                "diffusion_block takes no window or positions, and keys "
+                "as long as the queries")
+        return _flash_diffusion(q, k, v, diffusion_block, block_q, block_k,
+                                interpret, scale)
     return _flash(
         q, k, v, q_positions, k_positions, causal, block_q, block_k,
         interpret, window, sinks, scale,

@@ -55,12 +55,16 @@ def _tiled(a, tile, n):
     return jnp.swapaxes(jnp.pad(a, pad).reshape(tile, n, *a.shape[1:]), 0, 1)
 
 
-def _tiles(x, w, labels, chunk):
+def _tiles(x, w, labels, chunk, weights=None):
     """The scan's inputs: x, labels and the rows' weights by tile, and the
-    head in the input dtype.  A row weighs 1/T, a padded row nothing."""
+    head in the input dtype.  A row weighs 1/T where the caller gives no
+    ``weights``; a padded row weighs nothing."""
     tokens = x.shape[0]
     tile, n = _row_tiles(tokens, w.shape[1], chunk)
-    weight = jnp.full((tokens,), 1.0 / tokens, jnp.float32)
+    if weights is None:
+        weight = jnp.full((tokens,), 1.0 / tokens, jnp.float32)
+    else:
+        weight = weights.astype(jnp.float32)
     return (
         _tiled(x, tile, n),
         _tiled(labels.astype(jnp.int32), tile, n),
@@ -85,7 +89,7 @@ def _scores(x_t, w, labels_t):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_cross_entropy(x, w, labels, chunk: int = 8192):
+def fused_cross_entropy(x, w, labels, chunk: int = 8192, weights=None):
     """Mean cross-entropy of ``softmax(x @ w)`` against integer labels.
 
     ``x``: (T, d) features (bf16 on TPU), ``w``: (d, V) lm_head kernel,
@@ -93,9 +97,11 @@ def fused_cross_entropy(x, w, labels, chunk: int = 8192):
     chunk elements).  It matches a bf16-input,
     f32-accumulated logits matmul followed by a stable log-softmax — NOT
     the f32-input matmul path (which is the point: that path runs at
-    half MXU rate and writes the full logits tensor).
+    half MXU rate and writes the full logits tensor).  ``weights`` ((T,),
+    no gradient) replaces the mean's 1/T a row: the loss is then the
+    weighted sum.
     """
-    tiles, w = _tiles(x, w, labels, chunk)
+    tiles, w = _tiles(x, w, labels, chunk, weights)
 
     def body(_, t):
         x_t, labels_t, weight_t = t
@@ -106,8 +112,8 @@ def fused_cross_entropy(x, w, labels, chunk: int = 8192):
     return jnp.sum(parts)
 
 
-def _fused_xent_fwd(x, w, labels, chunk):
-    tiles, wc = _tiles(x, w, labels, chunk)
+def _fused_xent_fwd(x, w, labels, chunk, weights):
+    tiles, wc = _tiles(x, w, labels, chunk, weights)
 
     def body(dw, t):
         x_t, labels_t, weight_t = t
@@ -137,7 +143,7 @@ def _fused_xent_fwd(x, w, labels, chunk):
 def _fused_xent_bwd(chunk, res, g):
     dx, dw = res
     d_labels = np.zeros(dx.shape[:1], jax.dtypes.float0)
-    return (dx * g).astype(dx.dtype), (dw * g).astype(dw.dtype), d_labels
+    return (dx * g).astype(dx.dtype), (dw * g).astype(dw.dtype), d_labels, None
 
 
 fused_cross_entropy.defvjp(_fused_xent_fwd, _fused_xent_bwd)

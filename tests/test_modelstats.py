@@ -85,7 +85,40 @@ def test_the_result_trailer_carries_the_models_counts():
         "held", "dropped", "whole_buffer"}
 
 
-@pytest.mark.parametrize("field", ["latent", "routed", "streams"])
+def test_masked_tokens_record_absorb_and_leave_the_routed_series_alone():
+    """A block-diffusion step's counts: the tokens it masked, with the
+    routed layers' table where it has routed layers, or alone."""
+    worker, dispatcher = Registry(), Registry()
+    modelstats._record([
+        {"masked": np.float32(4100.0),
+         "moe": np.array([[100.0, 1.2, 0.0, 0.0]])},
+        {"masked": np.float32(4000.0)},
+    ], worker)
+    totals = modelstats.totals(worker)
+    assert totals["steps"] == 2 and totals["tokens"] == {"masked": 8100.0}
+    assert totals["rows"]["held"] == 100.0
+    json.dumps(totals)
+    modelstats.absorb_worker(totals, dispatcher)
+    snap = dispatcher.snapshot()["metrics"]
+    per_step = snap[modelstats.WORKER_DIFFUSION_TOKENS_PER_STEP]["series"]
+    assert [(e["labels"], e["value"]) for e in per_step] == [
+        ({"kind": "masked"}, 4050.0)]
+    # A program without routed layers: the tokens alone, no rows.
+    alone = Registry()
+    modelstats._record([{"masked": 7.0}], alone)
+    assert modelstats.totals(alone) == {
+        "rows": {}, "steps": 1, "load_ratio": {}, "tokens": {"masked": 7.0}}
+    # A routed program's totals carry no tokens, and absorb makes none.
+    routed, home = Registry(), Registry()
+    modelstats._record([np.array([[5.0, 1.0, 0.0, 0.0]])], routed)
+    assert "tokens" not in modelstats.totals(routed)
+    modelstats.absorb_worker(modelstats.totals(routed), home)
+    assert modelstats.WORKER_DIFFUSION_TOKENS_PER_STEP not in (
+        home.snapshot()["metrics"])
+
+
+@pytest.mark.parametrize("field", ["latent", "routed", "streams",
+                                   "diffusion_block"])
 def test_the_serving_engine_refuses_the_block_forms_by_name(field):
     import jax.numpy as jnp
 
@@ -100,7 +133,8 @@ def test_the_serving_engine_refuses_the_block_forms_by_name(field):
 
     value = {"latent": LatentAttentionConfig(8, 8, 8, 4, 8),
              "routed": RoutedExpertsConfig(8, 2, 16),
-             "streams": ResidualStreamsConfig()}[field]
+             "streams": ResidualStreamsConfig(),
+             "diffusion_block": 4}[field]
     config = TransformerConfig(
         vocab_size=32, d_model=16, n_layers=1, n_heads=2, d_ff=32,
         dtype=jnp.float32, scan_layers=False, **{field: value})

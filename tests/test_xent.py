@@ -272,3 +272,46 @@ def test_fused_xent_under_sharded_train_step():
     assert abs(float(m_std["loss"]) - float(m_fused["loss"])) < 5e-3
     gs, gf = float(m_std["grad_norm"]), float(m_fused["grad_norm"])
     assert abs(gs - gf) / gs < 0.02
+
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_fused_xent_weights_of_one_over_t_lower_as_the_default(
+        differentiated):
+    """A row weighs 1/T where the caller gives no weights: handing that in
+    lowers to the same text, the loss and its gradients alike."""
+    x, w, labels = _inputs(300, 64, 500)
+
+    def text(weighted: bool):
+        def loss(x, w):
+            weights = jnp.full((300,), 1.0 / 300, jnp.float32)
+            return fused_cross_entropy(
+                x, w, labels, 256, *([weights] if weighted else []))
+
+        fn = jax.value_and_grad(loss, (0, 1)) if differentiated else loss
+        return jax.jit(fn).lower(x, w).as_text()
+
+    assert text(True) == text(False)
+
+
+def test_fused_xent_row_weights_weigh_each_rows_loss_and_gradients():
+    """The weighted sum of the rows' cross-entropies, and its gradients,
+    against the dense path; a row of weight 0 adds nothing."""
+    x, w, labels = _inputs(300, 64, 500)
+    weights = jax.random.uniform(jax.random.PRNGKey(3), (300,))
+    weights = weights.at[:50].set(0.0)
+
+    def dense(x, w):
+        logits = x @ w
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        nll = lse - jnp.take_along_axis(logits, labels[:, None], 1)[:, 0]
+        return jnp.sum(weights * nll)
+
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = jax.value_and_grad(dense, (0, 1))(x, w)
+        got, grads = jax.value_and_grad(
+            lambda x, w: fused_cross_entropy(x, w, labels, 256, weights),
+            (0, 1))(x, w)
+    assert got == pytest.approx(float(want), rel=1e-5)
+    for g, ref in zip(grads, want_grads):
+        assert jnp.max(jnp.abs(g - ref)) < 1e-5
+    assert jnp.all(grads[0][:50] == 0)
