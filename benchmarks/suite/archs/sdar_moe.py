@@ -189,7 +189,7 @@ def program(config: dict, job: dict, mesh):
         diffusion_loss, block=config["block_length"],
         mask_id=config["mask_token_id"],
         noise_key=functools.partial(noise_key, config),
-        vocab_chunk=job["vocab_chunk"])
+        vocab_chunk=job["vocab_chunk"], t_min=config["noise_level_min"])
 
 
 # -- the plain reference -----------------------------------------------------

@@ -41,7 +41,8 @@ def noise(key, x0, block: int, mask_id: int, t_min: float = T_MIN):
 
 
 def diffusion_loss(params, apply_fn, batch, *, block: int, mask_id: int,
-                   noise_key, vocab_chunk: int = 8192):
+                   noise_key, vocab_chunk: int = 8192,
+                   t_min: float = T_MIN):
     """The block-diffusion loss over a ``{"tokens": (B, S + 1)}`` batch
     (the first S ids of a row are its ``x0``), for ``make_train_step``:
     ``(loss, counts)``, the counts the step's masked tokens (``masked``)
@@ -57,7 +58,7 @@ def diffusion_loss(params, apply_fn, batch, *, block: int, mask_id: int,
     rows, seq = x0.shape
     with jax.named_scope("bd_noise"):
         xt, weight = jax.vmap(
-            lambda row: noise(noise_key(row), row, block, mask_id))(x0)
+            lambda row: noise(noise_key(row), row, block, mask_id, t_min))(x0)
     feats, sown = apply_fn(
         {"params": params}, jnp.concatenate([xt, x0], axis=1),
         mutable=["intermediates"], return_features=True)

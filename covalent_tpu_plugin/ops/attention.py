@@ -1260,8 +1260,9 @@ def _diffusion_forward(q, k, v, block: int, block_q, block_k,
                             lambda b, h, s, qt, kt, f: (b, h, qt[s], 0))
 
     def k_side(width):
-        return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
 
     out, lse = pl.pallas_call(
         functools.partial(_diffusion_fwd_kernel, scale=scale,
@@ -1367,8 +1368,9 @@ def _diffusion_backward(q, k, v, out, lse, g, block: int, interpret: bool,
                             lambda b, h, s, qt, kt, f: (b, h, qt[s], 0))
 
     def k_side(width):
-        return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, h, s, qt, kt, f: (b, h // group, kt[s], 0))
 
     dq = pl.pallas_call(
         functools.partial(_diffusion_dq_kernel, scale=scale, noisy=noisy),

@@ -100,7 +100,8 @@ def test_leaves_and_program_parameters_pair_one_to_one():
         assert int(jnp.prod(jnp.asarray(shape))) == int(
             jnp.prod(jnp.asarray(held[name]))), name
     assert held["layer_0.q_norm"] == (16,) and held["layer_1.k_norm"] == (16,)
-    assert held["layer_1.q"] == (64, 4, 16) and held["layer_1.k"] == (64, 2, 16)
+    assert held["layer_1.q"] == (64, 4, 16)
+    assert held["layer_1.k"] == (64, 2, 16)
     assert not [n for n in held if "shared" in n or "bias" in n]
     with pytest.raises(KeyError, match="no benchmark leaf"):
         arch.leaf_name((jax.tree_util.DictKey("layer_1"),
@@ -376,7 +377,8 @@ def test_the_scope_metrics_read_the_traced_scope_alone():
     # A program without the scope (the parent of this configuration):
     # nothing, and no error.
     trace["scopes"] = {lead + "q_proj/dot": 0.5}
-    assert scope_ms.read(context, **by_name["attn_bd_ms.train"]["args"]) is None
+    assert scope_ms.read(
+        context, **by_name["attn_bd_ms.train"]["args"]) is None
     assert scope_roofline.read(
         context, **by_name["attn_bd_roofline.train"]["args"]) is None
     args = by_name["bd_masked_tokens.train"]["args"]
