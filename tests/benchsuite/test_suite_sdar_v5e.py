@@ -27,7 +27,7 @@ from tests.benchsuite.test_suite_xing4_v5e import (  # noqa: F401 - fixtures
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CELL = "sdar-30b.train-8k"
-#: ISSUE 34: ``sc2-3b.train-16k``'s step holds 14.56 GB and runs.
+#: ``sc2-3b.train-16k``'s step holds 14.56 GB and runs on the chip.
 LIMIT = 14.5e9
 LAYERS = 6
 
@@ -103,15 +103,15 @@ def test_train_8k_step_fits_and_names_its_kernels_and_the_noise(
     assert re.search(r'op_name="[^"]*/optimizer/', text)
 
 
-@pytest.mark.parametrize("block", [4, 8, 16, 32, 64, 48])
+@pytest.mark.parametrize("block", [4, 16, 48])
 @pytest.mark.parametrize("differentiated", [False, True],
                          ids=["forward", "gradients"])
 def test_the_block_diffusion_kernels_compile_at_the_cell_s_widths(
         topo, no_cache, monkeypatch, block, differentiated):
     """The kernels alone at the cell's shapes (32 query heads on 4 KV
-    heads of 128 over 2 x 8,192 positions) for block lengths from 4 to 64
-    and one, 48, that divides no tile: Mosaic takes each grid of live
-    tiles, forward and backward."""
+    heads of 128 over 2 x 8,192 positions) for the cell's block length,
+    a longer one and one, 48, that divides no tile: Mosaic takes each grid
+    of live tiles, forward and backward."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
