@@ -1233,6 +1233,8 @@ def _diffusion_fit(requested: int | None, default: int, half: int) -> int:
 
 
 def _diffusion_cost(q, v, pairs: int, factor: int):
+    """The compiler's cost model of a sweep over ``pairs`` visited score
+    entries a head: ``factor`` FLOPs a pair for each of the two widths."""
     batch, heads, _, head_dim = q.shape
     return pl.CostEstimate(
         flops=int(batch * heads * pairs * factor * (head_dim + v.shape[3])),
