@@ -65,14 +65,15 @@ def layer_specs(config: dict, i: int) -> list:
     s = sizes(config)
     std = float(config["initializer_range"])
     res = std / (2 * s["L"]) ** 0.5
+    qk = {"const": float(config["qk_norm_init"])}
     d, p = s["D"], f"layer_{i}."
     specs = [
         ("ln_attn", (d,), None),
         ("q", (d, s["H"] * s["hd"]), std),
         ("k", (d, s["KV"] * s["hd"]), std),
         ("v", (d, s["KV"] * s["hd"]), std),
-        ("q_norm", (s["hd"],), None),
-        ("k_norm", (s["hd"],), None),
+        ("q_norm", (s["hd"],), qk),
+        ("k_norm", (s["hd"],), qk),
         ("o", (s["H"] * s["hd"], d), res),
         ("ln_mlp", (d,), None),
         ("router", (d, s["E"]), std),

@@ -20,15 +20,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-#: The least noise level a block draws: its tokens' weight is at most
-#: 1 / T_MIN.
-T_MIN = 1e-3
 
-
-def noise(key, x0, block: int, mask_id: int, t_min: float = T_MIN):
+def noise(key, x0, block: int, mask_id: int, *, t_min: float):
     """``(xt, weight)`` for one row ``x0`` (S,): the row with each block's
     masked tokens replaced by ``mask_id``, and each token's loss weight,
-    ``1 / t`` of its block where it is masked and 0 elsewhere."""
+    ``1 / t`` of its block where it is masked and 0 elsewhere.  ``t_min``
+    is the least level a block draws, so a weight is at most
+    ``1 / t_min``."""
     seq = x0.shape[0]
     if seq % block:
         raise ValueError(f"a row of {seq} is not whole blocks of {block}")
@@ -41,8 +39,7 @@ def noise(key, x0, block: int, mask_id: int, t_min: float = T_MIN):
 
 
 def diffusion_loss(params, apply_fn, batch, *, block: int, mask_id: int,
-                   noise_key, vocab_chunk: int = 8192,
-                   t_min: float = T_MIN):
+                   noise_key, t_min: float, vocab_chunk: int = 8192):
     """The block-diffusion loss over a ``{"tokens": (B, S + 1)}`` batch
     (the first S ids of a row are its ``x0``), for ``make_train_step``:
     ``(loss, counts)``, the counts the step's masked tokens (``masked``)
@@ -58,7 +55,8 @@ def diffusion_loss(params, apply_fn, batch, *, block: int, mask_id: int,
     rows, seq = x0.shape
     with jax.named_scope("bd_noise"):
         xt, weight = jax.vmap(
-            lambda row: noise(noise_key(row), row, block, mask_id, t_min))(x0)
+            lambda row: noise(noise_key(row), row, block, mask_id,
+                              t_min=t_min))(x0)
     feats, sown = apply_fn(
         {"params": params}, jnp.concatenate([xt, x0], axis=1),
         mutable=["intermediates"], return_features=True)

@@ -15,8 +15,6 @@ import json
 import os
 import re
 
-import pytest
-
 from tests.benchsuite.test_suite_xing4_v5e import (  # noqa: F401 - fixtures
     _held,
     no_cache,
@@ -101,44 +99,6 @@ def test_train_8k_step_fits_and_names_its_kernels_and_the_noise(
     assert re.search(r'op_name="[^"]*/loss/jvp\(bd_noise\)/', text)
     assert re.search(r'op_name="[^"]*/experts/cond/branch_1_fun/', text)
     assert re.search(r'op_name="[^"]*/optimizer/', text)
-
-
-@pytest.mark.parametrize("block", [4, 16, 48])
-@pytest.mark.parametrize("differentiated", [False, True],
-                         ids=["forward", "gradients"])
-def test_the_block_diffusion_kernels_compile_at_the_cell_s_widths(
-        topo, no_cache, monkeypatch, block, differentiated):
-    """The kernels alone at the cell's shapes (32 query heads on 4 KV
-    heads of 128 over 2 x 8,192 positions) for the cell's block length,
-    a longer one and one, 48, that divides no tile: Mosaic takes each grid
-    of live tiles, forward and backward."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
-
-    from covalent_tpu_plugin.ops import attention
-
-    monkeypatch.setattr(attention, "default_interpret", lambda: False)
-    one = SingleDeviceSharding(topo.devices[0])
-    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16, sharding=one)
-    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16, sharding=one)
-
-    def attend(q, k, v):
-        return attention.flash_attention(q, k, v, diffusion_block=block)
-
-    def loss(q, k, v):
-        return jnp.sum(attend(q, k, v).astype(jnp.float32))
-
-    fn = jax.grad(loss, (0, 1, 2)) if differentiated else attend
-    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    # Outside a scope a transform's name wraps the kernel's (``jvp_...``).
-    want = ["flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"] if (
-        differentiated) else ["flash_fwd"]
-    assert len(calls) == len(want), calls
-    for name in want:
-        assert any(name in line for line in calls), name
 
 
 def test_reference_train_8k_step_fits_one_chip(one_chip, no_cache):

@@ -12,12 +12,15 @@ import pytest
 from flax.core import meta
 
 from covalent_tpu_plugin.models import TransformerConfig, TransformerLM
-from covalent_tpu_plugin.models.diffusion import T_MIN, noise
+from covalent_tpu_plugin.models.diffusion import noise
+
+#: The least noise level these tests draw at.
+T_MIN = 1e-3
 
 
 def test_noise_masks_each_block_at_its_own_level():
     x0 = jnp.arange(4096, dtype=jnp.int32) % 100
-    xt, weight = noise(jax.random.PRNGKey(3), x0, 4, 999)
+    xt, weight = noise(jax.random.PRNGKey(3), x0, 4, 999, t_min=T_MIN)
     masked = np.asarray(weight > 0)
     np.testing.assert_array_equal(np.asarray(xt == 999), masked)
     np.testing.assert_array_equal(np.asarray(xt)[~masked],
@@ -31,10 +34,10 @@ def test_noise_masks_each_block_at_its_own_level():
     assert np.all(blocks[blocks > 0] <= 1.0 / T_MIN)
     # U[1e-3, 1] masks about half; the same key draws the same noise.
     assert 0.45 < masked.mean() < 0.55
-    again = noise(jax.random.PRNGKey(3), x0, 4, 999)
+    again = noise(jax.random.PRNGKey(3), x0, 4, 999, t_min=T_MIN)
     np.testing.assert_array_equal(np.asarray(again[1]), np.asarray(weight))
     with pytest.raises(ValueError, match="whole blocks"):
-        noise(jax.random.PRNGKey(3), x0[:10], 4, 999)
+        noise(jax.random.PRNGKey(3), x0[:10], 4, 999, t_min=T_MIN)
 
 
 def _model(**changes):
