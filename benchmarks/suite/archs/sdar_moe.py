@@ -31,8 +31,8 @@ import jax.numpy as jnp
 
 from benchmarks.suite import reference, work
 
-#: The named scope the program's kernel call stands under (what
-#: ``attn_bd_ms.train`` and ``attn_bd_roofline.train`` read).
+#: The named scope the program's kernel call stands under, and the part
+#: whose needed work ``kernel_work`` gives for it.
 SCOPE = "attn_bd"
 
 

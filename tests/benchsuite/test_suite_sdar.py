@@ -350,11 +350,19 @@ def test_needed_work_against_hand_arithmetic():
     assert archs.load(config) is arch
 
 
-def test_the_scope_metrics_read_the_traced_scope_alone():
+#: The arguments the benchmark's existing readers take for the new scope and
+#: series, ready for per-layer entries of their own.
+ATTN_BD = {"under": [arch.SCOPE]}
+MASKED = {"label": "kind", "values": ["masked"]}
+
+
+def test_the_existing_readers_read_the_traced_scope_alone(monkeypatch):
     from benchmarks.suite.readers import registry_sum, scope_ms, scope_roofline
+    from covalent_tpu_plugin import obs
+    from covalent_tpu_plugin.obs import modelstats
+    from covalent_tpu_plugin.obs.metrics import Registry
 
     cell = spec.load_cell(REPO, REAL)
-    by_name = {m["name"]: m for m in cell["per_layer"]}
     lead = "jit(step)/loss/jvp(LM)/layer_1/attention/"
     trace = {
         "scopes": {lead + "attn_bd/flash_fwd": 0.004,
@@ -366,27 +374,30 @@ def test_the_scope_metrics_read_the_traced_scope_alone():
     }
     context = {"cell": cell, "require_tpu": True, "chips": 1, "trace": trace,
                "trace_steps": 2, "device": {"kind": "TPU v5 lite"}}
-    assert scope_ms.read(
-        context, **by_name["attn_bd_ms.train"]["args"]) == pytest.approx(6.0)
+    assert scope_ms.read(context, **ATTN_BD) == pytest.approx(6.0)
     needed = arch.kernel_work(cell["config"], cell["traffic"], "attn_bd")
     assert scope_roofline.read(
-        context, **by_name["attn_bd_roofline.train"]["args"]
+        context, part=arch.SCOPE, **ATTN_BD
     ) == pytest.approx(100 * needed["flops"] / 197e12 / 0.006)
     # Not the flash metrics of the dense cell, which read every kernel.
-    assert not [n for n in by_name if n.startswith("flash_")]
+    assert not [m["name"] for m in cell["per_layer"]
+                if m["name"].startswith("flash_")]
     # A program without the scope (the parent of this configuration):
     # nothing, and no error.
     trace["scopes"] = {lead + "q_proj/dot": 0.5}
-    assert scope_ms.read(
-        context, **by_name["attn_bd_ms.train"]["args"]) is None
-    assert scope_roofline.read(
-        context, **by_name["attn_bd_roofline.train"]["args"]) is None
-    args = by_name["bd_masked_tokens.train"]["args"]
-    assert by_name["bd_masked_tokens.train"]["reader"] == "registry_sum"
-    from covalent_tpu_plugin.obs import modelstats
-
-    assert args["metric"] == modelstats.WORKER_DIFFUSION_TOKENS_PER_STEP
-    assert registry_sum.read(context, **dict(args, metric="no_such")) is None
+    assert scope_ms.read(context, **ATTN_BD) is None
+    assert scope_roofline.read(context, part=arch.SCOPE, **ATTN_BD) is None
+    # The masked tokens a step, on a registry of its own: nothing before a
+    # worker sent its counts home, then their mean over its steps.
+    registry = Registry()
+    monkeypatch.setattr(obs, "REGISTRY", registry)
+    masked = modelstats.WORKER_DIFFUSION_TOKENS_PER_STEP
+    assert registry_sum.read(context, metric=masked, **MASKED) is None
+    modelstats.absorb_worker({
+        "steps": 2, "rows": {"held": 2.0}, "load_ratio": {"peak": 1.0},
+        "tokens": {"masked": 8100.0}}, registry=registry)
+    assert registry_sum.read(
+        context, metric=masked, **MASKED) == pytest.approx(4050.0)
 
 
 def test_the_shares_of_experts_add_up_to_the_uncut_layer():
