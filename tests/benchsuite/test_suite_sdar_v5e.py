@@ -2,8 +2,11 @@
 described (not attached) v5e chip: the bytes the step holds (16,384
 positions through six layers, the whole row buffer behind every layer's
 conditional), the block-diffusion kernels and the noise under their scopes,
-the parameters the program builds, and the plain reference's float32 step
-on the same chip.  Costs no chip time and guards every later PR.
+and the parameters the program builds.  Costs no chip time and guards every
+later PR that changes the program.  The plain reference's float32 step is
+not compiled here: no such PR can change it, every run on the chip runs it
+at this size, and its compile for the described chip takes minutes of
+several cores (it holds 13.19 GB there).
 
 The fixtures are ``test_suite_xing4_v5e.py``'s, by import: the topology is
 described inside a fixture, never at import.
@@ -18,7 +21,6 @@ import re
 from tests.benchsuite.test_suite_xing4_v5e import (  # noqa: F401 - fixtures
     _held,
     no_cache,
-    one_chip,
     topo,
 )
 
@@ -100,24 +102,3 @@ def test_train_8k_step_fits_and_names_its_kernels_and_the_noise(
     assert re.search(r'op_name="[^"]*/experts/cond/branch_1_fun/', text)
     assert re.search(r'op_name="[^"]*/optimizer/', text)
 
-
-def test_reference_train_8k_step_fits_one_chip(one_chip, no_cache):
-    """The plain reference follows the cell's first steps on the same chip
-    once the program is gone: its float32 step has to fit too."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmarks.suite import archs, reference
-
-    cell = _cell()
-    config, job = cell["config"], cell["traffic"]
-    f32 = jnp.dtype("float32")
-    w = {name: jax.ShapeDtypeStruct(shape, f32, sharding=one_chip)
-         for name, shape, _ in archs.load(config).leaf_specs(config)}
-    batch = jax.ShapeDtypeStruct(
-        (job["batch"], job["sequence"] + 1), jnp.int32, sharding=one_chip)
-    count = jax.ShapeDtypeStruct((), f32, sharding=one_chip)
-    step = reference.make_train_step(config, job, f32, None)
-    compiled = step.lower(w, w, w, batch, count).compile()
-    print(json.dumps({"reference": {"held": _held(compiled)}}))
-    assert _held(compiled) <= LIMIT
