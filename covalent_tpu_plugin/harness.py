@@ -841,11 +841,19 @@ def run_task(spec: dict) -> int:
             f.write(str(os.getpid()))
         os.replace(tmp_pid, pid_file)
 
+    # Liveness starts before any blocking stage (the env contract's
+    # `import jax`, pip install, distributed barrier, the task itself): a
+    # worker hung anywhere keeps beating — and one that goes silent is
+    # genuinely wedged, not slow to import on a busy host.
+    heartbeat_stop = _start_heartbeat(spec)
+
     distributed = spec.get("distributed")
     process_id = int(distributed["process_id"]) if distributed else 0
     try:
         _apply_spec_env(spec)
     except Exception as env_error:  # noqa: BLE001 - transported to dispatcher
+        if heartbeat_stop is not None:
+            heartbeat_stop.set()
         if process_id == 0:
             _fallback_result(result_file, env_error)
         return 1
@@ -859,10 +867,6 @@ def run_task(spec: dict) -> int:
             spec[sink_key] = os.path.abspath(spec[sink_key])
 
     _emit_worker_event(spec, "worker.task_started", process_id=process_id)
-    # Liveness starts before any blocking stage (pip install, distributed
-    # barrier, the task itself): a worker hung anywhere keeps beating —
-    # and one that goes silent is genuinely wedged.
-    heartbeat_stop = _start_heartbeat(spec)
 
     # Elastic gangs: interval checkpointer (the SIGTERM preemption handler
     # is installed LATER, after the distributed bootstrap — jax's

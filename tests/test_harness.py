@@ -9,6 +9,7 @@ import os
 import pickle
 import subprocess
 import sys
+import time
 
 import cloudpickle
 import pytest
@@ -66,6 +67,30 @@ def test_run_task_applies_env(tmp_path, monkeypatch):
     harness.run_task(spec)
     result, _ = load_result(result_file)
     assert result == "42"
+
+
+def test_run_task_beats_before_the_env_contract(tmp_path, monkeypatch):
+    """The env contract may ``import jax`` (the platform pin), seconds on a
+    busy host: a worker that has not beaten by then reads as stalled from
+    birth to the dispatcher, so the first beat must not wait for it."""
+    # The beats go to the telemetry file alone: with no pid file there is
+    # no snapshot and no stacks file held open by this process's record.
+    beats = tmp_path / "telemetry.jsonl"
+    beat_first = []
+
+    def apply_env(spec):
+        deadline = time.monotonic() + 10
+        while not beats.exists() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        beat_first.append(beats.exists())
+
+    monkeypatch.setattr(harness, "_apply_spec_env", apply_env)
+    spec, result_file = _stage(
+        tmp_path, lambda: 1, telemetry_file=str(beats), heartbeat_s=5.0,
+    )
+    assert harness.run_task(spec) == 0
+    assert beat_first == [True]
+    assert load_result(result_file) == (1, None)
 
 
 def test_run_task_nonzero_process_writes_done_marker(tmp_path, monkeypatch):

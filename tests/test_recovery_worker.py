@@ -13,6 +13,7 @@ cache, so the rendezvous artifacts land next to the copy (the contract
 
 import json
 import os
+import select
 import shutil
 import socket
 import subprocess
@@ -28,8 +29,86 @@ from covalent_tpu_plugin.cache import bytes_digest
 from .test_serving import GATE_OPEN, allow_steps, make_factory
 
 
-class Worker:
-    """A pool server over raw pipes, with a background JSONL reader."""
+class _EventTail:
+    """The JSONL events of one channel, gathered by a background reader.
+
+    The reader polls the raw fd and never blocks inside a buffered
+    stream: a blocked buffered read holds the stream's lock, so closing
+    the stream would wait for the worker's next line (the 30 s stats
+    line).  Stop the reader before closing the fd it reads."""
+
+    def _start_reader(self, fd: int) -> None:
+        self.events: list = []
+        self._cond = threading.Condition()
+        self._stop = threading.Event()
+        self._reader = threading.Thread(target=self._read, args=(fd,), daemon=True)
+        self._reader.start()
+
+    def _read(self, fd: int) -> None:
+        pending = b""
+        while not self._stop.is_set():
+            if not select.select([fd], [], [], 0.05)[0]:
+                continue
+            try:
+                chunk = os.read(fd, 65536)
+            except BlockingIOError:
+                continue  # the socket's timeout makes its fd non-blocking
+            except OSError:
+                return  # connection reset by the worker
+            if not chunk:
+                return  # EOF: the worker closed its end
+            *lines, pending = (pending + chunk).split(b"\n")
+            for raw in lines:
+                try:
+                    event = json.loads(raw)
+                except ValueError:
+                    continue
+                with self._cond:
+                    self.events.append(event)
+                    self._cond.notify_all()
+
+    def _stop_reader(self) -> None:
+        self._stop.set()
+        self._reader.join()
+
+    def wait_for(self, pred, timeout=20.0):
+        deadline = time.monotonic() + timeout
+        with self._cond:
+            while True:
+                for event in self.events:
+                    if pred(event):
+                        return event
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    raise AssertionError(
+                        f"no matching event within {timeout}s; saw "
+                        f"{[e.get('event') for e in self.events]}"
+                    )
+                self._cond.wait(left)
+
+    def tokens(self, rid, base=0):
+        """Exactly-once splice of every serve.token chunk seen for rid,
+        with absolute idx positions; ``base`` is a resumed stream's
+        starting offset (the client-held high-water mark)."""
+        out: list = []
+        with self._cond:
+            chunks = [
+                e["data"] for e in self.events
+                if e.get("event") == "telemetry"
+                and (e.get("data") or {}).get("type") == "serve.token"
+                and e["data"].get("rid") == rid
+            ]
+        for data in chunks:
+            idx = int(data.get("idx") or 0) - base
+            toks = list(data.get("tokens") or [])
+            if idx > len(out):
+                raise AssertionError(f"token gap for {rid}: idx {idx} > have {len(out)}")
+            out.extend(toks[len(out) - idx:])
+        return out
+
+
+class Worker(_EventTail):
+    """A pool server over raw pipes."""
 
     def __init__(self, tmp_path, env=None):
         self.dir = tmp_path / "pool"
@@ -48,25 +127,7 @@ class Worker:
             stdin=subprocess.PIPE, stdout=subprocess.PIPE,
             stderr=subprocess.DEVNULL, env=full_env,
         )
-        self.events: list = []
-        self._cond = threading.Condition()
-        self._reader = threading.Thread(
-            target=self._read, args=(self.proc.stdout,), daemon=True
-        )
-        self._reader.start()
-
-    def _read(self, stream) -> None:
-        try:
-            for raw in stream:
-                try:
-                    event = json.loads(raw)
-                except ValueError:
-                    continue
-                with self._cond:
-                    self.events.append(event)
-                    self._cond.notify_all()
-        except (OSError, ValueError):
-            pass  # read end torn down by the test: the "crash"
+        self._start_reader(self.proc.stdout.fileno())
 
     def stage(self, factory):
         payload = cloudpickle.dumps(factory)
@@ -79,54 +140,19 @@ class Worker:
         self.proc.stdin.write((json.dumps(cmd) + "\n").encode())
         self.proc.stdin.flush()
 
-    def wait_for(self, pred, timeout=20.0):
-        deadline = time.monotonic() + timeout
-        with self._cond:
-            while True:
-                for event in self.events:
-                    if pred(event):
-                        return event
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    raise AssertionError(
-                        f"no matching event within {timeout}s; saw "
-                        f"{[e.get('event') for e in self.events]}"
-                    )
-                self._cond.wait(left)
-
-    def tokens(self, rid):
-        """Exactly-once splice of every serve.token chunk seen for rid."""
-        out: list = []
-        with self._cond:
-            chunks = [
-                e["data"] for e in self.events
-                if e.get("event") == "telemetry"
-                and (e.get("data") or {}).get("type") == "serve.token"
-                and e["data"].get("rid") == rid
-            ]
-        for data in chunks:
-            idx = int(data.get("idx") or 0)
-            toks = list(data.get("tokens") or [])
-            if idx > len(out):
-                raise AssertionError(f"token gap for {rid}: idx {idx} > have {len(out)}")
-            fresh = toks[len(out) - idx:]
-            out.extend(fresh)
-        return out
-
     def crash_dispatcher(self) -> None:
         """Sever both pipes without touching the child: stdout first so
         in-flight emits hit a dead pipe (tokens genuinely lost), then
         stdin EOF to trigger the worker's orphan path."""
-        try:
-            self.proc.stdout.close()
-        except OSError:
-            pass
+        self._stop_reader()
+        self.proc.stdout.close()
         try:
             self.proc.stdin.close()
         except OSError:
             pass
 
     def close(self) -> None:
+        self._stop_reader()
         if self.proc.poll() is None:
             try:
                 self.proc.stdin.close()
@@ -137,79 +163,24 @@ class Worker:
             except subprocess.TimeoutExpired:
                 self.proc.kill()
                 self.proc.wait()
+        self.proc.stdout.close()
 
 
-class SockChannel:
+class SockChannel(_EventTail):
     """JSONL over the adoption unix socket — the successor dispatcher."""
 
     def __init__(self, path: str):
         self.sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         self.sock.settimeout(20.0)
         self.sock.connect(path)
-        self._file = self.sock.makefile("rb")
-        self.events: list = []
-        self._cond = threading.Condition()
-        self._reader = threading.Thread(target=self._read, daemon=True)
-        self._reader.start()
-
-    def _read(self) -> None:
-        try:
-            for raw in self._file:
-                try:
-                    event = json.loads(raw)
-                except ValueError:
-                    continue
-                with self._cond:
-                    self.events.append(event)
-                    self._cond.notify_all()
-        except (OSError, ValueError):
-            pass
+        self._start_reader(self.sock.fileno())
 
     def send(self, **cmd) -> None:
         self.sock.sendall((json.dumps(cmd) + "\n").encode())
 
-    def wait_for(self, pred, timeout=20.0):
-        deadline = time.monotonic() + timeout
-        with self._cond:
-            while True:
-                for event in self.events:
-                    if pred(event):
-                        return event
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    raise AssertionError(
-                        f"no matching socket event within {timeout}s; saw "
-                        f"{[e.get('event') for e in self.events]}"
-                    )
-                self._cond.wait(left)
-
-    def tokens(self, rid, base=0):
-        """Splice with absolute idx positions; ``base`` is the resumed
-        stream's starting offset (the client-held high-water mark)."""
-        out: list = []
-        with self._cond:
-            chunks = [
-                e["data"] for e in self.events
-                if e.get("event") == "telemetry"
-                and (e.get("data") or {}).get("type") == "serve.token"
-                and e["data"].get("rid") == rid
-            ]
-        for data in chunks:
-            idx = int(data.get("idx") or 0) - base
-            toks = list(data.get("tokens") or [])
-            if idx > len(out):
-                raise AssertionError(f"token gap for {rid}: idx {idx} > have {len(out)}")
-            out.extend(toks[len(out) - idx:])
-        return out
-
     def close(self) -> None:
-        # makefile() dups the fd: both must close for the worker's read
-        # end to see EOF.
-        for closer in (self.sock.close, self._file.close):
-            try:
-                closer()
-            except OSError:
-                pass
+        self._stop_reader()
+        self.sock.close()
 
 
 def _open_session(worker, sid="s-rec", **factory_kw):

@@ -9,15 +9,16 @@ by sigmoid score) take the same gradients with the names kept as with
 nothing kept, and the kept grad runs the router's top-k once a routed layer
 where it ran it twice, and the kept products' matmuls once; an unfenced
 checkpoint lowers to the text it lowered to before the names.  At the
-cell's own size, for a described (not attached) v5e, remat's recompute in
-``laguna-s.train-16k``'s step sorts and multiplies less than with nothing
-kept (the step's bytes and kernels are ``test_suite_laguna_v5e.py``'s).
+cell's own widths, sequence and batch, for a described (not attached) v5e,
+remat's recompute in ``laguna-s.train-16k``'s step, cut to the layers that
+hold each of its pairs of attention and MLP type once, sorts and multiplies
+less than with nothing kept (the whole step's bytes and kernels are
+``test_suite_laguna_v5e.py``'s).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
 import re
 
 import jax
@@ -33,9 +34,8 @@ from covalent_tpu_plugin.models.moe import RoutedExpertsConfig
 from covalent_tpu_plugin.models.streams import ResidualStreamsConfig
 from covalent_tpu_plugin.models.train import lm_loss
 from covalent_tpu_plugin.ops import attention
-from tests.benchsuite.test_suite_laguna_v5e import SCOPES, _cell
+from tests.benchsuite.test_suite_laguna_v5e import _cell
 from tests.benchsuite.test_suite_xing4_v5e import (  # noqa: F401 - fixtures
-    _held,
     no_cache,
     topo,
 )
@@ -178,6 +178,21 @@ def test_an_unfenced_checkpoint_lowers_as_it_did_without_the_names(
     assert lowered() == named
 
 
+def _one_of_each_pair(config: dict) -> dict:
+    """``config`` cut to the first layer of each of its pairs of
+    (attention type, MLP type): laguna's layers 0 (full, dense), 1
+    (sliding, sparse) and 4 (full, sparse)."""
+    pairs = list(zip(config["layer_types"], config["mlp_layer_types"]))
+    keep = [i for i, pair in enumerate(pairs) if pair not in pairs[:i]]
+    cut = {**config, "num_hidden_layers": len(keep)}
+    for key in ("layer_types", "mlp_layer_types", "gating_types",
+                "num_attention_heads_per_layer"):
+        cut[key] = [config[key][i] for i in keep]
+    cut["mlp_only_layers"] = [
+        i for i, kind in enumerate(cut["mlp_layer_types"]) if kind == "dense"]
+    return cut
+
+
 def test_train_16k_step_recomputes_attention_and_the_routers_gate_alone(
         topo, no_cache, monkeypatch):
     import optax
@@ -193,7 +208,7 @@ def test_train_16k_step_recomputes_attention_and_the_routers_gate_alone(
     # interpreted; the chip this compiles for runs them through Mosaic.
     monkeypatch.setattr(attention, "default_interpret", lambda: False)
     cell = _cell()
-    config, job = cell["config"], cell["traffic"]
+    config, job = _one_of_each_pair(cell["config"]), cell["traffic"]
     mesh = make_mesh(MeshPlan(**job["mesh"]), [topo.devices[0]])
     lm, loss_fn = archs.load(config).program(config, job, mesh)
     tokens = jax.ShapeDtypeStruct(
@@ -216,7 +231,6 @@ def test_train_16k_step_recomputes_attention_and_the_routers_gate_alone(
         for x, s in zip(leaves, jax.tree_util.tree_leaves(shardings))])
     step = make_train_step(loss_fn, mesh, shardings)
     compiled = step.lower(state, {"tokens": tokens}).compile()
-    print(json.dumps({"laguna-s.train-16k": {"held": _held(compiled)}}))
     text = compiled.as_text().splitlines()
     recompute = [line for line in text if "rematted_computation" in line]
     # The matmuls the recompute runs again are attention's q, k, v, gate
@@ -228,7 +242,7 @@ def test_train_16k_step_recomputes_attention_and_the_routers_gate_alone(
                          name) for name in products), products
     routed = config["mlp_layer_types"].count("sparse")
     assert sum("/router/" in name for name in products) == routed
-    assert len(products) == 5 * sum(SCOPES.values()) + routed
+    assert len(products) == 5 * config["num_hidden_layers"] + routed
     # The router's choice and the held experts' order are kept: no sort.
     assert not any(" sort(" in line.split(" metadata=")[0]
                    for line in recompute)
